@@ -39,11 +39,10 @@ def acoustic3d_fused(nx=256, nt=600, k=6, ny=None, nz=None, fused_tile=None,
         **setup_kwargs,
     )
     # Large chunks amortize per-call dispatch latency; `fused_k` must divide
-    # the chunk.  donate=False for remote/tunneled runtimes — flip it back on
-    # for a locally attached pod (docs/performance.md).
+    # the chunk.
     chunk = max(k * max(min(nt, 96) // k, 1), k)
     step = acoustic3d.make_multi_step(
-        params, chunk, fused_k=k, fused_tile=fused_tile, donate=False
+        params, chunk, fused_k=k, fused_tile=fused_tile
     )
     state = step(*state)  # compile + warmup chunk
     float(state[0].addressable_shards[0].data[0, 0, 0])  # honest completion sync
@@ -62,6 +61,9 @@ def acoustic3d_fused(nx=256, nt=600, k=6, ny=None, nz=None, fused_tile=None,
 
 
 if __name__ == "__main__":
+    from implicitglobalgrid_tpu.utils.config import enable_compile_cache
+
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--nx", type=int, default=256)
     p.add_argument("--nt", type=int, default=600)

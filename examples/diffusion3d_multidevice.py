@@ -122,6 +122,9 @@ def _write_frames(frames, outdir):
 
 
 if __name__ == "__main__":
+    from implicitglobalgrid_tpu.utils.config import enable_compile_cache
+
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--nx", type=int, default=64)
     p.add_argument("--nt", type=int, default=2000)

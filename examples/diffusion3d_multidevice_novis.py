@@ -95,6 +95,9 @@ def diffusion3d(nx=128, ny=None, nz=None, nt=1000, device_type="auto", dtype=Non
 
 
 if __name__ == "__main__":
+    from implicitglobalgrid_tpu.utils.config import enable_compile_cache
+
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--nx", type=int, default=128)
     p.add_argument("--nt", type=int, default=1000)

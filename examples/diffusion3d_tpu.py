@@ -20,6 +20,9 @@ _mod = importlib.util.module_from_spec(_spec)
 _spec.loader.exec_module(_mod)
 
 if __name__ == "__main__":
+    from implicitglobalgrid_tpu.utils.config import enable_compile_cache
+
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--nx", type=int, default=128)
     p.add_argument("--nt", type=int, default=2000)

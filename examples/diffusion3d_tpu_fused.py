@@ -39,19 +39,15 @@ def diffusion3d_fused(nx=256, nt=1000, k=2, **setup_kwargs):
     )
     # Large chunks amortize per-call dispatch latency (one compiled program
     # advances `chunk` steps); `fused_k` must divide the chunk.
-    # donate=False: on remote/tunneled runtimes donated buffers round-trip
-    # through the host (docs/performance.md); on a locally attached pod flip
-    # it back on — donation is the memory-correct production setting there.
     chunk = max(k * max(min(nt, 100) // k, 1), k)
-    step = diffusion3d.make_multi_step(params, chunk, fused_k=k, donate=False)
+    step = diffusion3d.make_multi_step(params, chunk, fused_k=k)
     state = step(*state)  # compile + warmup chunk
     float(state[0].addressable_shards[0].data[0, 0, 0])  # honest completion sync
     igg.tic()
     for _ in range(max(nt // chunk, 1)):
         state = step(*state)
-    # Async dispatch: force completion before reading the clock.  A one-element
-    # fetch is the only sync some remote backends honor (block_until_ready can
-    # return early there); it costs one host round trip.
+    # Async dispatch: force completion (a one-element fetch) before reading
+    # the clock.
     T = diffusion3d.temperature(state)
     float(T.addressable_shards[0].data[0, 0, 0])
     t = igg.toc()
@@ -64,6 +60,9 @@ def diffusion3d_fused(nx=256, nt=1000, k=2, **setup_kwargs):
 
 
 if __name__ == "__main__":
+    from implicitglobalgrid_tpu.utils.config import enable_compile_cache
+
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--nx", type=int, default=256)
     p.add_argument("--nt", type=int, default=1000)

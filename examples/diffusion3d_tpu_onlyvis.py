@@ -52,5 +52,8 @@ def diffusion3d(nx=8, ny=8, nz=8, nt=3, nvis=1, **grid_kwargs):
 
 
 if __name__ == "__main__":
+    from implicitglobalgrid_tpu.utils.config import enable_compile_cache
+
+    enable_compile_cache()
     n = len(diffusion3d())
     print(f"onlyvis recipe produced {n} frame(s)")

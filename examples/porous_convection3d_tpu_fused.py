@@ -39,11 +39,10 @@ def porous_convection3d_fused(nx=256, nt=24, w=6, npt=12, ny=None, nz=None,
         **setup_kwargs,
     )
     # Whole time steps chunk into one program (each carries npt PT
-    # iterations); donate=False for remote/tunneled runtimes — flip it back
-    # on for a locally attached pod (docs/performance.md).
+    # iterations).
     chunk = max(min(nt, 8), 1)
     step = pc.make_multi_step(
-        params, chunk, fused_k=w, fused_tile=fused_tile, donate=False
+        params, chunk, fused_k=w, fused_tile=fused_tile
     )
     state = step(*state)  # compile + warmup chunk
     float(state[0].addressable_shards[0].data[0, 0, 0])  # honest completion sync
@@ -65,6 +64,9 @@ def porous_convection3d_fused(nx=256, nt=24, w=6, npt=12, ny=None, nz=None,
 
 
 if __name__ == "__main__":
+    from implicitglobalgrid_tpu.utils.config import enable_compile_cache
+
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--nx", type=int, default=256)
     p.add_argument("--nt", type=int, default=24)

@@ -1,7 +1,7 @@
 """Pallas aliasing / buffer-donation lint.
 
 The fused kernels' carry steps are in-place: ``input_output_aliases`` tells
-XLA (and the generic Pallas interpreter, which honors it — the env-notes
+XLA (and the TPU Pallas interpreter, which honors it — the
 contract the pipelined ring/mid combine relies on) that an input buffer IS
 an output buffer.  A wrong declaration is silent corruption, not an error:
 XLA happily reuses the buffer while the kernel still reads it.  Donation
@@ -224,7 +224,7 @@ def traced_findings(ctx: Context) -> list:
                             ),
                         )
                     )
-            elif eqn.primitive.name == "pjit":
+            elif eqn.primitive.name == "jit":
                 donated = eqn.params.get("donated_invars", ())
                 if not any(donated):
                     continue

@@ -603,21 +603,15 @@ def ensure_cpu_devices(n: int = 8) -> None:
     """Stage an ``n``-device XLA:CPU mesh before first backend use.
 
     The one staging recipe shared by every CLI driver of the suite
-    (``igg_lint.py``, ``refresh_cost_baseline.py``; the tier-1 tests
-    inherit conftest's identical dance): `XLA_FLAGS` must be set before
-    the backend initializes, and the `jax_num_cpu_devices` config option
-    does not exist on older installs.  Call it before the first
-    `jax.devices()` — it is a no-op guard, not a backend reset.
+    (``igg_lint.py``, ``refresh_cost_baseline.py``; the tier-1 tests do the
+    same in conftest).  Call it before the first `jax.devices()`: the
+    config cannot change once the backend is up.
     """
     import re
 
     flags = os.environ.get("XLA_FLAGS", "")
     m = re.search(r"--xla_force_host_platform_device_count=(\d+)", flags)
-    if m is None:
-        os.environ["XLA_FLAGS"] = (
-            flags + f" --xla_force_host_platform_device_count={n}"
-        ).strip()
-    elif int(m.group(1)) != n:
+    if m is not None and int(m.group(1)) != n:
         # Silently keeping a pre-staged wrong count would surface later as
         # a confusing mesh-size error (or a census on the wrong mesh).
         raise RuntimeError(
@@ -629,10 +623,7 @@ def ensure_cpu_devices(n: int = 8) -> None:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", n)
-    except AttributeError:  # pre-0.4.38 installs: XLA_FLAGS alone carries it
-        pass
+    jax.config.update("jax_num_cpu_devices", n)
 
 
 def changed_files(repo_root: str | None = None,

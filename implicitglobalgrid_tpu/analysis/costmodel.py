@@ -68,7 +68,7 @@ def text_census(txt: str) -> dict:
     "one parser ... cannot drift" contract) — a formatting fix landed
     there must not diverge from the counts this baseline gates on.
     ``kernel_launches`` counts ``custom-call`` instructions (Pallas kernels
-    on a real backend; the generic interpreter lowers kernels to pure HLO,
+    on a real backend; the Pallas interpreter lowers kernels to pure HLO,
     where the fusion count carries the structure instead).
     """
     from ..utils.hlo_analysis import (

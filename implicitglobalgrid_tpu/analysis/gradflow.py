@@ -61,7 +61,7 @@ def _inexact(v) -> bool:
 
 
 def _eqn_location(eqn) -> tuple[str, int]:
-    """Best-effort ``(file, line)`` of one equation (private-API tolerant).
+    """Best-effort ``(file, line)`` of one equation (private API).
 
     Paths under the repo come back REPO-RELATIVE — the fingerprint hashes
     the path, so an absolute checkout prefix would pin baselines (and the
@@ -73,7 +73,7 @@ def _eqn_location(eqn) -> tuple[str, int]:
     try:
         from jax._src import source_info_util
 
-        frame = source_info_util.user_frame(eqn.source_info)
+        frame = source_info_util.user_frame(eqn.source_info.traceback)
         if frame is not None:
             path = str(frame.file_name)
             if os.path.isabs(path) and path.startswith(repo + os.sep):

@@ -12,8 +12,8 @@ config matrix on the 8-device virtual CPU mesh:
   pipeline), coalesce auto/off, on one grid that has BOTH periodic and
   PROC_NULL transports;
 * **cadence entries** — each model's fused `make_multi_step` program,
-  pipelined on/off (the kernels trace through the generic Pallas
-  interpreter, `utils.compat.pallas_force_interpret`).
+  pipelined on/off (the kernels trace under the TPU Pallas
+  interpreter, `pltpu.force_tpu_interpret_mode`).
 
 Everything here is TRACE-only (`jax.make_jaxpr`): no executable is built, no
 device computation runs — which is what makes a full-matrix census cheap
@@ -169,7 +169,7 @@ def unwrap_inner(jaxpr):
         len(inner.eqns) == 1
         and "custom_vjp" in inner.eqns[0].primitive.name
     ):
-        inner = inner.eqns[0].params["fun_jaxpr"].jaxpr
+        inner = inner.eqns[0].params["call_jaxpr"].jaxpr
     return inner
 
 
@@ -234,7 +234,7 @@ def _trace_mapped(body, fields, gg, out_fields=None):
     from jax.sharding import PartitionSpec as P
 
     from .. import AXIS_NAMES, NDIMS
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     def spec(f):
         nbatch = max(f.ndim - NDIMS, 0)
@@ -463,7 +463,7 @@ def _compile_exchange_program(model: str = "porous", n: int = 8) -> CompiledProg
         from jax.sharding import PartitionSpec as P
 
         from .. import AXIS_NAMES
-        from ..utils.compat import shard_map
+        from jax import shard_map
 
         specs = tuple(P(*AXIS_NAMES[: f.ndim]) for f in fields)
         mapped = shard_map(
@@ -517,7 +517,7 @@ def _compile_batched_exchange_program(model: str = "porous", n: int = 8,
             return jax.vmap(single)(*fs)
 
         from ..models._batched import _batched_spec
-        from ..utils.compat import shard_map
+        from jax import shard_map
 
         # THE batched-layout spec (`models._batched`): one definition for
         # the traced census, the serving pool and these compiled programs.
@@ -581,7 +581,7 @@ def _compile_batched_cadence_program(n: int = 8, B: int | None = None,
         )
         gg = igg.get_global_grid()
         from ..models._batched import _batched_spec
-        from ..utils.compat import shard_map
+        from jax import shard_map
 
         spec = _batched_spec(4)  # the one batched-layout definition
         mapped = shard_map(
@@ -647,7 +647,7 @@ def _cadence_setup_kwargs(cfg) -> dict:
 def trace_cadence_entries() -> list:
     """Trace each model's fused multi-step cadence, pipelined on/off.
 
-    Trace-only through the generic Pallas interpreter — no execution.  A
+    Trace-only through the TPU Pallas interpreter — no execution.  A
     pipelined config that falls back to the serialized schedule (warn-once
     path) is recorded with ``admissible=False`` so the overlap analyzer can
     distinguish "no overlap possible" from "overlap lost".
@@ -658,7 +658,8 @@ def trace_cadence_entries() -> list:
     from jax.sharding import PartitionSpec as P
 
     import implicitglobalgrid_tpu as igg
-    from ..utils.compat import pallas_force_interpret, shard_map
+    from jax import shard_map
+    from jax.experimental.pallas.tpu import force_tpu_interpret_mode
 
     entries = []
     for model, cfg in _CADENCES:
@@ -671,7 +672,7 @@ def trace_cadence_entries() -> list:
                     *cfg["nloc"], **_cadence_setup_kwargs(cfg)
                 )
                 admissible = True
-                with pallas_force_interpret():
+                with force_tpu_interpret_mode():
                     with warnings.catch_warnings(record=True) as caught:
                         warnings.simplefilter("always")
                         step = mod.make_multi_step(
@@ -715,7 +716,7 @@ def trace_cadence_entries() -> list:
 
 def _compile_cadence_program(model: str) -> CompiledProgram:
     """Compile one model's fused cadence (pipelined=True, the `_CADENCES`
-    config) through the generic Pallas interpreter — the optimized-HLO view
+    config) through the TPU Pallas interpreter — the optimized-HLO view
     of the production multi-step program the cost model pins.  One XLA:CPU
     build per model, seconds each; `Context` caches the result."""
     import importlib
@@ -724,7 +725,8 @@ def _compile_cadence_program(model: str) -> CompiledProgram:
     from jax.sharding import PartitionSpec as P
 
     import implicitglobalgrid_tpu as igg
-    from ..utils.compat import pallas_force_interpret, shard_map
+    from jax import shard_map
+    from jax.experimental.pallas.tpu import force_tpu_interpret_mode
 
     cfg = dict(_CADENCES)[model]
     mod = importlib.import_module(
@@ -732,7 +734,7 @@ def _compile_cadence_program(model: str) -> CompiledProgram:
     )
     try:
         state, params = mod.setup(*cfg["nloc"], **_cadence_setup_kwargs(cfg))
-        with pallas_force_interpret():
+        with force_tpu_interpret_mode():
             with warnings.catch_warnings():
                 warnings.simplefilter("ignore")
                 step = mod.make_multi_step(
@@ -816,7 +818,8 @@ def trace_grad_entries(n: int = 8) -> list:
 
     import implicitglobalgrid_tpu as igg
     from ..ops import halo
-    from ..utils.compat import pallas_force_interpret, shard_map
+    from jax import shard_map
+    from jax.experimental.pallas.tpu import force_tpu_interpret_mode
 
     entries = []
 
@@ -864,7 +867,7 @@ def trace_grad_entries(n: int = 8) -> list:
             state, params = mod.setup(
                 *cfg["nloc"], **_cadence_setup_kwargs(cfg)
             )
-            with pallas_force_interpret():
+            with force_tpu_interpret_mode():
                 with warnings.catch_warnings():
                     warnings.simplefilter("ignore")
                     step = mod.make_multi_step(
@@ -910,22 +913,11 @@ def trace_grad_entries(n: int = 8) -> list:
 
 
 def is_kernel_eqn(eqn) -> bool:
-    """A Pallas kernel launch: a ``pallas_call`` eqn, or a ``pjit`` whose
-    body is (recursively) just kernel launches — the kernels' cached
-    ``jax.jit(pallas_call)`` builders appear as pjit eqns."""
-    if eqn.primitive.name == "pallas_call":
-        return True
-    if eqn.primitive.name == "pjit":
-        sub = eqn.params.get("jaxpr")
-        if sub is None:
-            return False
-        body = sub.jaxpr if hasattr(sub, "jaxpr") else sub
-        return any(
-            e.primitive.name == "pallas_call"
-            or (e.primitive.name == "pjit" and is_kernel_eqn(e))
-            for e in body.eqns
-        )
-    return False
+    """A Pallas kernel launch: a ``pallas_call`` eqn, or an envelope whose
+    body holds kernel launches and no collectives — the kernels' cached
+    ``jax.jit(pallas_call)`` builders under their per-member
+    ``custom_vmap`` wrapper (`ops._fused_envelope.per_member`)."""
+    return _eqn_presence(eqn) == (True, False)
 
 
 def iter_pallas_calls(jaxpr):

@@ -295,6 +295,7 @@ def gate_summary(candidate_record: dict, repo_root: str, *,
     records, skipped = load_bench_records(repo_root)
     if not records:
         return {"ok": True, "note": "no committed BENCH records to compare",
+                "reported": reported_metrics(candidate_record),
                 "skipped_records": skipped}
     ref_round, ref_rec = records[-1]
     cmp = compare_metrics(

@@ -80,7 +80,7 @@ def _compare_fn(gg, shapes_dtypes):
 
     from ..ops.halo import _flat_words
     from ..parallel.topology import AXIS_NAMES, NDIMS
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     key = (gg.epoch, shapes_dtypes)
     fn = _compare_cache.get(key)

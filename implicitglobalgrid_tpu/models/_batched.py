@@ -88,7 +88,7 @@ def _stack_fn(gg, ndims: tuple[int, ...]):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     key = ("stack", gg.epoch, tuple(ndims))
     fn = _jit_cache.get(key)
@@ -142,7 +142,7 @@ def _member_fn(gg, ndim: int):
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     key = ("member", gg.epoch, ndim)
     fn = _jit_cache.get(key)
@@ -193,7 +193,7 @@ def _set_member_fn(gg, ndim: int):
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     key = ("set_member", gg.epoch, ndim)
     fn = _jit_cache.get(key)
@@ -244,7 +244,7 @@ def _member_finite_fn(gg, sig):
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     key = ("finite", gg.epoch, sig)
     fn = _jit_cache.get(key)
@@ -323,7 +323,7 @@ def _select_fn(gg, sig):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     key = ("select", gg.epoch, sig)
     fn = _jit_cache.get(key)

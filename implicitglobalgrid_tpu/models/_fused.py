@@ -84,7 +84,7 @@ def run_pipelined_group_schedule(chunks, boundary, interior, carry, *,
     excess) is `run_group_schedule`'s.
     """
 
-    from ..utils.compat import named_scope
+    from jax import named_scope
 
     def group(ki, c):
         # Named profiler scopes (docs/observability.md): the ring pass (and

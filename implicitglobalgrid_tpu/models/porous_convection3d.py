@@ -1029,7 +1029,7 @@ def make_batched_residual(params: Params):
 
     from ..parallel.grid import global_grid
     from ..parallel.topology import AXIS_NAMES
-    from ..utils.compat import shard_map
+    from jax import shard_map
     from ._batched import _batched_spec
 
     block = _pt_residual_block(params)

@@ -254,3 +254,32 @@ def support_error(shape, k, itemsize, bx, by, *, tile_error, candidates):
     if bx is None or by is None:
         return "pass both bx and by, or neither"
     return tile_error(n0, n1, n2, k, bx, by, itemsize)
+
+
+def per_member(launch):
+    """A kernel launch that `jax.vmap` runs once per ensemble member.
+
+    The kernels address HBM through manual DMAs on ``memory_space=ANY``
+    refs.  The `pallas_call` batching rule hands such a kernel the batched
+    buffer while its DMA indices stay per-member, so a vmapped launch would
+    read the wrong member or out of bounds.  Under vmap this wrapper runs
+    the launch for each member in a `lax.map` instead: every member
+    advances exactly as its own call (`models._batched`).  Unbatched calls
+    run ``launch`` unchanged.
+    """
+    import jax
+    import jax.numpy as jnp
+    from jax import custom_batching, lax
+
+    f = custom_batching.custom_vmap(launch)
+
+    @f.def_vmap
+    def _rule(axis_size, in_batched, *args):
+        args = tuple(
+            a if b else jnp.broadcast_to(a, (axis_size, *jnp.shape(a)))
+            for a, b in zip(args, in_batched)
+        )
+        out = lax.map(lambda xs: f(*xs), args)
+        return out, jax.tree.map(lambda _: True, out)
+
+    return f

@@ -134,7 +134,7 @@ def _block_fetch_fn(gg, ndim: int, block_shape, dtype, nsel: int = 1):
             out = lax.complex(out[..., 0], out[..., 1]).astype(jnp.dtype(dtype))
         return out
 
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     mapped = shard_map(
         local,

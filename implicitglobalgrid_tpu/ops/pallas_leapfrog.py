@@ -314,15 +314,12 @@ def fused_leapfrog_steps(P, Vxp, Vyp, Vzp, k: int,
     carry_in = _envelope.check_tile_subset(
         tile_sel, carry_in, (n0, n1), (bx, by), nouts=7 if z_export else 4
     )
-    from ..utils.compat import pallas_interpret_active
-
     fn = _build(n0, n1, n2, str(P.dtype), int(k),
                 float(cax), float(cay), float(caz),
                 float(b), float(idx), float(idy), float(idz),
                 int(bx), int(by), zp,
                 bool(z_export), int(z_overlap) if z_export else 0,
-                str(tile_sel), carry_in is not None,
-                pallas_interpret_active())
+                str(tile_sel), carry_in is not None)
     args = (P, Vxp, Vyp, Vzp) + (tuple(z_patches) if zp else ())
     if carry_in is not None:
         args += tuple(carry_in)
@@ -332,13 +329,12 @@ def fused_leapfrog_steps(P, Vxp, Vyp, Vzp, k: int,
 @functools.lru_cache(maxsize=64)
 def _build(n0, n1, n2, dtype, k, cax, cay, caz, b, idx, idy, idz, bx, by,
            zp: bool = False, zx: bool = False, o: int = 0,
-           tile_sel: str = "all", carry: bool = False, interp: bool = False):
+           tile_sel: str = "all", carry: bool = False):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from ..utils.compat import pallas_compiler_params
     from .overlap import tile_subset_count, tile_subset_map
 
     H = _envelope.aligned_halo(k)
@@ -706,9 +702,8 @@ def _build(n0, n1, n2, dtype, k, cax, cay, caz, b, idx, idy, idz, bx, by,
         input_output_aliases=(
             {nbase + j: j for j in range(nouts)} if carry else {}
         ),
-        interpret=interp,
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_envelope.vmem_limit(vmem_bytes)
         ),
     )
-    return jax.jit(call)
+    return _envelope.per_member(jax.jit(call))

@@ -215,15 +215,12 @@ def fused_pt_iterations(T, Pf, qxp, qyp, qzp, k: int,
     carry_in = _envelope.check_tile_subset(
         tile_sel, carry_in, (n0, n1), (bx, by), nouts=7 if z_export else 4
     )
-    from ..utils.compat import pallas_interpret_active
-
     fn = _build(n0, n1, n2, str(Pf.dtype), int(k),
                 float(th), float(idx), float(idy), float(idz),
                 float(ralam), float(bp), int(bx), int(by), zp,
                 bool(z_export), int(z_overlap) if z_export else 0,
                 wp if zp else 0, we if z_export else 0,
-                str(tile_sel), carry_in is not None,
-                pallas_interpret_active())
+                str(tile_sel), carry_in is not None)
     args = (T, Pf, qxp, qyp, qzp) + (tuple(z_patches) if zp else ())
     if carry_in is not None:
         args += tuple(carry_in)
@@ -234,13 +231,12 @@ def fused_pt_iterations(T, Pf, qxp, qyp, qzp, k: int,
 def _build(n0, n1, n2, dtype, k, th, idx, idy, idz, ralam, bp, bx, by,
            zp: bool = False, zx: bool = False, o: int = 0,
            wp: int = 0, we: int = 0,
-           tile_sel: str = "all", carry: bool = False, interp: bool = False):
+           tile_sel: str = "all", carry: bool = False):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from ..utils.compat import pallas_compiler_params
     from .overlap import tile_subset_count, tile_subset_map
 
     H = _envelope.aligned_halo(k)
@@ -600,9 +596,8 @@ def _build(n0, n1, n2, dtype, k, th, idx, idy, idz, ralam, bp, bx, by,
         input_output_aliases=(
             {nbase + j: j for j in range(nouts)} if carry else {}
         ),
-        interpret=interp,
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_envelope.vmem_limit(vmem_bytes)
         ),
     )
-    return jax.jit(call)
+    return _envelope.per_member(jax.jit(call))

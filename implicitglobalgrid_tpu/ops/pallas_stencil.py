@@ -323,13 +323,10 @@ def fused_diffusion_steps(T, Cp, k: int, cx: float, cy: float, cz: float,
         tile_sel, carry_in, (n0, n1), (bx, by),
         nouts=2 if z_export else 1,
     )
-    from ..utils.compat import pallas_interpret_active
-
     fn = _build(n0, n1, n2, str(T.dtype), int(k),
                 float(cx), float(cy), float(cz), int(bx), int(by), zp,
                 bool(z_export), int(z_overlap) if z_export else 0,
-                str(tile_sel), carry_in is not None,
-                pallas_interpret_active())
+                str(tile_sel), carry_in is not None)
     args = (T, Cp, z_patch) if zp else (T, Cp)
     if carry_in is not None:
         args += tuple(carry_in)
@@ -339,13 +336,12 @@ def fused_diffusion_steps(T, Cp, k: int, cx: float, cy: float, cz: float,
 @functools.lru_cache(maxsize=64)
 def _build(n0, n1, n2, dtype, k, cx, cy, cz, bx, by, zp: bool = False,
            zx: bool = False, o: int = 0, tile_sel: str = "all",
-           carry: bool = False, interp: bool = False):
+           carry: bool = False):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from ..utils.compat import pallas_compiler_params
     from .overlap import tile_subset_count, tile_subset_map
 
     # Full-y mode (by == n1): the window spans all of y with no y halo (the
@@ -639,9 +635,8 @@ def _build(n0, n1, n2, dtype, k, cx, cy, cz, bx, by, zp: bool = False,
             if zx else pl.BlockSpec(memory_space=pl.ANY)
         ),
         input_output_aliases=aliases if carry else {},
-        interpret=interp,
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_envelope.vmem_limit(2 * vmem_bytes)
         ),
     )
-    return jax.jit(call)
+    return _envelope.per_member(jax.jit(call))

@@ -78,6 +78,16 @@ class _Stencil:
         _instances.add(self)
 
     def __call__(self, *args):
+        return self._jitted(args)(*args)
+
+    def lower(self, *args):
+        """Ahead-of-time lowering for ``args`` (arrays or shape structs), as
+        `jax.jit(...).lower`: the program a call with these arguments runs.
+        ``.compile()`` on the result also fills the persistent compile cache
+        that such a call reads."""
+        return self._jitted(args).lower(*args)
+
+    def _jitted(self, args):
         import jax
 
         _grid.check_initialized()
@@ -92,7 +102,7 @@ class _Stencil:
         if compiled is None:
             compiled = self._build(gg, args, treedef)
             self._cache[sig] = compiled
-        return compiled(*args)
+        return compiled
 
     def _build(self, gg, args, treedef):
         import jax
@@ -120,7 +130,7 @@ class _Stencil:
         else:
             out_specs = self._infer_out_specs(gg, in_specs, args)
 
-        from ..utils.compat import shard_map
+        from jax import shard_map
 
         mapped = shard_map(
             self._fn,
@@ -152,7 +162,7 @@ class _Stencil:
         import jax
         from jax.sharding import PartitionSpec as P
 
-        from ..utils.compat import shard_map
+        from jax import shard_map
 
         probe = shard_map(
             self._fn,

@@ -421,7 +421,7 @@ def _barrier() -> None:
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     gg = global_grid()
     if _barrier_fn is None or _barrier_fn[0] is not gg.mesh:

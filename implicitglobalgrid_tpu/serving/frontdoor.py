@@ -178,7 +178,7 @@ def _bcast_fn(gg, n: int):
     from jax.sharding import PartitionSpec as P
 
     from ..parallel.topology import AXIS_NAMES
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     def per_block(x):
         return lax.pmax(x, AXIS_NAMES)

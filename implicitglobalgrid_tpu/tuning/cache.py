@@ -8,7 +8,7 @@ later lookup).  A cache hit is ZERO search cost: no candidate is measured,
 no compile beyond the production program itself.
 
 Layers: lookups read the PRIMARY directory (``IGG_TUNE_CACHE`` env, else
-``~/.cache/implicitglobalgrid_tpu/tune``) first and fall back to the
+``.igg_tune`` in the checkout) first and fall back to the
 committed SEED layer (`SEED_DIR`, shipped in the package) — chip-measured
 winners ingested from the ``BENCH_r*.json`` trajectory by ``igg_tune.py
 seed``, so environments that cannot re-measure (no chip, CI) still apply
@@ -60,15 +60,10 @@ def schedule_class(model: str, nsteps: int | None) -> str:
 
 
 def default_cache_dir() -> str:
-    """``IGG_TUNE_CACHE`` env, else the per-user cache directory."""
-    from ..utils.config import tune_cache_env
+    """``IGG_TUNE_CACHE`` env, else ``.igg_tune`` in the checkout."""
+    from ..utils.config import CHECKOUT_ROOT, tune_cache_env
 
-    env = tune_cache_env()
-    if env:
-        return env
-    return os.path.join(
-        os.path.expanduser("~"), ".cache", "implicitglobalgrid_tpu", "tune"
-    )
+    return tune_cache_env() or os.path.join(CHECKOUT_ROOT, ".igg_tune")
 
 
 def topology_string(gg) -> str:

@@ -285,8 +285,8 @@ The *mechanism* carries over with the TPU-meaningful knobs:
                           a pure substitution of existing kwargs, resolved
                           host-side before tracing (docs/performance.md)
 ``IGG_TUNE_CACHE``        primary directory of the autotuner's on-disk
-                          winner table (unset = ``~/.cache/
-                          implicitglobalgrid_tpu/tune``); the committed
+                          winner table (unset = ``.igg_tune`` in the
+                          checkout, `CHECKOUT_ROOT`); the committed
                           seed layer ``tuning/entries`` is always the
                           read-only fallback — read per resolve
 ``IGG_TUNE_TOPK``         total candidates measured per search (int >= 1,
@@ -302,11 +302,46 @@ The *mechanism* carries over with the TPU-meaningful knobs:
 Explicit kwargs always win over env values; env values win over built-in
 defaults — the reference's precedence.  The resilience knobs are read per
 call (like ``IGG_DONATE``), not snapshotted at init.
+
+JAX's own ``JAX_COMPILATION_CACHE_DIR`` places the persistent compile cache
+that entry points turn on with `enable_compile_cache`.
 """
 
 from __future__ import annotations
 
 import os
+
+#: The checkout this package runs from.  Every default path the program
+#: writes lies under it (compile cache, tuner winners), never under
+#: ``HOME`` or a temp dir.
+CHECKOUT_ROOT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
+
+
+def compile_cache_dir() -> str:
+    """Where `enable_compile_cache` keeps compiled programs:
+    ``JAX_COMPILATION_CACHE_DIR`` when set, else the fixed ``.jax_cache``
+    in the checkout (a fixed path, so the next run in this checkout finds
+    what this one compiled)."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+        CHECKOUT_ROOT, ".jax_cache"
+    )
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Called by entry points (``chip_smoke.py``, ``bench.py``, the examples),
+    never at library import.  A set ``JAX_COMPILATION_CACHE_DIR`` is JAX's
+    own default for the directory and is left alone.
+    """
+    import jax
+
+    path = compile_cache_dir()
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 #: Valid values for ``IGG_GUARD_POLICY`` / the models' ``guard_policy``.
 GUARD_POLICIES = ("raise", "warn", "rollback")
@@ -807,7 +842,7 @@ def autotune_env() -> bool | None:
 
 def tune_cache_env() -> str | None:
     """``IGG_TUNE_CACHE``: primary winner-table directory (unset = the
-    per-user default, `tuning.cache.default_cache_dir`)."""
+    in-checkout default, `tuning.cache.default_cache_dir`)."""
     val = os.environ.get("IGG_TUNE_CACHE")
     return val or None
 

@@ -117,7 +117,7 @@ def from_block_fn(fn, local_shape, dtype=None):
             per_block, out_shardings=SingleDeviceSharding(gg.mesh.devices.flat[0])
         )()
 
-    from .compat import shard_map
+    from jax import shard_map
 
     mapped = shard_map(
         per_block,
@@ -198,7 +198,7 @@ def block_slice(A, slices):
             per_block, out_shardings=SingleDeviceSharding(gg.mesh.devices.flat[0])
         )(A)
 
-    from .compat import shard_map
+    from jax import shard_map
 
     spec = P(*AXIS_NAMES[:nd])
     mapped = shard_map(

@@ -222,6 +222,13 @@ COLLECTIVE_OP_TOKENS = (
     "all-to-all",
     "reduce-scatter",
     "collective-broadcast",
+    # Instruction names XLA derives from the JAX primitive (the profiler's
+    # op names on the CPU backend: ``ppermute.18``).
+    "ppermute",
+    "psum",
+    "all_gather",
+    "all_to_all",
+    "reduce_scatter",
 )
 
 KERNEL_OP_TOKENS = ("fusion", "custom-call", "convolution", "dot")

@@ -354,7 +354,7 @@ def _probe_fn(gg, shapes_dtypes):
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from .compat import shard_map
+    from jax import shard_map
 
     key = (gg.epoch, shapes_dtypes)
     fn = _probe_cache.get(key)
@@ -1347,7 +1347,7 @@ def _guarded_loop_body(step_fn, state, nt, it, guard, enabled,
                        sync_every_step, model, tele) -> tuple:
     import jax
 
-    from .compat import trace_annotation
+    from jax.profiler import TraceAnnotation
 
     while it < nt:
         # The ``igg.step`` host span (docs/observability.md): one span per
@@ -1360,7 +1360,7 @@ def _guarded_loop_body(step_fn, state, nt, it, guard, enabled,
             span = ann = _NULL_CM
         else:
             span = _tracing.trace_span("igg.step", model=model, step=it + 1)
-            ann = trace_annotation(f"igg_step[{model}]")
+            ann = TraceAnnotation(f"igg_step[{model}]")
         with span:
             # Shadow-audit retention (docs/robustness.md): off-cadence steps
             # pay one `is not None`-style check; on-cadence steps snapshot

@@ -13,7 +13,7 @@ cross-rank half (docs/observability.md):
   per-process ring buffer (``IGG_TRACE_RING``, default `RING_DEFAULT`).
   Span names reuse the compiled-HLO annotation names where one exists
   (``igg_halo_exchange``, ``igg_slab_exchange_begin`` ... — see
-  `utils.compat.named_scope`), so a host span and the device ops it
+  `jax.named_scope`), so a host span and the device ops it
   dispatched correlate BY NAME across a merged trace and a profiler
   capture.  With ``IGG_TELEMETRY=0`` (or ``IGG_TRACE_RING=0``) every call
   returns one shared no-op singleton — no allocation, no clock reads.
@@ -1464,7 +1464,7 @@ def _skew_fn(gg):
     from jax.sharding import PartitionSpec as P
 
     from ..parallel.topology import AXIS_NAMES, NDIMS
-    from .compat import shard_map
+    from jax import shard_map
 
     key = gg.epoch
     fn = _skew_cache.get(key)

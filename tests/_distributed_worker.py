@@ -46,13 +46,6 @@ import os
 # replaces this timer once the package is importable).
 faulthandler.dump_traceback_later(270, exit=True)
 
-# Fresh process: stage the virtual-device count before jax import so older
-# JAX versions (no jax_num_cpu_devices config option) honor it too.
-os.environ["XLA_FLAGS"] = (
-    os.environ.get("XLA_FLAGS", "")
-    + f" --xla_force_host_platform_device_count={LOCAL_DEVICES}"
-).strip()
-
 # Telemetry armed for the whole worker run (docs/observability.md): both
 # ranks log into one shared directory; the parent test asserts per-rank
 # event files with consistent rank/coords tags.
@@ -62,10 +55,7 @@ os.environ["IGG_TELEMETRY_DIR"] = out_path + ".telemetry"
 import jax
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", LOCAL_DEVICES)
-except AttributeError:
-    pass
+jax.config.update("jax_num_cpu_devices", LOCAL_DEVICES)
 jax.config.update("jax_enable_x64", True)
 jax.config.update("jax_cpu_collectives_implementation", "gloo")
 

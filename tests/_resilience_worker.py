@@ -37,15 +37,10 @@ mode = sys.argv[4]
 ckptdir = sys.argv[5]
 out_path = sys.argv[6]
 
-# Fresh process: stage the virtual-device count before jax import (older JAX
-# has no jax_num_cpu_devices config option).
-os.environ["XLA_FLAGS"] = (
-    os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=1"
-).strip()
-
 import jax
 
 jax.config.update("jax_platforms", "cpu")
+jax.config.update("jax_num_cpu_devices", 1)
 jax.config.update("jax_enable_x64", True)
 jax.config.update("jax_cpu_collectives_implementation", "gloo")
 

@@ -96,7 +96,7 @@ def test_grad_through_fused_diffusion_multi_step():
     Pallas chunk has no VJP, so `fused_with_xla_grad` runs the kernel in the
     primal and differentiates the XLA-cadence twin in the backward pass —
     the gradient must match the XLA cadence's gradient to float rounding."""
-    from implicitglobalgrid_tpu.utils.compat import pallas_force_interpret
+    from jax.experimental.pallas.tpu import force_tpu_interpret_mode
     from implicitglobalgrid_tpu.ops.pallas_stencil import fused_support_error
 
     nloc = (16, 32, 128)
@@ -110,7 +110,7 @@ def test_grad_through_fused_diffusion_multi_step():
     state, params = diffusion3d.setup(*nloc, **kw)
     T, Cp = state
 
-    with pallas_force_interpret():
+    with force_tpu_interpret_mode():
         fused = diffusion3d.make_multi_step(
             params, 2, donate=False, fused_k=2, fused_tile=(8, 16)
         )
@@ -137,7 +137,7 @@ def test_grad_through_fused_diffusion_multi_step():
 
 def test_grad_through_fused_staggered_multi_step():
     """Same custom-VJP story for a staggered fused chunk (acoustic)."""
-    from implicitglobalgrid_tpu.utils.compat import pallas_force_interpret
+    from jax.experimental.pallas.tpu import force_tpu_interpret_mode
     from implicitglobalgrid_tpu.ops.pallas_leapfrog import fused_support_error
 
     nloc = (16, 32, 128)
@@ -149,7 +149,7 @@ def test_grad_through_fused_staggered_multi_step():
     state, params = acoustic3d.setup(*nloc, **kw)
     P, Vx, Vy, Vz = state
 
-    with pallas_force_interpret():
+    with force_tpu_interpret_mode():
         fused = acoustic3d.make_multi_step(
             params, 2, donate=False, fused_k=2, fused_tile=(8, 16)
         )

@@ -103,7 +103,7 @@ def test_batched_fused_cadence_matches_independent(name):
     """The fused Pallas chunks under vmap (interpret mode): the
     pallas_call batching rule must advance every member exactly as its
     own call — all three kernel families (stencil, leapfrog, PT)."""
-    from implicitglobalgrid_tpu.utils.compat import pallas_force_interpret
+    from jax.experimental.pallas.tpu import force_tpu_interpret_mode
 
     model, nf, extra = MODELS[name]
     if name == "porous":
@@ -118,7 +118,7 @@ def test_batched_fused_cadence_matches_independent(name):
         for s in (1.0, 1.25)
     ]
     bstate = _batched.stack_states(singles)
-    with pallas_force_interpret(), warnings.catch_warnings():
+    with force_tpu_interpret_mode(), warnings.catch_warnings():
         warnings.simplefilter("ignore", RuntimeWarning)
         step1 = model.make_multi_step(params, k, donate=False, fused_k=k)
         stepB = model.make_multi_step(params, k, donate=False, fused_k=k,
@@ -132,7 +132,7 @@ def test_batched_step_collective_count_is_b_invariant():
     """The traced per-step model program emits the SAME ppermute count
     batched and unbatched (the model-level twin of the budget census)."""
     from implicitglobalgrid_tpu.analysis.budget import _count_ppermutes
-    from implicitglobalgrid_tpu.utils.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = 8

@@ -12,6 +12,7 @@ import importlib.util
 import os
 
 import numpy as np
+import pytest
 
 _root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _spec = importlib.util.spec_from_file_location(
@@ -60,7 +61,8 @@ def test_bench_porous_smoke():
 def test_bench_entrypoint_contract(monkeypatch, capsys):
     """bench.py must print exactly ONE valid JSON line with the driver's
     required keys, pick the faster production path as the headline, and
-    isolate a failing extra without losing the rest."""
+    isolate a failing extra without losing the rest — then exit non-zero
+    naming it."""
     import importlib.util
     import json
 
@@ -100,7 +102,15 @@ def test_bench_entrypoint_contract(monkeypatch, capsys):
         bm._bench, "_time_steps", lambda step, state, chunk, reps: (1e-3, state, 0.0)
     )
     monkeypatch.setattr(
-        bm._bench, "aot_weak_proxy", lambda emit=False: {"stub": True}
+        bm._bench, "aot_weak_proxy",
+        lambda emit=False, pipelined=None: {"stub": True},
+    )
+    # the grad record runs the fused cadence at 256^3 on one chip; the
+    # 8-device test mesh has no deep halo for it
+    monkeypatch.setattr(
+        bm._bench, "bench_diffusion_grad",
+        lambda **kw: {"value": 1.0, "t_it_ms": 2.0, "t_fwd_ms": 1.0,
+                      "grad_over_fwd": 2.0},
     )
     # the front-door record drives a real serving pool + HTTP round trip
     # (covered by tests/test_frontdoor.py) — stub it for the contract test
@@ -120,7 +130,8 @@ def test_bench_entrypoint_contract(monkeypatch, capsys):
             stderr="",
         ),
     )
-    bm.main()
+    with pytest.raises(SystemExit, match="diffusion_512_pallas_fused4"):
+        bm.main()
     out = capsys.readouterr().out.strip().splitlines()
     assert len(out) == 1, f"expected ONE JSON line, got {len(out)}"
     rec = json.loads(out[0])

@@ -232,7 +232,7 @@ def _traced_ppermutes(build, args):
 
     from jax.sharding import PartitionSpec as P
 
-    from implicitglobalgrid_tpu.utils.compat import shard_map
+    from jax import shard_map
 
     _here = os.path.dirname(os.path.abspath(__file__))
     spec = importlib.util.spec_from_file_location(

@@ -62,3 +62,34 @@ def test_profile_trace(tmp_path):
         T = igg.update_halo(T)
     assert any((tmp_path / "trace").rglob("*"))  # trace files written
     igg.finalize_global_grid()
+
+
+@pytest.mark.parametrize("env", [None, "", "/elsewhere/jax-cache"])
+def test_compile_cache_dir_env_wins_else_fixed_checkout_path(monkeypatch, env):
+    """``JAX_COMPILATION_CACHE_DIR`` wins when set; otherwise the cache sits
+    at one fixed path inside the checkout (never a temp, pid or time name),
+    so the next run in the same checkout finds it.  Path choice only: the
+    cache is not switched on here."""
+    from implicitglobalgrid_tpu.utils import config
+
+    if env is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+    got = config.compile_cache_dir()
+    if env:
+        assert got == env
+    else:
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert got == os.path.join(repo, ".jax_cache")
+        assert got == config.compile_cache_dir()  # stable across calls
+
+
+def test_tune_cache_default_is_inside_the_checkout(monkeypatch):
+    from implicitglobalgrid_tpu.tuning.cache import default_cache_dir
+    from implicitglobalgrid_tpu.utils.config import CHECKOUT_ROOT
+
+    monkeypatch.delenv("IGG_TUNE_CACHE", raising=False)
+    assert default_cache_dir() == os.path.join(CHECKOUT_ROOT, ".igg_tune")
+    monkeypatch.setenv("IGG_TUNE_CACHE", "/x/tune")
+    assert default_cache_dir() == "/x/tune"

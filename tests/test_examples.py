@@ -71,14 +71,14 @@ def test_tpu_onlyvis_recipe_runs():
 def test_tpu_fused_runs():
     # The deep-halo temporal-blocking example on the virtual mesh (interpret-
     # mode kernel; overlap=2k licenses fused_k=k on the communicating grid).
-    from implicitglobalgrid_tpu.utils.compat import pallas_force_interpret
+    from jax.experimental.pallas.tpu import force_tpu_interpret_mode
 
     import implicitglobalgrid_tpu as igg
 
     import jax
 
     mod = _load("diffusion3d_tpu_fused")
-    with pallas_force_interpret():
+    with force_tpu_interpret_mode():
         T = mod.diffusion3d_fused(
             nx=32, nt=4, k=2, quiet=True,
             devices=jax.devices()[:2], dimx=2, dimy=1, dimz=1,
@@ -93,7 +93,7 @@ def test_tpu_zsplit_fused_runs():
     # The round-4 z-split production example: 2 devices are forced onto
     # dimz=2, so the in-kernel z-slab apply + export cadence is the
     # exercised path (interpret-mode kernel).
-    from implicitglobalgrid_tpu.utils.compat import pallas_force_interpret
+    from jax.experimental.pallas.tpu import force_tpu_interpret_mode
 
     import jax
 
@@ -105,7 +105,7 @@ def test_tpu_zsplit_fused_runs():
     # runs the real z-patch cadence, not the warn-once XLA fallback.
     assert fused_support_error((16, 32, 128), 2, 4, zpatch=True) is None
     mod = _load("diffusion3d_tpu_zsplit_fused")
-    with pallas_force_interpret():
+    with force_tpu_interpret_mode():
         T = mod.diffusion3d_zsplit(
             nx=16, ny=32, nz=128, nt=4, k=2, quiet=True,
             devices=jax.devices()[:2],
@@ -119,7 +119,7 @@ def test_acoustic_fused_runs():
     # The staggered fused example on the virtual mesh (interpret-mode
     # kernel; per-block (16, 32, 128) fits the (8, 16) tile envelope at
     # k=2 — the nx=256 k=6 production default is a hardware config).
-    from implicitglobalgrid_tpu.utils.compat import pallas_force_interpret
+    from jax.experimental.pallas.tpu import force_tpu_interpret_mode
 
     import jax
     import numpy as np
@@ -127,7 +127,7 @@ def test_acoustic_fused_runs():
     import implicitglobalgrid_tpu as igg
 
     mod = _load("acoustic3d_tpu_fused")
-    with pallas_force_interpret():
+    with force_tpu_interpret_mode():
         P = mod.acoustic3d_fused(
             nx=16, ny=32, nz=128, nt=4, k=2, fused_tile=(8, 16), quiet=True,
             devices=jax.devices()[:2], dimx=2, dimy=1, dimz=1,
@@ -139,7 +139,7 @@ def test_acoustic_fused_runs():
 def test_porous_fused_runs():
     # The flagship's fused production example on the virtual mesh
     # (interpret-mode kernel; per-block (16, 32, 128) fits (8, 16) at w=2).
-    from implicitglobalgrid_tpu.utils.compat import pallas_force_interpret
+    from jax.experimental.pallas.tpu import force_tpu_interpret_mode
 
     import jax
     import numpy as np
@@ -147,7 +147,7 @@ def test_porous_fused_runs():
     import implicitglobalgrid_tpu as igg
 
     mod = _load("porous_convection3d_tpu_fused")
-    with pallas_force_interpret():
+    with force_tpu_interpret_mode():
         T = mod.porous_convection3d_fused(
             nx=16, ny=32, nz=128, nt=2, w=2, npt=4, fused_tile=(8, 16),
             quiet=True, devices=jax.devices()[:2], dimx=2, dimy=1, dimz=1,

@@ -45,7 +45,7 @@ from implicitglobalgrid_tpu.ops import halo as halo_mod
 from implicitglobalgrid_tpu.utils import checkpoint as ck
 from implicitglobalgrid_tpu.utils import resilience
 from implicitglobalgrid_tpu.utils import telemetry as tele
-from implicitglobalgrid_tpu.utils.compat import pallas_force_interpret
+from jax.experimental.pallas.tpu import force_tpu_interpret_mode
 from implicitglobalgrid_tpu.utils.resilience import (
     FaultInjector,
     RunGuard,
@@ -208,7 +208,7 @@ def test_shadow_audit_healthy_bit_identical(name, names, extra, pipelined):
     base = _counter("integrity.audits")
     with warnings.catch_warnings():
         warnings.simplefilter("ignore", RuntimeWarning)
-        with pallas_force_interpret():
+        with force_tpu_interpret_mode():
             step = model.make_multi_step(
                 params, 2, donate=False, fused_k=2, fused_tile=(8, 16),
                 pipelined=pipelined,

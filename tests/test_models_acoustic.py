@@ -144,7 +144,7 @@ def test_fused_single_device_matches_xla():
     """fused_k on a no-halo-activity grid (1 device): the padded-layout
     staggered kernel chunk must match the per-step XLA path to few f32 ULPs
     (interpret-mode kernel)."""
-    from implicitglobalgrid_tpu.utils.compat import pallas_force_interpret
+    from jax.experimental.pallas.tpu import force_tpu_interpret_mode
 
     nt = 4
     # dtype pinned: the suite runs x64, and f64 is outside the kernel
@@ -157,7 +157,7 @@ def test_fused_single_device_matches_xla():
     igg.finalize_global_grid()
 
     state, params = acoustic3d.setup(16, 32, 128, **kw)
-    with pallas_force_interpret():
+    with force_tpu_interpret_mode():
         stepf = acoustic3d.make_multi_step(
             params, nt, donate=False, fused_k=2, fused_tile=(8, 16)
         )
@@ -174,7 +174,7 @@ def test_fused_deep_halo_matches_xla_multiblock():
 
     2 devices deliberately — the interpret-mode Pallas + shard_map deadlock
     constraint probed for the diffusion kernel applies here too."""
-    from implicitglobalgrid_tpu.utils.compat import pallas_force_interpret
+    from jax.experimental.pallas.tpu import force_tpu_interpret_mode
 
     nt = 4
     kw = dict(
@@ -187,7 +187,7 @@ def test_fused_deep_halo_matches_xla_multiblock():
     igg.finalize_global_grid()
 
     state, params = acoustic3d.setup(16, 32, 128, **kw)
-    with pallas_force_interpret():
+    with force_tpu_interpret_mode():
         stepf = acoustic3d.make_multi_step(
             params, nt, donate=False, fused_k=2, fused_tile=(8, 16)
         )
@@ -245,7 +245,7 @@ def test_fused_zpatch_deep_halo_z_split_matches_xla():
     """The in-kernel z-slab cadence (z-dim decomposition): k fused kernel
     steps with VMEM-applied z patches + outside x/y exchange vs the
     per-step path (interpret-mode kernel, 2 devices split along z)."""
-    from implicitglobalgrid_tpu.utils.compat import pallas_force_interpret
+    from jax.experimental.pallas.tpu import force_tpu_interpret_mode
 
     nt = 4
     kw = dict(
@@ -258,7 +258,7 @@ def test_fused_zpatch_deep_halo_z_split_matches_xla():
     igg.finalize_global_grid()
 
     state, params = acoustic3d.setup(16, 32, 128, **kw)
-    with pallas_force_interpret():
+    with force_tpu_interpret_mode():
         stepf = acoustic3d.make_multi_step(
             params, nt, donate=False, fused_k=2, fused_tile=(8, 16)
         )
@@ -272,7 +272,7 @@ def test_fused_zpatch_periodic_z_matches_xla():
     """Same cadence on the periodic self-neighbor z config (1 device,
     z-activity via the wrap — the degenerate config the hardware bench
     uses)."""
-    from implicitglobalgrid_tpu.utils.compat import pallas_force_interpret
+    from jax.experimental.pallas.tpu import force_tpu_interpret_mode
 
     nt = 4
     kw = dict(
@@ -285,7 +285,7 @@ def test_fused_zpatch_periodic_z_matches_xla():
     igg.finalize_global_grid()
 
     state, params = acoustic3d.setup(16, 32, 128, **kw)
-    with pallas_force_interpret():
+    with force_tpu_interpret_mode():
         stepf = acoustic3d.make_multi_step(
             params, nt, donate=False, fused_k=2, fused_tile=(8, 16)
         )

@@ -136,7 +136,7 @@ def test_fused_deep_halo_matches_xla_multiblock():
     rendezvous involved — probed at 4 and 8 virtual devices; the compiled
     kernel + slab path is validated on hardware and the slab exchange alone
     on 8 devices in test_update_halo)."""
-    from implicitglobalgrid_tpu.utils.compat import pallas_force_interpret
+    from jax.experimental.pallas.tpu import force_tpu_interpret_mode
 
     nt = 4
     kw = dict(
@@ -150,7 +150,7 @@ def test_fused_deep_halo_matches_xla_multiblock():
     igg.finalize_global_grid()
 
     state, params = diffusion3d.setup(16, 32, 128, **kw)
-    with pallas_force_interpret():
+    with force_tpu_interpret_mode():
         stepf = diffusion3d.make_multi_step(params, nt, donate=False, fused_k=2)
         state = jax.block_until_ready(stepf(*state))
     T_fused = np.asarray(igg.gather(state[0]))
@@ -282,7 +282,7 @@ def test_fused_zpatch_random_topology_invariance(seed):
     and the random draws cover local shape, tile, and step count instead;
     dims_z=2 keeps the in-kernel z-slab machinery on the exercised path in
     every draw."""
-    from implicitglobalgrid_tpu.utils.compat import pallas_force_interpret
+    from jax.experimental.pallas.tpu import force_tpu_interpret_mode
 
     rng = np.random.default_rng(7100 + seed)
     dims = (1, 1, 2)
@@ -323,7 +323,7 @@ def test_fused_zpatch_random_topology_invariance(seed):
         dtype=jax.numpy.float32,
     )
     state, params = diffusion3d.setup(*nloc, **kw)
-    with pallas_force_interpret():
+    with force_tpu_interpret_mode():
         step = diffusion3d.make_multi_step(
             params, nt, donate=False, fused_k=k, fused_tile=tile
         )
@@ -348,7 +348,7 @@ def test_fused_zpatch_random_topology_invariance(seed):
 def test_fused_zpatch_deep_halo_z_split_matches_xla():
     """The in-kernel z-slab diffusion cadence (z-dim decomposition) vs the
     per-step path (interpret-mode kernel, 2 devices split along z)."""
-    from implicitglobalgrid_tpu.utils.compat import pallas_force_interpret
+    from jax.experimental.pallas.tpu import force_tpu_interpret_mode
 
     nt = 4
     kw = dict(
@@ -361,7 +361,7 @@ def test_fused_zpatch_deep_halo_z_split_matches_xla():
     igg.finalize_global_grid()
 
     state, params = diffusion3d.setup(16, 32, 128, **kw)
-    with pallas_force_interpret():
+    with force_tpu_interpret_mode():
         stepf = diffusion3d.make_multi_step(params, nt, donate=False, fused_k=2)
         T_got = np.asarray(igg.gather(jax.block_until_ready(stepf(*state))[0]))
     igg.finalize_global_grid()
@@ -372,7 +372,7 @@ def test_fused_zpatch_periodic_z_multiblock_matches_xla():
     """Periodic z with dims_z=2: the packed exports communicate via the
     wrap ppermute (neither the self-neighbor fast path nor the PROC_NULL
     masking — the third topology of `z_patch_from_export`)."""
-    from implicitglobalgrid_tpu.utils.compat import pallas_force_interpret
+    from jax.experimental.pallas.tpu import force_tpu_interpret_mode
 
     nt = 4
     kw = dict(
@@ -385,7 +385,7 @@ def test_fused_zpatch_periodic_z_multiblock_matches_xla():
     igg.finalize_global_grid()
 
     state, params = diffusion3d.setup(16, 32, 128, **kw)
-    with pallas_force_interpret():
+    with force_tpu_interpret_mode():
         stepf = diffusion3d.make_multi_step(params, nt, donate=False, fused_k=2)
         T_got = np.asarray(igg.gather(jax.block_until_ready(stepf(*state))[0]))
     igg.finalize_global_grid()
@@ -398,7 +398,7 @@ def test_fused_zpatch_periodic_z_bfloat16():
     bf16 path at bf16 accuracy.  nt=4 = two fused groups, so the second
     group applies a REAL export-derived patch in-kernel (one group would
     only ever apply the trivial identity patch)."""
-    from implicitglobalgrid_tpu.utils.compat import pallas_force_interpret
+    from jax.experimental.pallas.tpu import force_tpu_interpret_mode
 
     nt = 4
     kw = dict(
@@ -413,7 +413,7 @@ def test_fused_zpatch_periodic_z_bfloat16():
     igg.finalize_global_grid()
 
     state, params = diffusion3d.setup(16, 32, 128, **kw)
-    with pallas_force_interpret():
+    with force_tpu_interpret_mode():
         stepf = diffusion3d.make_multi_step(params, nt, donate=False, fused_k=2)
         T_got = np.asarray(
             jax.block_until_ready(stepf(*state))[0].astype(jax.numpy.float32)
@@ -425,7 +425,7 @@ def test_fused_zpatch_periodic_z_bfloat16():
 
 def test_fused_zpatch_periodic_z_matches_xla():
     """Same cadence on the periodic self-neighbor z config (1 device)."""
-    from implicitglobalgrid_tpu.utils.compat import pallas_force_interpret
+    from jax.experimental.pallas.tpu import force_tpu_interpret_mode
 
     nt = 4
     kw = dict(
@@ -438,7 +438,7 @@ def test_fused_zpatch_periodic_z_matches_xla():
     igg.finalize_global_grid()
 
     state, params = diffusion3d.setup(16, 32, 128, **kw)
-    with pallas_force_interpret():
+    with force_tpu_interpret_mode():
         stepf = diffusion3d.make_multi_step(params, nt, donate=False, fused_k=2)
         T_got = np.asarray(jax.block_until_ready(stepf(*state))[0])
     igg.finalize_global_grid()

@@ -211,7 +211,7 @@ def test_fused_single_device_matches_xla():
     """fused_k on a no-halo-activity grid: the fluxes stay in the kernel's
     padded layout across the whole PT loop; results must match the plain
     multi-step path to few (scale-relative) f32 ULPs."""
-    from implicitglobalgrid_tpu.utils.compat import pallas_force_interpret
+    from jax.experimental.pallas.tpu import force_tpu_interpret_mode
 
     nt = 2
     # dtype pinned: f64 is outside the kernel envelope (see the acoustic
@@ -224,7 +224,7 @@ def test_fused_single_device_matches_xla():
     igg.finalize_global_grid()
 
     state, params = pc.setup(16, 32, 128, **kw)
-    with pallas_force_interpret():
+    with force_tpu_interpret_mode():
         stepf = pc.make_multi_step(
             params, nt, donate=False, fused_k=2, fused_tile=(8, 16)
         )
@@ -240,7 +240,7 @@ def test_fused_ragged_npt_matches_xla(npt, fused_k):
     odd lead iteration + even kernel chunks, all exchanges at width w —
     must match the per-iteration path.  (10, 4) -> chunks [4, 4, 2];
     (5, 2) -> lead 1 + chunks [2, 2]."""
-    from implicitglobalgrid_tpu.utils.compat import pallas_force_interpret
+    from jax.experimental.pallas.tpu import force_tpu_interpret_mode
 
     nt = 2
     kw = dict(
@@ -253,7 +253,7 @@ def test_fused_ragged_npt_matches_xla(npt, fused_k):
     igg.finalize_global_grid()
 
     state, params = pc.setup(16, 32, 128, **kw)
-    with pallas_force_interpret():
+    with force_tpu_interpret_mode():
         stepf = pc.make_multi_step(
             params, nt, donate=False, fused_k=fused_k, fused_tile=(8, 16)
         )
@@ -268,7 +268,7 @@ def test_fused_ragged_zpatch_periodic_z_matches_xla(npt):
     """Ragged schedule through the in-kernel z-slab cadence (periodic
     self-neighbor z): patch application and export both at width w for
     every chunk, shorter chunks included."""
-    from implicitglobalgrid_tpu.utils.compat import pallas_force_interpret
+    from jax.experimental.pallas.tpu import force_tpu_interpret_mode
 
     nt = 1
     kw = dict(
@@ -281,7 +281,7 @@ def test_fused_ragged_zpatch_periodic_z_matches_xla(npt):
     igg.finalize_global_grid()
 
     state, params = pc.setup(16, 32, 128, **kw)
-    with pallas_force_interpret():
+    with force_tpu_interpret_mode():
         stepf = pc.make_multi_step(
             params, nt, donate=False, fused_k=4, fused_tile=(8, 16)
         )
@@ -295,7 +295,7 @@ def test_fused_deep_halo_matches_xla_multiblock():
     """k fused PT iterations + one width-k all-field slab exchange vs the
     per-iteration comm-lean path (interpret-mode kernel; 2 devices — the
     interpret-mode Pallas + shard_map deadlock constraint)."""
-    from implicitglobalgrid_tpu.utils.compat import pallas_force_interpret
+    from jax.experimental.pallas.tpu import force_tpu_interpret_mode
 
     nt = 2
     kw = dict(
@@ -308,7 +308,7 @@ def test_fused_deep_halo_matches_xla_multiblock():
     igg.finalize_global_grid()
 
     state, params = pc.setup(16, 32, 128, **kw)
-    with pallas_force_interpret():
+    with force_tpu_interpret_mode():
         stepf = pc.make_multi_step(
             params, nt, donate=False, fused_k=2, fused_tile=(8, 16)
         )
@@ -366,7 +366,7 @@ def test_fused_validation():
 def test_fused_zpatch_deep_halo_z_split_matches_xla():
     """The in-kernel z-slab PT cadence (z-dim decomposition) vs the
     per-iteration comm-lean path (interpret-mode kernel, 2 devices on z)."""
-    from implicitglobalgrid_tpu.utils.compat import pallas_force_interpret
+    from jax.experimental.pallas.tpu import force_tpu_interpret_mode
 
     nt = 2
     kw = dict(
@@ -379,7 +379,7 @@ def test_fused_zpatch_deep_halo_z_split_matches_xla():
     igg.finalize_global_grid()
 
     state, params = pc.setup(16, 32, 128, **kw)
-    with pallas_force_interpret():
+    with force_tpu_interpret_mode():
         stepf = pc.make_multi_step(
             params, nt, donate=False, fused_k=2, fused_tile=(8, 16)
         )
@@ -391,7 +391,7 @@ def test_fused_zpatch_deep_halo_z_split_matches_xla():
 
 def test_fused_zpatch_periodic_z_matches_xla():
     """Same cadence on the periodic self-neighbor z config (1 device)."""
-    from implicitglobalgrid_tpu.utils.compat import pallas_force_interpret
+    from jax.experimental.pallas.tpu import force_tpu_interpret_mode
 
     nt = 2
     kw = dict(
@@ -404,7 +404,7 @@ def test_fused_zpatch_periodic_z_matches_xla():
     igg.finalize_global_grid()
 
     state, params = pc.setup(16, 32, 128, **kw)
-    with pallas_force_interpret():
+    with force_tpu_interpret_mode():
         stepf = pc.make_multi_step(
             params, nt, donate=False, fused_k=2, fused_tile=(8, 16)
         )

@@ -62,7 +62,7 @@ def _xla_steps(state, params, k):
 
 
 def _fused_interpret(state, params, k, **kw):
-    from implicitglobalgrid_tpu.utils.compat import pallas_force_interpret
+    from jax.experimental.pallas.tpu import force_tpu_interpret_mode
 
     P, Vx, Vy, Vz = state
     cax = params.dt / params.rho / params.dx
@@ -70,7 +70,7 @@ def _fused_interpret(state, params, k, **kw):
     caz = params.dt / params.rho / params.dz
     b = params.dt * params.K
     Vxp, Vyp, Vzp = pad_faces(Vx, Vy, Vz)
-    with pallas_force_interpret():
+    with force_tpu_interpret_mode():
         Pg, Vxp, Vyp, Vzp = fused_leapfrog_steps(
             P, Vxp, Vyp, Vzp, k, cax, cay, caz, b,
             1.0 / params.dx, 1.0 / params.dy, 1.0 / params.dz, **kw,

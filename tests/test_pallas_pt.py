@@ -65,11 +65,11 @@ def _xla_iters(state, params, k):
 
 
 def _fused_interpret(state, params, k, **kw):
-    from implicitglobalgrid_tpu.utils.compat import pallas_force_interpret
+    from jax.experimental.pallas.tpu import force_tpu_interpret_mode
 
     T, Pf, qDx, qDy, qDz = state
     qxp, qyp, qzp = pad_faces(qDx, qDy, qDz)
-    with pallas_force_interpret():
+    with force_tpu_interpret_mode():
         Pf, qxp, qyp, qzp = fused_pt_iterations(
             T, Pf, qxp, qyp, qzp, k,
             params.theta_q,

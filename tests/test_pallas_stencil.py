@@ -1,7 +1,7 @@
 """Tests for the temporally-blocked Pallas diffusion kernel.
 
 The suite runs on the 8-virtual-CPU-device mesh (conftest), so the TPU
-kernel executes under interpret mode (`utils.compat.pallas_force_interpret`) — the interpreter
+kernel executes under interpret mode (`pltpu.force_tpu_interpret_mode`) — the interpreter
 implements the DMA/semaphore semantics, which is exactly what the kernel's
 double-buffering logic needs validated.  Compiled-mode numbers come from
 `bench.py` on the real chip (same code path minus the interpreter flag).
@@ -34,9 +34,9 @@ def _setup(shape, seed=0):
 
 
 def _fused_interpret(T, Cp, k, c, **kw):
-    from implicitglobalgrid_tpu.utils.compat import pallas_force_interpret
+    from jax.experimental.pallas.tpu import force_tpu_interpret_mode
 
-    with pallas_force_interpret():
+    with force_tpu_interpret_mode():
         return fused_diffusion_steps(T, Cp, k, c, c, c, **kw)
 
 
@@ -97,9 +97,9 @@ def test_nonuniform_spacing_coefficients():
     params = Params(dx=dx, dy=dy, dz=dz, dt=dt, dtype=jnp.float32)
     upd = jax.jit(_diffusion_update(params))
     ref = upd(upd(T, Cp), Cp)
-    from implicitglobalgrid_tpu.utils.compat import pallas_force_interpret
+    from jax.experimental.pallas.tpu import force_tpu_interpret_mode
 
-    with pallas_force_interpret():
+    with force_tpu_interpret_mode():
         got = fused_diffusion_steps(
             T, Cp, 2,
             float(dt / (dx * dx)), float(dt / (dy * dy)), float(dt / (dz * dz)),

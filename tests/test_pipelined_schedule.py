@@ -6,8 +6,8 @@ partition the same tiles tile-for-tile, and the early-dispatch exchange
 (`ops.halo.begin_slab_exchange`/`finish_slab_exchange`) moves exactly the
 serialized slabs (corner strips patched in).  Inadmissible configs must
 fall back to the serialized schedule (still bit-identical, warn-once under
-``pipelined=True``).  Kernels run through the generic Pallas interpreter
-(`utils.compat.pallas_force_interpret`).
+``pipelined=True``).  Kernels run through the TPU Pallas interpreter
+(`pltpu.force_tpu_interpret_mode`).
 """
 
 import warnings
@@ -25,7 +25,8 @@ from implicitglobalgrid_tpu.ops.overlap import (
     tile_subset_count,
     tile_subset_map,
 )
-from implicitglobalgrid_tpu.utils.compat import pallas_force_interpret
+from implicitglobalgrid_tpu.analysis.ir import is_kernel_eqn
+from jax.experimental.pallas.tpu import force_tpu_interpret_mode
 
 
 # --- tile-subset decomposition ---------------------------------------------
@@ -96,7 +97,7 @@ def test_pipelined_deep_overlap_falls_back_serialized():
         state, params = diffusion3d.setup(24, 32, 128, **kw)
         err = diffusion3d.pipelined_support_error((24, 32, 128), 2, 4, 8, 16)
         assert err is not None and "past the ring tiles" in err, err
-        with pallas_force_interpret():
+        with force_tpu_interpret_mode():
             with warnings.catch_warnings():
                 warnings.simplefilter("ignore", RuntimeWarning)
                 step = diffusion3d.make_multi_step(
@@ -222,7 +223,7 @@ def _diffusion_states(nloc, dims, periods, k, nt, pipelined, tile):
               overlapx=2 * k, overlapy=2 * k, overlapz=2 * k, quiet=True,
               dtype=jnp.float32, **periods)
     state, params = diffusion3d.setup(*nloc, **kw)
-    with pallas_force_interpret():
+    with force_tpu_interpret_mode():
         step = diffusion3d.make_multi_step(
             params, nt, donate=False, fused_k=k, fused_tile=tile,
             pipelined=pipelined,
@@ -274,7 +275,7 @@ def test_pipelined_acoustic_matches_serialized_bitwise():
                   overlapx=4, overlapy=4, overlapz=4, periodz=1, quiet=True,
                   dtype=jnp.float32)
         state, params = acoustic3d.setup(24, 32, 128, **kw)
-        with pallas_force_interpret():
+        with force_tpu_interpret_mode():
             step = acoustic3d.make_multi_step(
                 params, 4, donate=False, fused_k=2, fused_tile=(8, 16),
                 pipelined=pipelined,
@@ -297,7 +298,7 @@ def test_pipelined_porous_ragged_matches_serialized_bitwise():
                   overlapx=4, overlapy=4, overlapz=4, periodz=1, quiet=True,
                   dtype=jnp.float32, npt=5)
         state, params = pc.setup(24, 32, 128, **kw)
-        with pallas_force_interpret():
+        with force_tpu_interpret_mode():
             step = pc.make_multi_step(
                 params, 2, donate=False, fused_k=2, fused_tile=(8, 16),
                 pipelined=pipelined,
@@ -391,7 +392,7 @@ def _kernel_permute_independent_pairs(pipelined):
     the traced program — the dataflow freedom the pipelined schedule exists
     to create, asserted below the compiler (toolchain-independent)."""
     from implicitglobalgrid_tpu.models import diffusion3d
-    from implicitglobalgrid_tpu.utils.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     k = 2
@@ -399,7 +400,7 @@ def _kernel_permute_independent_pairs(pipelined):
               overlapx=4, overlapy=4, overlapz=4, quiet=True,
               dtype=jnp.float32)
     state, params = diffusion3d.setup(40, 32, 128, **kw)  # ncx=5 at bx=8
-    with pallas_force_interpret():
+    with force_tpu_interpret_mode():
         step = diffusion3d.make_multi_step(
             params, 2 * k, donate=False, fused_k=k, fused_tile=(8, 16),
             pipelined=pipelined,
@@ -417,7 +418,7 @@ def _kernel_permute_independent_pairs(pipelined):
     # The kernel-vs-fallback wrapper (`fused_with_xla_grad`) nests the whole
     # cadence under one custom_vjp eqn: unwrap to its primal jaxpr.
     while len(inner.eqns) == 1 and "custom_vjp" in inner.eqns[0].primitive.name:
-        inner = inner.eqns[0].params["fun_jaxpr"].jaxpr
+        inner = inner.eqns[0].params["call_jaxpr"].jaxpr
     producer = {}
     for e in inner.eqns:
         for ov in e.outvars:
@@ -433,18 +434,7 @@ def _kernel_permute_independent_pairs(pipelined):
                     stack.append(p)
         return seen
 
-    def is_kernel(e):
-        # the kernels' `jax.jit(pallas_call)` builders appear as pjit eqns
-        if e.primitive.name == "pallas_call":
-            return True
-        if e.primitive.name == "pjit":
-            sub = e.params.get("jaxpr")
-            return sub is not None and any(
-                se.primitive.name == "pallas_call" for se in sub.jaxpr.eqns
-            )
-        return False
-
-    kernels = [e for e in inner.eqns if is_kernel(e)]
+    kernels = [e for e in inner.eqns if is_kernel_eqn(e)]
     perms = [e for e in inner.eqns if e.primitive.name == "ppermute"]
     assert kernels and perms, (len(kernels), len(perms))
     kc = {id(e): closure(e) for e in kernels}

@@ -653,7 +653,7 @@ def _shard_mapped_jaxpr(body, nargs=1):
     from jax.sharding import Mesh, PartitionSpec as P
 
     from implicitglobalgrid_tpu.analysis.ir import unwrap_inner
-    from implicitglobalgrid_tpu.utils.compat import shard_map
+    from jax import shard_map
 
     mesh = Mesh(np.array(jax.devices()[:2]), ("x",))
     mapped = shard_map(body, mesh=mesh, in_specs=(P("x"),) * nargs,
@@ -705,7 +705,7 @@ def test_eqn_presence_classifies_collective_envelopes():
 
     jaxpr = _shard_mapped_jaxpr(body)
     by_name = {e.primitive.name: e for e in jaxpr.eqns}
-    assert _eqn_presence(by_name["pjit"]) == (False, True)
+    assert _eqn_presence(by_name["jit"]) == (False, True)
     assert _eqn_presence(by_name["add"]) == (False, False)
 
 

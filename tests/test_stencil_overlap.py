@@ -212,7 +212,7 @@ def _ppermute_waits_on_full_block(hide_comm):
     toolchain-independent (the same move `test_pipelined_schedule.py` makes
     for the pipelined group schedule)."""
     from implicitglobalgrid_tpu.models import diffusion3d
-    from implicitglobalgrid_tpu.utils.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     state, params = diffusion3d.setup(16, 16, 16, hide_comm=hide_comm, quiet=True)

@@ -351,7 +351,7 @@ def test_slab_exchange_scopes_in_compiled_hlo():
         begin_slab_exchange,
         finish_slab_exchange,
     )
-    from implicitglobalgrid_tpu.utils.compat import shard_map
+    from jax import shard_map
 
     igg.init_global_grid(8, 8, 8, periodx=1, quiet=True)
     gg = igg.get_global_grid()
@@ -376,18 +376,6 @@ def test_slab_exchange_scopes_in_compiled_hlo():
     snap = tele.snapshot()
     assert snap["counters"]["halo.begin_slab_traces"] == 1
     assert snap["counters"]["halo.finish_slab_traces"] == 1
-
-
-def test_compat_shims_are_context_managers():
-    from implicitglobalgrid_tpu.utils.compat import (
-        named_scope,
-        trace_annotation,
-    )
-
-    with named_scope("igg_test_scope"):
-        pass
-    with trace_annotation("igg_test_annotation"):
-        pass
 
 
 # -- Public surface: snapshot + dumps -----------------------------------------

@@ -1,0 +1,119 @@
+"""The main path's kernel programs compiled at real size for a described
+TPU v5e (no chip attached): the installed Mosaic must accept every kernel
+the chip smoke runs, and the four-chip cadence must keep its collectives.
+
+The topology is described inside a module fixture (only the worker that
+runs this file loads the TPU compiler); everything built from it is built
+in the fixture too.  The programs compile in parallel threads with the
+persistent compile cache off.  A compile here is not a chip run: it says
+nothing about results or times.
+"""
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+# name: (model, local n (cube), dims, overlaps, nsteps, make_multi_step
+#        kwargs, extra setup kwargs)
+# Longest compile first (Mosaic time grows with the tile window: ~180, 145,
+# 90, 80 and 20 s here), so three threads finish close together.
+PROGRAMS = {
+    "diffusion_k4_512_tile32x128": ("diffusion3d", 512, (1, 1, 1), (2, 2, 2),
+                                    8, dict(fused_k=4, fused_tile=(32, 128)),
+                                    {}),
+    "diffusion_k4_256": ("diffusion3d", 256, (1, 1, 1), (2, 2, 2), 8,
+                         dict(fused_k=4), {}),
+    "porous_k6_npt10_256": ("porous_convection3d", 256, (1, 1, 1), (2, 2, 2),
+                            1, dict(fused_k=6), dict(npt=10)),
+    "acoustic_k6_256": ("acoustic3d", 256, (1, 1, 1), (2, 2, 2), 12,
+                        dict(fused_k=6), {}),
+    "four_chip_diffusion_pipelined": (
+        "diffusion3d", 256, (2, 2, 1), (8, 8, 2), 8,
+        dict(fused_k=4, fused_tile=(32, 64), pipelined=True),
+        dict(overlapx=8, overlapy=8)),
+}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def compiled(topo):
+    import importlib
+    from concurrent.futures import ThreadPoolExecutor
+
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    import implicitglobalgrid_tpu as igg
+    from implicitglobalgrid_tpu.utils.aot import synthetic_topology_grid
+
+    if igg.grid_is_initialized():
+        igg.finalize_global_grid()
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    # The suite runs with 64-bit types on; Mosaic lowers no int64 index, and
+    # the chip smoke (like any f32 deployment) runs with them off.
+    x64 = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_x64", False)
+    try:
+        lowered = {}
+        for name, (model, n, dims, ovl, nsteps, kw, setup_kw) in (
+                PROGRAMS.items()):
+            mod = importlib.import_module(
+                f"implicitglobalgrid_tpu.models.{model}")
+            # Params and field ranks from a small grid: a compile needs
+            # shapes, not values (staggered faces keep their +1).
+            state, params = mod.setup(16, 16, 16, dtype=jnp.float32,
+                                      quiet=True, devices=jax.devices()[:1],
+                                      **setup_kw)
+            local = [tuple(s + n - 16 for s in A.shape) for A in state]
+            igg.finalize_global_grid()
+            with synthetic_topology_grid(dims, (n,) * 3, ovl, topo) as (
+                    _, mesh):
+                sh = NamedSharding(mesh, P("x", "y", "z"))
+                shapes = tuple(
+                    jax.ShapeDtypeStruct(
+                        tuple(d * e for d, e in zip(dims, shp)),
+                        jnp.float32, sharding=sh)
+                    for shp in local
+                )
+                step = mod.make_multi_step(params, nsteps, **kw)
+                lowered[name] = step.lower(*shapes)
+        # three threads: the suite's other workers share this machine
+        with ThreadPoolExecutor(3) as pool:
+            texts = pool.map(lambda lo: lo.compile().as_text(),
+                             lowered.values())
+            return dict(zip(lowered, texts))
+    finally:
+        jax.config.update("jax_enable_x64", x64)
+        jax.config.update("jax_enable_compilation_cache", prev)
+        cc.reset_cache()
+
+
+@pytest.mark.parametrize("name", sorted(PROGRAMS))
+def test_kernel_program_compiles_for_v5e(compiled, name):
+    assert "tpu_custom_call" in compiled[name], (
+        f"{name}: no Mosaic kernel in the compiled program (an XLA or "
+        "interpret path)"
+    )
+
+
+def test_four_chip_program_keeps_its_collectives(compiled):
+    txt = compiled["four_chip_diffusion_pipelined"]
+    # two groups x (x, y) slab exchanges, each a send/recv pair per side
+    assert txt.count("collective-permute-start") >= 8

@@ -467,12 +467,14 @@ def test_tuned_config_bit_identical_to_default(module, name, setup_kw,
     assert tune_cache.lookup(key)["source"] == "test"
 
 
-# -- seeding from the committed trajectory ------------------------------------
+# -- seeding from the recorded trajectory -------------------------------------
 
 
 def test_seed_from_bench_ingests_the_recorded_winners(tune_cache):
-    entries = tuning.seed_from_bench(_repo, tune_cache, backend="tpu")
-    assert entries, "the committed BENCH rounds carry seedable extras"
+    # the earlier runtime's rounds, kept as fixtures (tests/test_perf_gate.py)
+    traj = os.path.join(_here, "data", "bench_trajectory")
+    entries = tuning.seed_from_bench(traj, tune_cache, backend="tpu")
+    assert entries, "the recorded BENCH rounds carry seedable extras"
     by_key = {(e["key"]["model"], tuple(e["key"]["size"]),
                e["key"]["extra"].get("npt")): e for e in entries}
     porous = by_key[("porous_convection3d", (256, 256, 256), 12)]
@@ -664,7 +666,10 @@ def test_cli_sweep_measures_and_persists(igg_tune_cli, tmp_path, monkeypatch,
     assert os.listdir(str(tmp_path)) == []
 
 
-def test_cli_seed_dry_run_matches_committed(igg_tune_cli, tmp_path, capsys):
+def test_cli_seed_dry_run_matches_committed(igg_tune_cli, tmp_path,
+                                            monkeypatch, capsys):
+    monkeypatch.setattr(igg_tune_cli, "REPO",
+                        os.path.join(_here, "data", "bench_trajectory"))
     rc = igg_tune_cli.main(["seed", "--dry-run", "--json",
                             "--cache", str(tmp_path)])
     entries = json.loads(capsys.readouterr().out)

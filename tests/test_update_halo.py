@@ -471,7 +471,7 @@ def _collective_records(hlo):
 def _compiled_stencil_hlo(body, args):
     from jax.sharding import PartitionSpec as P
 
-    from implicitglobalgrid_tpu.utils.compat import shard_map
+    from jax import shard_map
 
     gg = igg.get_global_grid()
     specs = tuple(P(*igg.AXIS_NAMES[: a.ndim]) for a in args)
