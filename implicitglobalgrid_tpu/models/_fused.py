@@ -8,11 +8,37 @@ warning once per (shape, k, reason) so production loops are not spammed.
 Autodiff: `fused_with_xla_grad` makes ``jax.grad`` work through the fused
 Pallas chunks (which have no VJP of their own) by differentiating the
 equivalent XLA cadence in the backward pass.
+
+Spans: `spanned_multi_step` gives every model's ``make_multi_step``
+callable its ``igg.multi_step`` host span.
 """
 
 from __future__ import annotations
 
+import functools
+
 _warned: set = set()
+
+
+def spanned_multi_step(model: str):
+    """Decorator of a model's ``make_multi_step(params, nsteps, ...)``: the
+    callable it returns — plain jit, `igg.stencil` or batched alike — runs
+    each call inside one ``igg.multi_step`` span (`tracing.spanned_calls`)
+    tagged ``model``, ``nsteps`` and ``call``, the callable's own count of
+    its calls."""
+    from ..utils import tracing
+
+    def deco(make):
+        @functools.wraps(make)
+        def make_spanned(params, nsteps, *args, **kwargs):
+            return tracing.spanned_calls(
+                make(params, nsteps, *args, **kwargs), "igg.multi_step",
+                model=model, nsteps=int(nsteps),
+            )
+
+        return make_spanned
+
+    return deco
 
 
 def run_group_schedule(chunks, body, carry, *, unroll_limit=8,

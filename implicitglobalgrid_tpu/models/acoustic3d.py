@@ -43,6 +43,7 @@ from .. import (
     zeros,
 )
 from ..ops.overlap import hide_communication
+from ._fused import spanned_multi_step as _spanned_multi_step
 
 
 @dataclasses.dataclass(frozen=True)
@@ -166,7 +167,8 @@ def make_step(params: Params, *, donate: bool = True, batch: bool = False):
     ``batch=True``: the ensemble step over ``(B, ...)`` batched fields —
     `jax.vmap` of the same per-block step; bit-identical per member, one
     collective pair per exchanged dimension at any B (see
-    `models.diffusion3d.make_step`).
+    `models.diffusion3d.make_step`).  No ``igg.multi_step`` span: at one
+    step per call its microseconds would be a visible share of a step.
     """
     v_update = _velocity_update(params)
     p_update = _pressure_update(params)
@@ -223,6 +225,7 @@ def _tune_state(params: Params):
     )
 
 
+@_spanned_multi_step("acoustic3d")
 def make_multi_step(
     params: Params, nsteps: int, *, donate: bool = True, exchange_every: int = 1,
     fused_k: int | None = None, fused_tile: tuple[int, int] | None = None,

@@ -71,6 +71,7 @@ def _inn(A):
     return A[1:-1, 1:-1, 1:-1]
 
 
+from ._fused import spanned_multi_step as _spanned_multi_step
 from ._fused import warn_fused_fallback as _warn_fused_fallback  # shared w/ acoustic
 
 
@@ -181,6 +182,10 @@ def make_step(params: Params, *, donate: bool = True, batch: bool = False):
     B members advance bit-identically to B independent calls while every
     exchanged dimension still issues ONE collective pair (the ppermute
     batching rule carries the ensemble axis inside the same hop).
+
+    Unlike `make_multi_step`'s, this callable records no ``igg.multi_step``
+    span: at one step per call, even the microseconds a span costs are a
+    visible share of a step (a time loop's ``igg.step`` span covers it).
     """
     update = _diffusion_update(params)
 
@@ -232,6 +237,7 @@ def _tune_state(params: Params):
     return ones(shape, params.dtype), ones(shape, params.dtype)
 
 
+@_spanned_multi_step("diffusion3d")
 def make_multi_step(
     params: Params,
     nsteps: int,

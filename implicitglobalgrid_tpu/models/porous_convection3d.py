@@ -44,6 +44,7 @@ from .. import (
     zeros,
 )
 from ..ops.overlap import hide_communication
+from ._fused import spanned_multi_step as _spanned_multi_step
 
 
 @dataclasses.dataclass(frozen=True)
@@ -329,7 +330,8 @@ def make_step(params: Params, *, donate: bool = True, batch: bool = False):
     ``batch=True``: the ensemble step over ``(B, ...)`` batched fields —
     `jax.vmap` of the same per-block step; bit-identical per member, one
     collective pair per exchanged dimension at any B (see
-    `models.diffusion3d.make_step`).
+    `models.diffusion3d.make_step`).  No ``igg.multi_step`` span: at one
+    step per call its microseconds would be a visible share of a step.
     """
     donate_argnums = tuple(range(5)) if donate else ()
     if batch:
@@ -386,6 +388,7 @@ def pipelined_support_error(shape, k, itemsize: int = 4, bx=None, by=None,
     return _generic(pallas_pt, shape, k, itemsize, bx, by, gg, stagger=1)
 
 
+@_spanned_multi_step("porous_convection3d")
 def make_multi_step(
     params: Params,
     nsteps: int,

@@ -256,6 +256,16 @@ def support_error(shape, k, itemsize, bx, by, *, tile_error, candidates):
     return tile_error(n0, n1, n2, k, bx, by, itemsize)
 
 
+def kernel_name(family: str, k: int, zpatch: bool, tile_sel: str) -> str:
+    """The ``name=`` of one kernel variant's `pallas_call`:
+    ``igg_<family>_k<k>_<plain|zpatch>``, plus ``_<tile_sel>`` for a
+    pipelined subset launch (``ring0``, ``mid01``, ...).  It names the
+    compiled custom call and sits in its ``op_name`` scope path, so a
+    device trace says which variant ran."""
+    name = f"igg_{family}_k{k}_{'zpatch' if zpatch else 'plain'}"
+    return name if tile_sel == "all" else f"{name}_{tile_sel}"
+
+
 def per_member(launch):
     """A kernel launch that `jax.vmap` runs once per ensemble member.
 

@@ -65,6 +65,7 @@ and the lint will fail it.
 
 from __future__ import annotations
 
+import functools
 from typing import Sequence
 
 import numpy as np
@@ -822,6 +823,26 @@ def _padded_logicals(C, Axp, Ayp, Azp):
     return (None, (n0 + 1, n1, n2), (n0, n1 + 1, n2), (n0, n1, n2 + 1))
 
 
+def _zpatch_scope(what: str):
+    """Run a z-patch helper under ``jax.named_scope("igg_zpatch_<what>")``,
+    so a device trace names the ops of the self-neighbour z exchange.  The
+    scope changes no op's class in a trace reduction: it never contains
+    ``igg_halo_exchange`` or ``igg_slab_exchange``, and it adds to an op's
+    scope path without taking one away."""
+
+    def deco(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            from jax import named_scope
+
+            with named_scope(f"igg_zpatch_{what}"):
+                return fn(*args, **kwargs)
+
+        return scoped
+
+    return deco
+
+
 def _pack_z_patch(lo, hi, width: int):
     """Pack a field's two z slabs into one 128-lane array: lanes ``[0, w)``
     = values for planes ``[0, w)``, lanes ``[w, 2w)`` = values for planes
@@ -847,6 +868,7 @@ def z_slab_patch(A, *, width: int = 1):
     return _pack_z_patch(*vals, width)
 
 
+@_zpatch_scope("identity")
 def identity_z_patch(A, *, width: int = 1):
     """Single-field `identity_z_patches` (re-writes the current z planes)."""
     n = A.shape[2]
@@ -855,6 +877,7 @@ def identity_z_patch(A, *, width: int = 1):
     )
 
 
+@_zpatch_scope("apply")
 def apply_z_patch(A, patch, *, width: int = 1):
     """Single-field `apply_z_patches` (the chunk-end restoration)."""
     n = A.shape[2]
@@ -893,6 +916,7 @@ def _pack_z_patch_t(lo, hi, width: int):
     return jnp.pad(packed, ((0, 0), (0, _pad8(p) - p), (0, _pad128(n1) - n1)))
 
 
+@_zpatch_scope("identity")
 def identity_z_patch_t(A, *, width: int = 1):
     """Transposed-layout `identity_z_patch` (re-writes the current z planes)."""
     n = A.shape[2]
@@ -901,6 +925,7 @@ def identity_z_patch_t(A, *, width: int = 1):
     )
 
 
+@_zpatch_scope("apply")
 def apply_z_patch_t(A, patch_t, *, width: int = 1):
     """Transposed-layout `apply_z_patch` (the chunk-end restoration)."""
     n0, n1, n = A.shape
@@ -932,6 +957,7 @@ def exchange_dims_t(E, *, width: int, shape, coalesce=None):
     return E
 
 
+@_zpatch_scope("from_export")
 def z_patch_from_export_t(export_t, *, width: int):
     """Transposed-layout `z_patch_from_export`: the z communication on the
     ``(n0, PE, n1p)`` export's axis-1 plane slabs.  Must run AFTER the x/y
@@ -1093,6 +1119,7 @@ def finish_slab_exchange(fields, pends, *, logicals=None):
     return tuple(out)
 
 
+@_zpatch_scope("from_export")
 def z_patch_from_export(export, *, width: int):
     """The next group's packed z patch from a fused kernel's z-slab export.
 
@@ -1194,6 +1221,7 @@ def fix_topface_z_exports(exports, C, Axp, Ayp, Azp, *, width: int):
     return exp_cz, exp_x, exp_y
 
 
+@_zpatch_scope("from_export")
 def z_patches_from_exports(exports, C_shape, *, width: int, coalesce=None):
     """x/y-exchange the three packed z exports (real-shape slab indices via
     ``logical``) and turn each into the next group's patch — the multi-field
@@ -1272,6 +1300,7 @@ def z_slab_patches(C, Axp, Ayp, Azp, *, width: int = 1, coalesce=None):
     return (_pack_cz(packed[0], packed[3]), packed[1], packed[2])
 
 
+@_zpatch_scope("identity")
 def identity_z_patches(C, Axp, Ayp, Azp, *, width: int = 1):
     """Patches that re-write the CURRENT z-halo planes (a no-op application).
 
@@ -1288,6 +1317,7 @@ def identity_z_patches(C, Axp, Ayp, Azp, *, width: int = 1):
     return (_pack_cz(packed[0], packed[3]), packed[1], packed[2])
 
 
+@_zpatch_scope("apply")
 def apply_z_patches(C, Axp, Ayp, Azp, patches, *, width: int = 1):
     """Write packed z patches into the arrays (the chunk-end restoration).
 

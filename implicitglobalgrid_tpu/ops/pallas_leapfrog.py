@@ -705,5 +705,6 @@ def _build(n0, n1, n2, dtype, k, cax, cay, caz, b, idx, idy, idz, bx, by,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_envelope.vmem_limit(vmem_bytes)
         ),
+        name=_envelope.kernel_name("leapfrog", k, zp, tile_sel),
     )
     return _envelope.per_member(jax.jit(call))

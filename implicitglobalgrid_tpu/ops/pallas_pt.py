@@ -599,5 +599,6 @@ def _build(n0, n1, n2, dtype, k, th, idx, idy, idz, ralam, bp, bx, by,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_envelope.vmem_limit(vmem_bytes)
         ),
+        name=_envelope.kernel_name("pt", k, zp, tile_sel),
     )
     return _envelope.per_member(jax.jit(call))

@@ -638,5 +638,6 @@ def _build(n0, n1, n2, dtype, k, cx, cy, cz, bx, by, zp: bool = False,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_envelope.vmem_limit(2 * vmem_bytes)
         ),
+        name=_envelope.kernel_name("diffusion", k, zp, tile_sel),
     )
     return _envelope.per_member(jax.jit(call))

@@ -90,16 +90,17 @@ def topology_mesh(dims, topo=None):
 
 
 @contextlib.contextmanager
-def synthetic_topology_grid(dims, nloc, overlaps=(2, 2, 2), topo=None):
+def synthetic_topology_grid(dims, nloc, overlaps=(2, 2, 2), topo=None,
+                            periods=(0, 0, 0)):
     """Install a synthetic multi-chip `GlobalGrid` for AOT lowering.
 
-    Initializes a real 1-device grid with local shape ``nloc`` and
-    ``overlaps`` (so every derived quantity — implicit global size, halo
-    widths, timing functions — is built by the public path), then swaps in
-    a copy carrying the detached-topology ``dims`` mesh.  Yields
-    ``(gg, mesh)``; the grid is restored and finalized on exit.  Refuses to
-    run with a live caller grid rather than silently destroying it.
-    ``topo`` is passed to `topology_mesh`.
+    Initializes a real 1-device grid with local shape ``nloc``,
+    ``overlaps`` and ``periods`` (so every derived quantity — implicit
+    global size, halo widths, timing functions — is built by the public
+    path), then swaps in a copy carrying the detached-topology ``dims``
+    mesh.  Yields ``(gg, mesh)``; the grid is restored and finalized on
+    exit.  Refuses to run with a live caller grid rather than silently
+    destroying it.  ``topo`` is passed to `topology_mesh`.
     """
     import jax
 
@@ -113,9 +114,10 @@ def synthetic_topology_grid(dims, nloc, overlaps=(2, 2, 2), topo=None):
     mesh = topology_mesh(dims, topo)  # before init: a topology failure must skip cleanly
     nx, ny, nz = nloc
     ox, oy, oz = overlaps
+    px, py, pz = periods
     _grid.init_global_grid(
-        nx, ny, nz, overlapx=ox, overlapy=oy, overlapz=oz, quiet=True,
-        devices=list(jax.devices())[:1],
+        nx, ny, nz, overlapx=ox, overlapy=oy, overlapz=oz, periodx=px,
+        periody=py, periodz=pz, quiet=True, devices=list(jax.devices())[:1],
     )
     gg0 = _grid.get_global_grid()
     gg = dataclasses.replace(

@@ -30,13 +30,16 @@ already speaks (docs/observability.md "Device timeline").
   `resilience.guarded_time_loop`'s exit path so the bytes already
   captured still land.
 * **Attribution** — `attribute_trace` parses the Chrome/Perfetto JSON,
-  keeps the DEVICE ops (events carrying XLA's ``args.hlo_op``), and
-  attributes their time to the ``named_scope`` namespace where the op
-  name carries one, else to the blessed
-  `utils.hlo_analysis.classify_op_name` buckets: ``collectives`` (fabric
-  traffic), ``kernels`` (fusions / custom-calls — the Pallas launches),
-  ``glue`` (copies, slices, control flow — the unattributed cadence
-  overhead).  The **measured overlap fraction** is wall-clock
+  keeps the DEVICE ops (XLA:CPU's events carrying ``args.hlo_op``; a TPU
+  plane's ``XLA Ops`` line, whose events carry the instruction's text and
+  its ``named_scope`` path as ``args.tf_op``), and attributes their time
+  in the program's names: a Pallas launch to its kernel variant
+  (``igg_diffusion_k4_zpatch``, `ops._fused_envelope.kernel_name`), any
+  other op to the `SCOPE_NAMES` member on its scope path, else to the
+  blessed `utils.hlo_analysis.classify_op_name` buckets: ``collectives``
+  (fabric traffic), ``kernels`` (fusions / custom-calls), ``glue``
+  (copies, slices, control flow — XLA-inserted copies carry no scope and
+  land here).  The **measured overlap fraction** is wall-clock
   union-intersection per device track: |union(collective intervals) ∩
   union(kernel intervals)| / |union(collective intervals)| — the number
   ROADMAP item 1's acceptance needs, honest bounds in
@@ -44,8 +47,10 @@ already speaks (docs/observability.md "Device timeline").
 * **Join** — `attach_device_tracks` adds per-rank device tracks to the
   PR-9 merged host timeline (``scripts/igg_trace.py merge --device``):
   device events ride the owning rank's pid on dedicated device tids,
-  anchored at the host ``start_trace`` instant (the capture meta's perf
-  sample), and the output still passes `tracing.validate_chrome_trace`.
+  aligned on a program span that is both in the host ring and in the
+  capture (every span is a profiler annotation too; the host
+  ``start_trace`` instant is the fallback), and the output still passes
+  `tracing.validate_chrome_trace`.
 * **Feed out** — `publish_attribution` lands
   ``profile.scope_seconds.<name>`` / ``profile.overlap_fraction`` gauges;
   ``bench.py`` records ``extras.profile_attribution`` with the overlap
@@ -65,6 +70,7 @@ import glob
 import gzip
 import json
 import os
+import re
 import time
 from typing import Any, Sequence
 
@@ -110,7 +116,22 @@ SCOPE_NAMES = (
     "igg_slab_exchange_begin",
     "igg_slab_exchange_finish",
     "igg_halo_exchange",
+    "igg_zpatch_identity",
+    "igg_zpatch_apply",
+    "igg_zpatch_from_export",
 )
+
+#: a Pallas kernel variant's name (`ops._fused_envelope.kernel_name`): a
+#: kernel op attributes to its variant, ahead of any enclosing scope
+KERNEL_VARIANT = re.compile(r"igg_[a-z]+_k\d+_(?:plain|zpatch)\w*")
+
+#: profiler lines of a TPU plane (Chrome-trace thread names): the ops the
+#: core executes, and the async ops in flight from start to done (of which
+#: only the collectives count: the exchange's transfers)
+OP_LINE, ASYNC_LINE = "XLA Ops", "Async XLA Ops"
+
+#: opcodes that only enclose other ops on a TPU op line
+CONTAINER_OPCODES = ("while", "conditional", "call")
 
 #: fallback buckets for device ops outside any named scope (the
 #: `hlo_analysis.classify_op_name` vocabulary; "glue" is the unattributed
@@ -498,18 +519,67 @@ def find_trace_files(logdir: str | os.PathLike) -> list[str]:
     return sorted(set(hits), key=lambda p: (os.path.getmtime(p), p))
 
 
+def _opcode(long_name: str) -> str:
+    """Opcode of one HLO instruction's text (``%x = type opcode(...)``),
+    skipping a result type that may be a tuple with nested parentheses."""
+    rest = long_name.partition("=")[2]
+    depth, i = 0, 0
+    while i < len(rest):
+        ch = rest[i]
+        if ch in "([{":
+            depth += 1
+        elif ch in ")]}":
+            depth -= 1
+        elif ch == " " and depth == 0 and i > 0:
+            break
+        i += 1
+    m = re.match(r"\s*([a-z][\w\-]*)\(", rest[i:])
+    return m.group(1) if m else ""
+
+
+def _thread_names(doc: dict) -> dict:
+    return {
+        (e.get("pid"), e.get("tid")): (e.get("args") or {}).get("name", "")
+        for e in doc.get("traceEvents", [])
+        if isinstance(e, dict) and e.get("ph") == "M"
+        and e.get("name") == "thread_name"
+    }
+
+
 def device_ops(doc: dict) -> list[dict]:
-    """The device-op events of a capture: complete (``X``) events carrying
-    XLA's ``args.hlo_op`` — runtime/python/annotation events don't, which
-    is exactly the filter (host time is the span ring's job).  Returns
-    ``{name, hlo_op, hlo_module, pid, tid, ts, dur}`` dicts (µs)."""
+    """The device-op events of a capture (µs): ``{name, hlo_op, op_name,
+    kind, hlo_module, pid, tid, ts, dur}`` dicts.
+
+    Two shapes of capture.  XLA:CPU's op events carry ``args.hlo_op``.  A
+    TPU plane's events carry none: an op of the ``XLA Ops`` line is named
+    by its instruction, with the instruction's text in ``args.long_name``
+    (its opcode gives ``kind``) and its ``named_scope`` path in
+    ``args.tf_op`` (the compiled op's ``op_name``; absent on ops XLA
+    inserted).  Ops that only enclose others (``while``, ``call``) are
+    left out; of the ``Async XLA Ops`` line only the collectives are kept,
+    from start to done: the exchange's transfers in flight.
+    Runtime/python/annotation events are no device ops, which is exactly
+    the filter (host time is the span ring's job)."""
+    threads = _thread_names(doc)
     out = []
     for e in doc.get("traceEvents", []):
         if not isinstance(e, dict) or e.get("ph") != "X":
             continue
         args = e.get("args") or {}
         hlo_op = args.get("hlo_op")
-        if not hlo_op:
+        line = threads.get((e.get("pid"), e.get("tid")))
+        long_name = args.get("long_name")
+        if line in (OP_LINE, ASYNC_LINE) and long_name:
+            hlo_op = e.get("name") or hlo_op
+            opcode = _opcode(str(long_name))
+            if opcode in CONTAINER_OPCODES:
+                continue
+            kind = _hlo.classify_op_name(opcode)
+            if line == ASYNC_LINE and kind != "collective":
+                continue
+        elif hlo_op:
+            kind = _hlo.classify_op_name(hlo_op)
+        else:
             continue
         ts, dur = e.get("ts"), e.get("dur")
         if not isinstance(ts, (int, float)) or not isinstance(
@@ -520,6 +590,8 @@ def device_ops(doc: dict) -> list[dict]:
             {
                 "name": e.get("name", hlo_op),
                 "hlo_op": hlo_op,
+                "op_name": str(args.get("tf_op", "")).rstrip(":") or None,
+                "kind": kind,
                 "hlo_module": args.get("hlo_module"),
                 "pid": e.get("pid", 0),
                 "tid": e.get("tid", 0),
@@ -531,26 +603,31 @@ def device_ops(doc: dict) -> list[dict]:
 
 
 def scope_of(op: dict) -> str:
-    """Attribution bucket of one device op: a `SCOPE_NAMES` member when the
-    qualified op name carries one (TPU captures put the ``named_scope``
-    path in the op name), else the `hlo_analysis.classify_op_name` bucket
-    (``collectives`` / ``kernels`` / ``glue``)."""
-    name = op["name"]
+    """Attribution bucket of one device op, in the program's names: the
+    kernel variant a Pallas launch is (`KERNEL_VARIANT`), else the
+    `SCOPE_NAMES` member its scope path carries — the op's ``op_name``
+    where the capture records it, else the event's name — else the
+    op's kind as a bucket (``collectives`` / ``kernels`` / ``glue``)."""
+    path = op.get("op_name") or op["name"]
+    if op_kind(op) == "kernel":
+        m = KERNEL_VARIANT.search(path) or KERNEL_VARIANT.search(op["name"])
+        if m:
+            return m.group(0)
     for scope in SCOPE_NAMES:
-        if scope in name:
+        if scope in path:
             return scope
-    kind = _hlo.classify_op_name(op["hlo_op"] or name)
     return {"collective": "collectives", "kernel": "kernels"}.get(
-        kind, "glue"
+        op_kind(op), "glue"
     )
 
 
 def op_kind(op: dict) -> str:
-    """``collective`` | ``kernel`` | ``glue`` of one device op (by the
-    blessed name vocabulary — scope membership does not change what the op
-    IS; a collective inside ``igg_slab_exchange_begin`` still counts as
-    comm time in the overlap measure)."""
-    return _hlo.classify_op_name(op["hlo_op"] or op["name"])
+    """``collective`` | ``kernel`` | ``glue`` of one device op (the opcode
+    a TPU capture records, else the blessed name vocabulary — scope
+    membership does not change what the op IS; a collective inside
+    ``igg_slab_exchange_begin`` still counts as comm time in the overlap
+    measure)."""
+    return op.get("kind") or _hlo.classify_op_name(op["hlo_op"] or op["name"])
 
 
 # -- interval arithmetic (overlap measure) ------------------------------------
@@ -817,6 +894,46 @@ DEVICE_TID_BASE = 10_000
 STALE_META_TOL_S = 2.0
 
 
+def _span_key(name: str, args: dict | None) -> tuple:
+    """A program span's identity across the ring and a capture: its name
+    and its scalar tag values as strings (a capture's annotation holds its
+    arguments as strings — a step annotation's under ``step_num`` for its
+    numbering tag, beside profiler-private ``_`` keys)."""
+    vals = sorted(
+        str(v) for k, v in (args or {}).items()
+        if not str(k).startswith("_") and isinstance(v, (str, int, float))
+    )
+    return name, tuple(vals)
+
+
+def _span_anchor(events: list, rank: int, trace_doc: dict,
+                 guess_us: float) -> tuple[float, float, str] | None:
+    """``(capture µs, merged-timeline µs, name)`` of the first program span
+    (``igg.*``) of a capture that is also on ``rank``'s host track of the
+    merged timeline, or None.  ``guess_us`` (capture µs -> merged µs by the
+    ``start_trace`` anchor) picks among host spans of the same identity."""
+    host: dict[tuple, list[float]] = {}
+    for e in events:
+        if e.get("ph") == "X" and e.get("pid") == rank and e.get("tid") == 0:
+            host.setdefault(_span_key(e["name"], e.get("args")), []).append(
+                e["ts"]
+            )
+    caps = sorted(
+        (e for e in trace_doc.get("traceEvents", [])
+         if isinstance(e, dict) and e.get("ph") == "X"
+         and str(e.get("name", "")).startswith("igg.")
+         and isinstance(e.get("ts"), (int, float))),
+        key=lambda e: e["ts"],
+    )
+    for c in caps:
+        cands = host.get(_span_key(c["name"], c.get("args")))
+        if cands:
+            guess = c["ts"] + guess_us
+            return (float(c["ts"]), min(cands, key=lambda t: abs(t - guess)),
+                    c["name"])
+    return None
+
+
 def attach_device_tracks(
     doc: dict, metas: Sequence[str | os.PathLike | dict]
 ) -> dict:
@@ -825,13 +942,19 @@ def attach_device_tracks(
     ``doc`` is `tracing.merge_trace_files` output; ``metas`` are capture
     meta files (or loaded dicts) from the same run's ranks.  Each rank's
     device ops land on ITS host track's pid (new ``DEVICE_TID_BASE + k``
-    tids, one per original device thread), aligned by anchoring the
-    capture's first device-op timestamp at the host ``start_trace``
-    instant (the meta's ``t_start_perf`` sample) and riding the host
-    track's barrier offset.  The honesty bound: that anchor is accurate to
-    the profiler's start latency (ms-scale) — recorded per rank in
-    ``otherData.device_alignment``, never silently claimed tighter.  The
-    result still passes `tracing.validate_chrome_trace`.
+    tids, one per original device thread), riding the host track's barrier
+    offset.  Alignment: a program span present both on the rank's host
+    track and in the capture's host plane (`_span_anchor`: every
+    `tracing.trace_span` is a profiler annotation too) pins the capture's
+    clock to the host track's — accurate to the microseconds between the
+    annotation's entry and the span's ring timestamp.  A capture holding no
+    such span falls back to anchoring its first device op at the host
+    ``start_trace`` instant (the meta's ``t_start_perf``), accurate to the
+    profiler's start latency (ms-scale).  Either way the capture's own
+    mapping of device time to host time stays as recorded (device ops up
+    to 0.7 ms early on a v5e capture).  Which anchor each rank got is
+    recorded in ``otherData.device_alignment``.  The result still passes
+    `tracing.validate_chrome_trace`.
     """
     alignment = doc.get("otherData", {}).get("clock_alignment")
     if alignment is None:
@@ -842,12 +965,17 @@ def attach_device_tracks(
     base_us = float(alignment.get("ts_zero_offset_s", 0.0)) * 1e6
     dev_align: dict[str, Any] = {
         "note": (
-            "device tracks are aligned by anchoring each rank's first "
-            "captured device op at its host start_trace instant "
-            "(profile.p<rank>.json t_start_perf); the anchor error is the "
-            "profiler start latency — ms-scale — ON TOP of the host "
-            "clock_alignment uncertainty, so cross-track ordering finer "
-            "than that is not trustworthy."
+            "each rank's capture is aligned on a program span present both "
+            "on its host track and in the capture's host plane (anchor "
+            "'span': microseconds between the annotation's entry and the "
+            "ring's timestamp); without one, its first captured device op "
+            "is anchored at the host start_trace instant (anchor "
+            "'start_trace': the profiler start latency, ms-scale).  Neither "
+            "corrects the capture's own device-to-host clock mapping (a v5e "
+            "capture shows device ops up to 0.7 ms before the span that "
+            "dispatched them), and both come ON TOP of the host "
+            "clock_alignment uncertainty: cross-track ordering finer than "
+            "about a millisecond is not trustworthy."
         ),
         "per_rank": {},
     }
@@ -907,15 +1035,16 @@ def attach_device_tracks(
                 f"re-run the capture alongside the current trace dumps."
             )
         trace_path = resolve_trace_path(meta, meta_dir)
-        ops = (
-            device_ops(load_trace(trace_path))
+        trace_doc = (
+            load_trace(trace_path)
             if trace_path and meta.get("t_start_perf") is not None
-            else []  # load_trace raising here is still pre-mutation
+            else {}  # load_trace raising here is still pre-mutation
         )
-        plans.append((meta, per, trace_path, ops))
+        plans.append((meta, per, trace_path, trace_doc))
     # Phase 2 — attach the validated ranks' device tracks.
-    for meta, per, trace_path, ops in plans:
+    for meta, per, trace_path, trace_doc in plans:
         rank = meta["rank"]
+        ops = device_ops(trace_doc)
         entry: dict[str, Any] = {"trace": trace_path, "n_ops": 0}
         dev_align["per_rank"][str(rank)] = entry
         if not trace_path or meta.get("t_start_perf") is None:
@@ -924,13 +1053,21 @@ def attach_device_tracks(
         if not ops:
             entry["note"] = "capture holds no device ops"
             continue
+        # host merged-timeline µs of device ts, first guess: the first
+        # device op at the capture-start perf instant, through this rank's
+        # host offset, minus the merge's zero re-base.
         t0 = min(op["ts"] for op in ops)
-        # host merged-timeline µs of device ts: the capture-start perf
-        # instant, through this rank's host offset, minus the merge's
-        # zero re-base.
         anchor_us = (
             (meta["t_start_perf"] + per["offset_s"]) * 1e6 - base_us
         )
+        pinned = _span_anchor(events, rank, trace_doc, anchor_us - t0)
+        if pinned is None:
+            entry["anchor"] = "start_trace"
+        else:
+            cap_us, host_us, name = pinned
+            t0, anchor_us = cap_us, host_us
+            entry["anchor"] = "span"
+            entry["anchor_span"] = name
         tids = sorted({op["tid"] for op in ops})
         tid_map = {t: DEVICE_TID_BASE + i for i, t in enumerate(tids)}
         for t in tids:

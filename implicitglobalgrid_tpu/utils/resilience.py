@@ -1347,27 +1347,24 @@ def _guarded_loop_body(step_fn, state, nt, it, guard, enabled,
                        sync_every_step, model, tele) -> tuple:
     import jax
 
-    from jax.profiler import TraceAnnotation
-
     while it < nt:
         # The ``igg.step`` host span (docs/observability.md): one span per
         # loop iteration — dispatch + sync + guard pipeline, the same wall
         # time the step_seconds histogram records — tagged so a merged
-        # cross-rank trace aligns steps BY NUMBER; the profiler annotation
-        # rides along for on-device captures.  Untraced loops reuse the
-        # shared null managers (the zero-allocation contract).
+        # cross-rank trace aligns steps BY NUMBER, and in an on-device
+        # capture's host plane through the span's own profiler annotation.
+        # Untraced loops reuse the shared null manager (the
+        # zero-allocation contract).
         if tele is None:
-            span = ann = _NULL_CM
+            span = _NULL_CM
         else:
             span = _tracing.trace_span("igg.step", model=model, step=it + 1)
-            ann = TraceAnnotation(f"igg_step[{model}]")
         with span:
             # Shadow-audit retention (docs/robustness.md): off-cadence steps
             # pay one `is not None`-style check; on-cadence steps snapshot
             # the pre-step state the audit re-executes from.
             pre = guard.audit_snapshot(state, it) if enabled else None
-            with ann:
-                state = step_fn(*state)
+            state = step_fn(*state)
             if sync_every_step:
                 jax.block_until_ready(state)
             it += 1

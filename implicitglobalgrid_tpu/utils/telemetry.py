@@ -28,6 +28,12 @@ those numbers (docs/observability.md):
   count, so ``T_eff = bytes_model / t_step`` is a lower bound on achieved
   HBM traffic), plus an optional rank-0 heartbeat line every
   ``IGG_HEARTBEAT_EVERY`` steps.
+* **Compile counters** — one `jax.monitoring` listener, installed the
+  first time telemetry is used, turns JAX's own compile events into
+  ``compile.backend`` / ``compile.backend_seconds`` (every backend compile,
+  persistent-cache reads included), ``compile.cache_hits`` /
+  ``compile.cache_misses`` and one ``igg.compile`` span per backend
+  compile, tagged with the compiled function's name.
 
 Zero overhead when disabled: with ``IGG_TELEMETRY=0`` every accessor
 returns a shared no-op singleton (`counter`/`gauge`/`histogram`) or ``None``
@@ -44,6 +50,7 @@ from __future__ import annotations
 import collections
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any, Callable, Sequence
@@ -75,8 +82,73 @@ __all__ = [
 
 
 def enabled() -> bool:
-    """The ``IGG_TELEMETRY`` master switch (read per call, like IGG_DONATE)."""
-    return _config.telemetry_enabled_env()
+    """The ``IGG_TELEMETRY`` master switch (read per call, like IGG_DONATE).
+    The first call that finds it on in a process that has imported jax also
+    installs the compile listener (`_install_compile_listener`)."""
+    on = _config.telemetry_enabled_env()
+    if on and not _compile_listener_on:
+        _install_compile_listener()
+    return on
+
+
+# -- JAX's compile events -------------------------------------------------------
+
+#: the monitoring event of every backend compile, a persistent-cache read
+#: included: a time span tagged with the compiled function's ``fun_name``
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+#: persistent compile-cache events -> the counters they feed
+CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "compile.cache_hits",
+    "/jax/compilation_cache/cache_misses": "compile.cache_misses",
+}
+
+_compile_listener_on = False
+_listener_lock = threading.Lock()
+
+
+def _on_jax_event(event: str, **kwargs: Any) -> None:
+    name = CACHE_EVENTS.get(event)
+    if name is not None:
+        counter(name).inc()
+
+
+def _on_jax_time_span(event: str, start_time: float, end_time: float,
+                      **kwargs: Any) -> None:
+    if event != BACKEND_COMPILE_EVENT:
+        return
+    dur = end_time - start_time
+    counter("compile.backend").inc()
+    counter("compile.backend_seconds").inc(dur)
+    from . import tracing as _tracing
+
+    # the event's clock is time.time(); the span ring's is perf_counter
+    t0 = time.perf_counter() - (time.time() - start_time)
+    _tracing.record_span(
+        "igg.compile", t0=t0, dur=dur, fun_name=str(kwargs.get("fun_name", ""))
+    )
+
+
+def _install_compile_listener() -> None:
+    """Turn JAX's compile events into this registry's numbers, once per
+    process (a `reset` keeps the listeners): ``compile.backend`` /
+    ``compile.backend_seconds`` and one ``igg.compile`` ring span per
+    backend compile, ``compile.cache_hits`` / ``compile.cache_misses`` per
+    persistent-cache lookup.  Nothing happens before jax is imported — a
+    jax-free process compiles nothing — and the listeners feed the
+    registry only while telemetry is on."""
+    global _compile_listener_on
+    if "jax" not in sys.modules:
+        return
+    with _listener_lock:
+        if _compile_listener_on:
+            return
+        _compile_listener_on = True
+        try:
+            from jax import monitoring
+        except Exception:
+            return
+        monitoring.register_event_listener(_on_jax_event)
+        monitoring.register_event_time_span_listener(_on_jax_time_span)
 
 
 # -- Metric types -------------------------------------------------------------

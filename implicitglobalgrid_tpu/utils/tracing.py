@@ -15,8 +15,13 @@ cross-rank half (docs/observability.md):
   (``igg_halo_exchange``, ``igg_slab_exchange_begin`` ... — see
   `jax.named_scope`), so a host span and the device ops it
   dispatched correlate BY NAME across a merged trace and a profiler
-  capture.  With ``IGG_TELEMETRY=0`` (or ``IGG_TRACE_RING=0``) every call
-  returns one shared no-op singleton — no allocation, no clock reads.
+  capture.  Each span also enters a `jax.profiler.TraceAnnotation` of the
+  same name (scalar tags as its arguments), so inside a live profiler
+  session every program span lands in the capture's host plane on the
+  device ops' clock; with no session none is made.  `spanned_calls` wraps
+  a callable in one span per call (``igg.multi_step``).  With
+  ``IGG_TELEMETRY=0`` (or ``IGG_TRACE_RING=0``) every call returns one
+  shared no-op singleton — no allocation, no clock reads, no annotation.
 * **Merged timeline** — `dump_trace(dir)` writes this rank's spans plus its
   clock-sync anchor as ``trace.p<rank>.json``; `merge_trace_files` joins
   any set of per-rank files into ONE valid Chrome-trace/Perfetto JSON with
@@ -70,6 +75,7 @@ import collections
 import json
 import os
 import random
+import sys
 import threading
 import time
 from typing import Any, Sequence
@@ -79,6 +85,7 @@ from . import telemetry as _telemetry
 
 __all__ = [
     "trace_span",
+    "spanned_calls",
     "record_span",
     "span_records",
     "span_summary",
@@ -274,13 +281,15 @@ class use_context:
                     _ctx_stacks.pop(ident, None)
 
 
-def _ring_push(name: str, t0: float, dur: float, tags: dict | None) -> None:
+def _ring_push(name: str, t0: float, dur: float, tags: dict | None,
+               cap: int | None = None) -> None:
     """Append one closed span, counting evictions: a deque at maxlen
     silently drops its oldest on append, and a silently-truncated ring
     reconstructs into a silently-partial request tree — so the drop count
-    rides every dump and readers can refuse to pretend completeness."""
+    rides every dump and readers can refuse to pretend completeness.
+    ``cap``: the ring capacity, where the caller has just read it."""
     global _spans_dropped
-    ring = _get_ring(_ring_capacity())
+    ring = _get_ring(_ring_capacity() if cap is None else cap)
     if ring.maxlen is not None and len(ring) >= ring.maxlen:
         _spans_dropped += 1
         _telemetry.counter("trace.spans_dropped_total").inc()
@@ -294,24 +303,64 @@ def spans_dropped() -> int:
     return _spans_dropped
 
 
+# The profiler's annotation class, resolved by the first span that finds
+# jax imported: a process without jax has no profiler session to write
+# into, and importing jax from here would cost the jax-free tools (the
+# supervisor, the fleet tier) their start-up.  False once resolving failed.
+_annotations: type | bool | None = None
+
+
+def _profiler_annotation(name: str, tags: dict | None):
+    """A `jax.profiler.TraceAnnotation` named ``name`` whose arguments are
+    the span's scalar tags, or None where no profiler session is recording
+    (or jax is not imported): an annotation made outside a session is never
+    written, so it is not made.
+
+    Never a `StepTraceAnnotation`: with a step marker per call, the v5e
+    profiler left the device idle for 0.45-2.9 s of each of four warm 10 s
+    traced windows of the periodic-z benchmark cell; plain annotations in
+    the same windows, never."""
+    global _annotations
+    if _annotations is None:
+        if "jax" not in sys.modules:
+            return None
+        try:
+            from jax.profiler import TraceAnnotation
+        except Exception:
+            _annotations = False
+            return None
+        _annotations = TraceAnnotation
+    if _annotations is False or not _annotations.is_enabled():
+        return None
+    kw = {
+        k: v for k, v in tags.items() if isinstance(v, (str, int, float))
+    } if tags else {}
+    return _annotations(name, **kw)
+
+
 class _Span:
     """One live span.  Records itself into the ring on exit; re-entrant
     use records one span per enter/exit pair.  While executing it sits on
-    this thread's open-span stack (see `open_spans`); a span that resolved
-    a request context additionally pushes its own (child) context so
-    anything nested chains under it (`current_context`)."""
+    this thread's open-span stack (see `open_spans`) and inside a profiler
+    annotation of the same name (`_profiler_annotation`); a span that
+    resolved a request context additionally pushes its own (child) context
+    so anything nested chains under it (`current_context`)."""
 
-    __slots__ = ("name", "tags", "t0", "ctx")
+    __slots__ = ("name", "tags", "t0", "ctx", "ann", "cap", "ident")
 
     def __init__(self, name: str, tags: dict | None,
-                 ctx: dict | None = None):
+                 ctx: dict | None = None, cap: int | None = None):
         self.name = name
         self.tags = tags
         self.ctx = ctx
+        self.cap = cap
 
     def __enter__(self) -> "_Span":
+        self.ann = _profiler_annotation(self.name, self.tags)
+        if self.ann is not None:
+            self.ann.__enter__()
         self.t0 = time.perf_counter()
-        ident = threading.get_ident()
+        self.ident = ident = threading.get_ident()
         stack = _open_stacks.get(ident)
         if stack is None:
             stack = _open_stacks[ident] = []
@@ -325,7 +374,7 @@ class _Span:
 
     def __exit__(self, *exc) -> None:
         t1 = time.perf_counter()
-        ident = threading.get_ident()
+        ident = self.ident  # a span exits on the thread that entered it
         stack = _open_stacks.get(ident)
         if stack:
             stack.pop()
@@ -337,7 +386,9 @@ class _Span:
                 cstack.pop()
                 if not cstack:
                     _ctx_stacks.pop(ident, None)
-        _ring_push(self.name, self.t0, t1 - self.t0, self.tags)
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
+        _ring_push(self.name, self.t0, t1 - self.t0, self.tags, self.cap)
 
 
 class _NoopSpan:
@@ -364,6 +415,11 @@ def trace_span(name: str, *, parent: dict | None = None, **tags: Any):
     Returns the shared `NOOP_SPAN` when tracing is disabled — the
     zero-overhead contract of the rest of the registry.
 
+    The span also enters a `jax.profiler.TraceAnnotation` of the same
+    name, its scalar tags as the annotation's arguments: inside a live
+    profiler session the span is in the capture's host plane, on the
+    device ops' clock.
+
     Request context: ``parent=`` (a ``{"trace_id", "span_id"}`` dict) or —
     when omitted — the ambient context (`use_context` / an enclosing
     context-carrying span) threads a request's identity into the span.  A
@@ -374,22 +430,53 @@ def trace_span(name: str, *, parent: dict | None = None, **tags: Any):
     per-request spans.  With no context in scope the span records exactly
     as before — no ids, no extra allocation.
     """
-    if not enabled():
+    if not _telemetry.enabled():
+        return NOOP_SPAN
+    cap = _ring_capacity()  # `enabled`, reading the ring's size once
+    if cap <= 0:
         return NOOP_SPAN
     ctx = parent if parent is not None else current_context()
     if ctx is None:
-        return _Span(name, tags or None)
+        return _Span(name, tags or None, cap=cap)
     if "trace_ids" in ctx:
         tags["trace_ids"] = list(ctx["trace_ids"])
-        return _Span(name, tags, ctx=ctx)
+        return _Span(name, tags, ctx=ctx, cap=cap)
     sid = new_span_id()
     tags["trace_id"] = ctx["trace_id"]
     tags["span_id"] = sid
     if ctx.get("span_id"):
         tags["parent_id"] = ctx["span_id"]
     return _Span(
-        name, tags, ctx={"trace_id": ctx["trace_id"], "span_id": sid}
+        name, tags, ctx={"trace_id": ctx["trace_id"], "span_id": sid},
+        cap=cap,
     )
+
+
+class spanned_calls:
+    """``fn`` with one ``name`` span (`trace_span`) around each call,
+    tagged ``tags`` plus ``call``: this wrapper's own count of its calls
+    from 0.  Arguments and results pass through untouched (donation
+    included); every other attribute — ``lower``, ``__wrapped__``, a jit's
+    ``trace`` — is ``fn``'s own."""
+
+    __slots__ = ("_inner", "_name", "_tags", "_calls")
+
+    def __init__(self, fn, name: str, **tags: Any):
+        self._inner = fn
+        self._name = name
+        self._tags = tags
+        self._calls = 0
+
+    def __call__(self, *args, **kwargs):
+        n = self._calls
+        self._calls = n + 1
+        with trace_span(self._name, call=n, **self._tags):
+            return self._inner(*args, **kwargs)
+
+    def __getattr__(self, attr: str):
+        if attr == "_inner":  # not yet set (copy/pickle): no recursion
+            raise AttributeError(attr)
+        return getattr(self._inner, attr)
 
 
 def record_span(name: str, *, t0: float, dur: float,

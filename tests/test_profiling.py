@@ -32,6 +32,13 @@ from implicitglobalgrid_tpu.utils import tracing
 _here = os.path.dirname(os.path.abspath(__file__))
 _repo = os.path.dirname(_here)
 FIXTURE = os.path.join(_here, "data", "profile_fixture.trace.json.gz")
+#: a capture recorded on one TPU v5e (three calls of the periodic-z
+#: diffusion cadence at 128^3, k=4, 8 steps a call, each call inside an
+#: ``igg.step`` span), trimmed to its device op lines and program spans, and
+#: the span ring the same process dumped (`tracing.dump_trace`)
+V5E = os.path.join(_here, "data", "v5e_capture.trace.json.gz")
+V5E_SPANS = os.path.join(_here, "data", "v5e_capture.p0.json")
+V5E_T_START_PERF = 45.112375355  # perf_counter right after start_trace
 
 sys.path.insert(0, os.path.join(_repo, "scripts"))
 import igg_prof  # noqa: E402  (scripts/ CLI under test)
@@ -97,6 +104,71 @@ def test_classify_op_name_vocabulary():
 
 
 # -- fixture attribution (golden) ---------------------------------------------
+
+
+def test_v5e_capture_attribution_golden(capsys):
+    """On the chip's capture, device time lands in the program's names: the
+    kernel variant (its instruction name and ``op_name``), the z-patch
+    scopes (``op_name`` only), and XLA's own copies, which carry no scope,
+    as glue.  Module events and async slices in flight are no ops."""
+    rec = profiling.attribute_trace(V5E)
+    assert rec["n_device_ops"] == 99
+    assert rec["scope_seconds"] == pytest.approx({
+        "glue": 0.000138862,
+        "igg_diffusion_k4_zpatch": 0.000739652,
+        "igg_zpatch_apply": 0.000448734,
+        "igg_zpatch_from_export": 2.76e-07,
+        "igg_zpatch_identity": 7.9265e-05,
+        "kernels": 2e-09,
+    })
+    assert rec["overlap"]["fraction"] is None  # one chip: no collectives
+    assert igg_prof.main(["attribute", V5E, "--json"]) == 0
+    assert json.loads(capsys.readouterr().out)["scope_seconds"] == (
+        rec["scope_seconds"])
+
+
+def _v5e_merged(trace_path=V5E):
+    doc = tracing.merge_trace_files([V5E_SPANS])
+    sync = json.load(open(V5E_SPANS))["clock_sync"]
+    meta = {"schema": profiling.PROFILE_SCHEMA, "rank": 0,
+            "t_start_perf": V5E_T_START_PERF, "wall_start": sync["wall"],
+            "trace_path": trace_path, "window": [1, 3]}
+    return profiling.attach_device_tracks(doc, [meta])
+
+
+def test_v5e_capture_aligns_on_a_program_span():
+    """The device track is placed by the ``igg.step`` span that is both in
+    the ring and in the capture's host plane: each device op keeps, to the
+    microsecond, its distance from that span as the capture recorded it."""
+    doc = _v5e_merged()
+    assert tracing.validate_chrome_trace(doc) == []
+    per = doc["otherData"]["device_alignment"]["per_rank"]["0"]
+    assert per["anchor"] == "span" and per["anchor_span"] == "igg.step"
+    assert per["n_ops"] == 99
+    xs = [e for e in doc["traceEvents"] if e.get("ph") == "X"]
+    step1 = next(e for e in xs if e["name"] == "igg.step"
+                 and e["args"] == {"model": "probe", "step": 1})
+    first = min(e["ts"] for e in xs if e["tid"] >= profiling.DEVICE_TID_BASE)
+    cap = json.load(gzip.open(V5E, "rt"))["traceEvents"]
+    cap_step1 = next(e["ts"] for e in cap if e.get("name") == "igg.step")
+    cap_first = min(e["ts"] for e in cap if e.get("ph") == "X" and
+                    "long_name" in (e.get("args") or {}))
+    assert first - step1["ts"] == pytest.approx(cap_first - cap_step1, abs=1e-3)
+
+
+def test_v5e_capture_without_program_spans_anchors_at_start(tmp_path):
+    """A capture holding no program span falls back to the start_trace
+    anchor, and says so."""
+    cap = json.load(gzip.open(V5E, "rt"))
+    cap["traceEvents"] = [e for e in cap["traceEvents"]
+                          if not str(e.get("name", "")).startswith("igg.")]
+    path = str(tmp_path / "nospans.trace.json.gz")
+    with gzip.open(path, "wt") as f:
+        json.dump(cap, f)
+    doc = _v5e_merged(path)
+    per = doc["otherData"]["device_alignment"]["per_rank"]["0"]
+    assert per["anchor"] == "start_trace" and per["n_ops"] == 99
+    assert tracing.validate_chrome_trace(doc) == []
 
 
 def test_fixture_attribution_golden():

@@ -99,6 +99,70 @@ def test_disabled_model_run_records_nothing(monkeypatch):
     assert tele.snapshot()["counters"] == {}
 
 
+# -- Compile counters ---------------------------------------------------------
+
+
+def _compile_counters():
+    c = tele.snapshot()["counters"]
+    return {k: c.get(k, 0) for k in
+            ("compile.backend", "compile.cache_hits", "compile.cache_misses")}
+
+
+def test_compile_counters_count_backend_compiles():
+    """A new program adds one backend compile (and one ``igg.compile`` span
+    naming it), a repeat call adds none; the counting survives a reset."""
+    from implicitglobalgrid_tpu.utils import tracing
+
+    x = jnp.arange(7.0)
+    f = jax.jit(lambda v: v * 3.0 - 1.0)
+    assert tele.enabled()
+    tele.reset()
+    tracing.reset()
+    f(x)
+    assert _compile_counters()["compile.backend"] == 1
+    assert tele.snapshot()["counters"]["compile.backend_seconds"] > 0
+    f(x)
+    assert _compile_counters()["compile.backend"] == 1
+    (span,) = [r for r in tracing.span_records() if r["name"] == "igg.compile"]
+    assert "lambda" in span["args"]["fun_name"] and span["dur"] > 0
+    tele.reset()  # the listener stays: the next program still counts
+    jax.jit(lambda v: v * 4.0)(x)
+    assert _compile_counters()["compile.backend"] == 1
+    tracing.reset()
+
+
+def test_compile_cache_miss_then_hit(tmp_path):
+    """On a fresh persistent compile cache, a program is one miss (compiled
+    and written), then — in-memory caches cleared — one hit."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    keys = ("jax_enable_compilation_cache", "jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    prev = {k: getattr(jax.config, k) for k in keys}
+    x = jnp.arange(5.0)
+    f = jax.jit(lambda v: v * 5.0 + 2.0)
+    try:
+        for k, v in zip(keys, (True, str(tmp_path), 0, -1)):
+            jax.config.update(k, v)
+        cc.reset_cache()
+        assert tele.enabled()
+        tele.reset()
+        f(x)
+        assert _compile_counters() == {
+            "compile.backend": 1, "compile.cache_hits": 0,
+            "compile.cache_misses": 1}
+        jax.clear_caches()
+        f(x)
+        assert _compile_counters() == {
+            "compile.backend": 2, "compile.cache_hits": 1,
+            "compile.cache_misses": 1}
+    finally:
+        for k, v in prev.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+
+
 # -- Event log ----------------------------------------------------------------
 
 

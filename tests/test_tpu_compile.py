@@ -10,6 +10,7 @@ nothing about results or times.
 """
 
 import os
+import re
 
 import pytest
 
@@ -34,6 +35,25 @@ PROGRAMS = {
         "diffusion3d", 256, (2, 2, 1), (8, 8, 2), 8,
         dict(fused_k=4, fused_tile=(32, 64), pipelined=True),
         dict(overlapx=8, overlapy=8)),
+    "diffusion_k4_128_periodz": ("diffusion3d", 128, (1, 1, 1), (2, 2, 8), 8,
+                                 dict(fused_k=4), {}),
+}
+#: programs on a grid with periodic dimensions (x, y, z); others have none
+PERIODS = {"diffusion_k4_128_periodz": (0, 0, 1)}
+
+#: what each program's text must name: the kernel variants (the `name=` of
+#: each `pallas_call`) and the self-neighbour z exchange's scopes, as a
+#: device trace of the program names them
+NAMES = {
+    "diffusion_k4_512_tile32x128": ["igg_diffusion_k4_plain"],
+    "diffusion_k4_256": ["igg_diffusion_k4_plain"],
+    "porous_k6_npt10_256": ["igg_pt_k6_plain"],
+    "acoustic_k6_256": ["igg_leapfrog_k6_plain"],
+    "four_chip_diffusion_pipelined": ["igg_diffusion_k4_plain_ring01",
+                                      "igg_diffusion_k4_plain_mid01"],
+    "diffusion_k4_128_periodz": ["igg_diffusion_k4_zpatch",
+                                 "igg_zpatch_identity", "igg_zpatch_apply",
+                                 "igg_zpatch_from_export"],
 }
 
 
@@ -83,8 +103,9 @@ def compiled(topo):
                                       **setup_kw)
             local = [tuple(s + n - 16 for s in A.shape) for A in state]
             igg.finalize_global_grid()
-            with synthetic_topology_grid(dims, (n,) * 3, ovl, topo) as (
-                    _, mesh):
+            with synthetic_topology_grid(
+                    dims, (n,) * 3, ovl, topo,
+                    periods=PERIODS.get(name, (0, 0, 0))) as (_, mesh):
                 sh = NamedSharding(mesh, P("x", "y", "z"))
                 shapes = tuple(
                     jax.ShapeDtypeStruct(
@@ -117,3 +138,15 @@ def test_four_chip_program_keeps_its_collectives(compiled):
     txt = compiled["four_chip_diffusion_pipelined"]
     # two groups x (x, y) slab exchanges, each a send/recv pair per side
     assert txt.count("collective-permute-start") >= 8
+
+
+@pytest.mark.parametrize("name", sorted(PROGRAMS))
+def test_kernel_variants_are_named(compiled, name):
+    txt = compiled[name]
+    for want in NAMES[name]:
+        assert want in txt, f"{name}: {want} not in the compiled program"
+    kernels = [ln for ln in txt.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    assert kernels and all(
+        re.match(r"\s*(ROOT\s+)?%igg_\w+_k\d+_(plain|zpatch)", ln)
+        for ln in kernels), f"{name}: an unnamed Mosaic kernel"

@@ -556,3 +556,126 @@ def test_perf_gate_reports_achieved_fraction():
     assert not any("achieved_fraction" in k for k in gate_metrics(record))
     verdict = gate_summary(record, _repo)
     assert verdict["reported"] == rep
+
+
+# -- spans on the profiler's clock --------------------------------------------
+
+
+def _xplane_events(logdir):
+    """``[(name, {stat: str(value)})]`` of every event in the one
+    ``.xplane.pb`` a profiler session wrote under ``logdir``."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(str(logdir), "**", "*.xplane.pb"),
+                        recursive=True)
+    return [
+        (e.name, {k: str(v) for k, v in e.stats})
+        for plane in ProfileData.from_file(path).planes
+        for line in plane.lines
+        for e in line.events
+    ]
+
+
+def test_trace_span_lands_in_profiler_capture(tmp_path):
+    """A span inside a live profiler session is an annotation of the same
+    name in the capture's host plane, its scalar tags as arguments; a
+    `spanned_calls` call's annotation carries its call count (a plain
+    annotation, no step marker).  The ring records both as before."""
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tracing.trace_span("igg.test_span", step=3, kind="probe",
+                                trace_ids=["a", "b"]):
+            pass
+        calls = tracing.spanned_calls(lambda x: x + 1, "igg.test_calls",
+                                      model="m")
+        assert calls(1) == 2 and calls(2) == 3
+    finally:
+        jax.profiler.stop_trace()
+    evs = _xplane_events(tmp_path)
+    assert [s for n, s in evs if n == "igg.test_span"] == [
+        {"step": "3", "kind": "probe"}  # a list tag is no annotation argument
+    ]
+    steps = [s for n, s in evs if n == "igg.test_calls"]
+    assert steps == [{"call": "0", "model": "m"}, {"call": "1", "model": "m"}]
+    recs = tracing.span_records()
+    assert [r["name"] for r in recs] == [
+        "igg.test_span", "igg.test_calls", "igg.test_calls"
+    ]
+    assert [r["args"]["call"] for r in recs[1:]] == [0, 1]
+
+
+def test_disabled_span_writes_no_annotation(monkeypatch, tmp_path):
+    """``IGG_TELEMETRY=0``: the shared no-op span, no ring record and no
+    annotation in a live capture; the wrapper still calls through."""
+    monkeypatch.setenv("IGG_TELEMETRY", "0")
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        span = tracing.trace_span("igg.off_span", step=1)
+        assert span is tracing.NOOP_SPAN
+        with span:
+            pass
+        assert tracing.spanned_calls(lambda x: 2 * x, "igg.off_calls")(4) == 8
+    finally:
+        jax.profiler.stop_trace()
+    assert not [n for n, _ in _xplane_events(tmp_path) if n.startswith("igg.off")]
+    assert tracing.span_records() == []
+
+
+def _multi_step_case(case):
+    """``(callable, state)`` of one return path of a model's
+    ``make_multi_step``, at a tiny size, results not donated."""
+    import jax.numpy as jnp
+
+    from implicitglobalgrid_tpu.models import (
+        acoustic3d,
+        diffusion3d,
+        porous_convection3d,
+    )
+    from implicitglobalgrid_tpu.models._batched import batched_setup
+
+    one = jax.devices()[:1]
+    if case == "diffusion3d_stencil":
+        state, params = diffusion3d.setup(8, 8, 8, quiet=True)
+        return diffusion3d.make_multi_step(params, 2, donate=False), state
+    if case == "diffusion3d_jit_kernel":
+        # one block, no halo activity: the fused kernel under plain jax.jit
+        state, params = diffusion3d.setup(16, 16, 128, dtype=jnp.float32,
+                                          quiet=True, devices=one)
+        return diffusion3d.make_multi_step(params, 2, donate=False,
+                                           fused_k=2), state
+    if case == "diffusion3d_batched":
+        state, params = batched_setup(diffusion3d, 8, 8, 8, batch=2)
+        return diffusion3d.make_multi_step(params, 2, donate=False,
+                                           batch=True), state
+    if case == "acoustic3d":
+        state, params = acoustic3d.setup(8, 8, 8, quiet=True)
+        return acoustic3d.make_multi_step(params, 2, donate=False), state
+    state, params = porous_convection3d.setup(8, 8, 8, npt=2, quiet=True)
+    return porous_convection3d.make_multi_step(params, 2, donate=False), state
+
+
+@pytest.mark.parametrize("case", [
+    "diffusion3d_stencil", "diffusion3d_jit_kernel", "diffusion3d_batched",
+    "acoustic3d", "porous_convection3d",
+])
+def test_multi_step_records_one_span_per_call(case):
+    """Every return path of every model's ``make_multi_step``: one
+    ``igg.multi_step`` ring span per call numbered 0..n-1, ``.lower()``
+    still there, results bit-identical to the bare callable's."""
+    from jax.experimental.pallas.tpu import force_tpu_interpret_mode
+
+    with force_tpu_interpret_mode():
+        step, state = _multi_step_case(case)
+        assert step.lower(*state).compile() is not None
+        outs = [step(*state) for _ in range(3)]
+        bare = step._inner(*state)
+    spans = [r for r in tracing.span_records() if r["name"] == "igg.multi_step"]
+    model = case.split("_", 1)[0] if case.startswith("diffusion") else case
+    assert [s["args"] for s in spans] == [
+        {"call": i, "model": model, "nsteps": 2} for i in range(3)
+    ]
+    for out in outs:
+        for a, b in zip(jax.tree.leaves(out), jax.tree.leaves(bare)):
+            assert np.array_equal(np.asarray(a), np.asarray(b))
