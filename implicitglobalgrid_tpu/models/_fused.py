@@ -57,13 +57,29 @@ def run_group_schedule(chunks, body, carry, *, unroll_limit=8,
     win on 8 of them (advisor r4: the old shape sent such schedules
     entirely through the fori_loop).
 
+    On that (kernel) shape the fori_loop runs TWO groups per iteration,
+    ``body(k0, body(k0, c))``, over ``nloop // 2`` iterations; an odd
+    excess runs its one leftover group unrolled just before the loop.  A
+    while loop keeps each carry element in one buffer, and every fused
+    kernel is out of place (a tile reads the halo rows its neighbour tile
+    just wrote), so a one-kernel body's fresh output cannot take the
+    parameter's buffer while the parameter is live: XLA copies the whole
+    field back into the carry after every looped group (~204 us per 64 MiB
+    field on v5e, PERF.md §6).  With two chained kernels the second output
+    takes the parameter's buffer, dead once the first kernel has read it —
+    no copy, the same groups in the same order, bit-identical values.
+    Trace-time counters ``schedule.fori_paired_groups`` /
+    ``schedule.fori_single_groups`` count the looped groups of each shape.
+
     ``fori_excess_only=False`` restores the all-or-nothing shape: a uniform
-    run longer than the limit goes ENTIRELY through the fori_loop (the
-    ragged tail still unrolls).  The porous XLA cadence needs this — its
-    group bodies are large unrolled XLA programs whose bit-identity across
-    cadences relies on the fori boundary as a fusion barrier (unrolling the
-    last group lets XLA contract FMAs differently per surrounding context);
-    the Pallas paths are immune (fusion cannot reach inside a kernel).
+    run longer than the limit goes ENTIRELY through the fori_loop, one
+    group per iteration (the ragged tail still unrolls).  The porous XLA
+    cadence needs this — its group bodies are large unrolled XLA programs
+    whose bit-identity across cadences relies on the fori boundary as a
+    fusion barrier between groups (unrolling the last group, or pairing two
+    groups in one body, lets XLA contract FMAs differently per surrounding
+    context); the Pallas paths are immune (fusion cannot reach inside a
+    kernel).
     """
     prefix = 0
     while prefix < len(chunks) and chunks[prefix] == chunks[0]:
@@ -75,10 +91,24 @@ def run_group_schedule(chunks, body, carry, *, unroll_limit=8,
     if prefix > keep:
         from jax import lax
 
+        from ..utils import telemetry
+
         k0 = chunks[0]
         nloop = prefix - keep
-        carry = lax.fori_loop(0, nloop, lambda i, c: body(k0, c), carry)
         chunks = chunks[nloop:]
+        if fori_excess_only:
+            if nloop % 2:
+                carry = body(k0, carry)
+            if nloop >= 2:
+                carry = lax.fori_loop(
+                    0, nloop // 2, lambda i, c: body(k0, body(k0, c)), carry
+                )
+                telemetry.counter("schedule.fori_paired_groups").inc(
+                    nloop - nloop % 2
+                )
+        else:
+            carry = lax.fori_loop(0, nloop, lambda i, c: body(k0, c), carry)
+            telemetry.counter("schedule.fori_single_groups").inc(nloop)
     for ki in chunks:
         carry = body(ki, carry)
     return carry

@@ -41,6 +41,33 @@ def parse_computations(txt: str) -> dict[str, list[str]]:
     return comps
 
 
+def while_body_copies(txt: str, min_elems: int) -> list[tuple[str, str]]:
+    """``(body computation, instruction)`` of every ``copy``/``copy-start``
+    of at least ``min_elems`` elements inside a while-loop body.
+
+    A while loop keeps each carry element in one buffer; a body whose last
+    writer of a carry cannot take that buffer pays a whole-array copy per
+    iteration (the fused group schedule's carry copy,
+    `models._fused.run_group_schedule`).
+    """
+    comps = parse_computations(txt)
+    bodies = {
+        b for lines in comps.values() for l in lines
+        for b in re.findall(r"\bbody=%?([\w\.\-]+)", l)
+    }
+    out = []
+    for b in sorted(bodies):
+        for l in comps.get(b, ()):
+            m = _INST_RE.match(l)
+            if not m:
+                continue
+            name, rest = m.groups()
+            op = re.search(r"\s(copy|copy-start)\(", rest)
+            if op and _out_elems(rest[:op.start()]) >= min_elems:
+                out.append((b, name))
+    return out
+
+
 def _out_elems(typ: str) -> int:
     """Largest array size in an HLO type string (handles tuple types)."""
     best = 0

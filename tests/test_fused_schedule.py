@@ -1,12 +1,20 @@
-"""Group-schedule loop shape (`models/_fused.run_group_schedule`) and the
-deep-z envelope gate coupling (`ops/pallas_stencil`) — advisor r4 findings."""
+"""Group-schedule loop shape (`models/_fused.run_group_schedule`), its
+carry-copy-free paired fori body, and the deep-z envelope gate coupling
+(`ops/pallas_stencil`) — advisor r4 findings."""
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
+import implicitglobalgrid_tpu as igg
 from implicitglobalgrid_tpu.models._fused import run_group_schedule
 from implicitglobalgrid_tpu.ops.pallas_stencil import fused_support_error
+from implicitglobalgrid_tpu.utils import telemetry
+from implicitglobalgrid_tpu.utils.hlo_analysis import (
+    parse_computations,
+    while_body_copies,
+)
 
 
 def _traced(chunks):
@@ -34,16 +42,17 @@ def test_long_uniform_schedule_keeps_unrolled_groups():
     the documented 15-30% speedup for nsteps=24 at fused_k=2)."""
     out, ncalls = _traced([2] * 12)
     assert out == 24.0
-    # 8 unrolled traces + the fori body trace(s); strictly fewer than full
-    # unroll, strictly more than fori-only (1-2 traces).
-    assert 9 <= ncalls <= 10
+    # 8 unrolled traces + the paired fori body's trace(s), two groups each;
+    # strictly fewer than full unroll, strictly more than fori-only.
+    assert 10 <= ncalls <= 12
 
 
 def test_ragged_schedule_counts_tail_against_limit():
     out, ncalls = _traced([6] * 10 + [4])
     assert out == 64.0
-    # 7 unrolled prefix + 1 ragged tail + fori trace(s)
-    assert 9 <= ncalls <= 10
+    # 7 unrolled prefix + 1 ragged tail + the odd excess group unrolled
+    # before the paired fori body's trace(s), two groups each
+    assert 11 <= ncalls <= 13
 
 
 def test_all_or_nothing_shape_for_xla_cadences():
@@ -72,6 +81,86 @@ def test_all_or_nothing_shape_for_xla_cadences():
     )(jnp.float32(0))
     assert float(out) == 10.0
     assert calls == [6, 4]  # prefix of one group: fully unrolled
+
+
+_FIELD = (64, 128)
+
+
+def _opaque_body(ki, c):
+    """One opaque call with a fresh output, standing in for an out-of-place
+    Pallas kernel launch (fusion cannot reach inside it)."""
+    return jax.pure_callback(
+        lambda x: np.asarray(np.sin(x) + ki, x.dtype),
+        jax.ShapeDtypeStruct(c.shape, c.dtype), c,
+    )
+
+
+def _compiled_schedule(chunks, **kw):
+    f = jax.jit(lambda c: run_group_schedule(chunks, _opaque_body, c, **kw))
+    x = jnp.linspace(0.0, 1.0, _FIELD[0] * _FIELD[1],
+                     dtype=jnp.float32).reshape(_FIELD)
+    return f(x), f.lower(x).compile().as_text()
+
+
+@pytest.mark.parametrize("chunks", [[1] * 12, [1] * 12 + [2]],
+                         ids=["even_excess", "odd_excess_ragged"])
+def test_paired_fori_body_has_no_carry_copy(chunks):
+    """The kernel shape runs its fori excess two groups per iteration: the
+    second group's output takes the carry's buffer, so the while body holds
+    no whole-field copy (one group per iteration pays one per iteration,
+    the control below) — and the values are the fully unrolled schedule's,
+    bit for bit."""
+    out, txt = _compiled_schedule(chunks)
+    assert "while(" in txt  # the excess (4 or 5 groups) still loops
+    assert while_body_copies(txt, _FIELD[0] * _FIELD[1]) == []
+    ref, _ = _compiled_schedule(chunks, unroll_limit=len(chunks))
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+
+def test_xla_cadence_shape_keeps_one_group_per_iteration():
+    """`fori_excess_only=False` keeps one group per fori iteration (the
+    porous XLA cadence's fusion barrier between groups) — and with it the
+    carry copy the kernel shape removes."""
+    out, txt = _compiled_schedule([1] * 3, unroll_limit=1,
+                                  fori_excess_only=False)
+    (body,) = {b for b, _ in while_body_copies(txt, _FIELD[0] * _FIELD[1])}
+    calls = [l for l in parse_computations(txt)[body]
+             if "custom-call(" in l]
+    assert len(calls) == 1
+    ref, _ = _compiled_schedule([1] * 3, unroll_limit=3)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+
+def test_fori_group_counters():
+    """``schedule.fori_paired_groups`` counts the groups a compiled fused
+    cadence loops two per iteration (a 12-group k=4 diffusion chunk: 8
+    unrolled + 4 paired); ``schedule.fori_single_groups`` counts the porous
+    XLA cadence's one-per-iteration groups."""
+    from jax.experimental.pallas.tpu import force_tpu_interpret_mode
+
+    from implicitglobalgrid_tpu.models import diffusion3d
+    from implicitglobalgrid_tpu.models import porous_convection3d as pc
+
+    telemetry.reset()
+    state, params = diffusion3d.setup(16, 16, 128, dtype=jnp.float32,
+                                      quiet=True, devices=jax.devices()[:1])
+    with force_tpu_interpret_mode():
+        diffusion3d.make_multi_step(params, 48, fused_k=4).lower(*state)
+    igg.finalize_global_grid()
+    counters = telemetry.snapshot()["counters"]
+    assert counters.get("schedule.fori_paired_groups") == 4
+    assert "schedule.fori_single_groups" not in counters
+
+    telemetry.reset()
+    # one device: no shard_map, whose staging traces its body more than once
+    state, params = pc.setup(10, 10, 10, overlapx=4, overlapy=4, overlapz=4,
+                             npt=4, quiet=True, devices=jax.devices()[:1])
+    # two time steps x npt/exchange_every = 2 looped groups each
+    pc.make_multi_step(params, 2, donate=False, exchange_every=2).lower(*state)
+    counters = telemetry.snapshot()["counters"]
+    telemetry.reset()
+    assert counters.get("schedule.fori_single_groups") == 4
+    assert "schedule.fori_paired_groups" not in counters
 
 
 def test_deep_z_gate_and_budget_jointly_cover_by128():

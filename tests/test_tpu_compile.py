@@ -20,7 +20,7 @@ import jax.numpy as jnp
 # name: (model, local n (cube), dims, overlaps, nsteps, make_multi_step
 #        kwargs, extra setup kwargs)
 # Longest compile first (Mosaic time grows with the tile window: ~180, 145,
-# 90, 80 and 20 s here), so three threads finish close together.
+# 90, 80, 25 and 16 s here), so three threads finish close together.
 PROGRAMS = {
     "diffusion_k4_512_tile32x128": ("diffusion3d", 512, (1, 1, 1), (2, 2, 2),
                                     8, dict(fused_k=4, fused_tile=(32, 128)),
@@ -35,11 +35,16 @@ PROGRAMS = {
         "diffusion3d", 256, (2, 2, 1), (8, 8, 2), 8,
         dict(fused_k=4, fused_tile=(32, 64), pipelined=True),
         dict(overlapx=8, overlapy=8)),
-    "diffusion_k4_128_periodz": ("diffusion3d", 128, (1, 1, 1), (2, 2, 8), 8,
+    "diffusion_k4_128_periodz": ("diffusion3d", 128, (1, 1, 1), (2, 2, 8), 48,
                                  dict(fused_k=4), {}),
+    "diffusion_k4_128_walls": ("diffusion3d", 128, (1, 1, 1), (2, 2, 2), 48,
+                               dict(fused_k=4), {}),
 }
 #: programs on a grid with periodic dimensions (x, y, z); others have none
 PERIODS = {"diffusion_k4_128_periodz": (0, 0, 1)}
+#: programs with a fori excess (12 groups: 8 unrolled, 4 looped), the loop
+#: shape of every 1000-step cell of the benchmark
+LOOPED = ("diffusion_k4_128_periodz", "diffusion_k4_128_walls")
 
 #: what each program's text must name: the kernel variants (the `name=` of
 #: each `pallas_call`) and the self-neighbour z exchange's scopes, as a
@@ -54,6 +59,7 @@ NAMES = {
     "diffusion_k4_128_periodz": ["igg_diffusion_k4_zpatch",
                                  "igg_zpatch_identity", "igg_zpatch_apply",
                                  "igg_zpatch_from_export"],
+    "diffusion_k4_128_walls": ["igg_diffusion_k4_plain"],
 }
 
 
@@ -150,3 +156,19 @@ def test_kernel_variants_are_named(compiled, name):
     assert kernels and all(
         re.match(r"\s*(ROOT\s+)?%igg_\w+_k\d+_(plain|zpatch)", ln)
         for ln in kernels), f"{name}: an unnamed Mosaic kernel"
+
+
+@pytest.mark.parametrize("name", sorted(PROGRAMS))
+def test_no_field_copy_in_a_while_body(compiled, name):
+    """The fused group schedule's fori excess runs two kernel groups per
+    iteration, so no looped group pays a whole-field copy back into the
+    loop carry (`models._fused.run_group_schedule`)."""
+    from implicitglobalgrid_tpu.utils.hlo_analysis import while_body_copies
+
+    txt = compiled[name]
+    if name in LOOPED:
+        assert " while(" in txt, f"{name}: the group excess no longer loops"
+    n = PROGRAMS[name][1]
+    assert while_body_copies(txt, n ** 3) == [], (
+        f"{name}: a field-sized copy in a while body"
+    )
