@@ -11,6 +11,11 @@ equivalent XLA cadence in the backward pass.
 
 Spans: `spanned_multi_step` gives every model's ``make_multi_step``
 callable its ``igg.multi_step`` host span.
+
+Counters: ``fused.fallback`` (and ``fused.fallback.<model>``) count every
+fallback decision, one per trace of a kernel path the envelope rejects;
+``make_multi_step`` registers ``fused.fallback`` at 0, so a telemetry
+snapshot reads 0, not nothing, where no kernel path fell back.
 """
 
 from __future__ import annotations
@@ -19,18 +24,23 @@ import functools
 
 _warned: set = set()
 
+#: the telemetry counter of fused-kernel fallback decisions
+FALLBACK_COUNTER = "fused.fallback"
+
 
 def spanned_multi_step(model: str):
     """Decorator of a model's ``make_multi_step(params, nsteps, ...)``: the
     callable it returns — plain jit, `igg.stencil` or batched alike — runs
     each call inside one ``igg.multi_step`` span (`tracing.spanned_calls`)
     tagged ``model``, ``nsteps`` and ``call``, the callable's own count of
-    its calls."""
-    from ..utils import tracing
+    its calls.  Building one registers the ``fused.fallback`` counter (at 0
+    until a kernel path falls back, `warn_fused_fallback`)."""
+    from ..utils import telemetry, tracing
 
     def deco(make):
         @functools.wraps(make)
         def make_spanned(params, nsteps, *args, **kwargs):
+            telemetry.counter(FALLBACK_COUNTER).inc(0)
             return tracing.spanned_calls(
                 make(params, nsteps, *args, **kwargs), "igg.multi_step",
                 model=model, nsteps=int(nsteps),
@@ -316,14 +326,20 @@ def fused_with_xla_grad(fused_body, xla_body):
 
 
 def warn_fused_fallback(shape, k, err, model: str = "diffusion") -> None:
-    """Warn once per (model, shape, k, reason) that fused_k fell back to XLA.
+    """Count a fallback of fused_k to XLA; warn once per (model, shape, k,
+    reason).
 
-    ``model`` keys the registry per kernel: the diffusion and leapfrog
-    envelopes share reason strings, and one model's fallback must not
-    silence another's first warning.
+    Every call counts (``fused.fallback`` and ``fused.fallback.<model>``),
+    the warning only the first.  ``model`` keys the registry per kernel: the
+    diffusion and leapfrog envelopes share reason strings, and one model's
+    fallback must not silence another's first warning.
     """
     import warnings
 
+    from ..utils import telemetry
+
+    telemetry.counter(FALLBACK_COUNTER).inc()
+    telemetry.counter(f"{FALLBACK_COUNTER}.{model}").inc()
     key = (model, shape, k, err)
     if key in _warned:
         return

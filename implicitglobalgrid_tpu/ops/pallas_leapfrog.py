@@ -195,24 +195,32 @@ def pad_faces(Vx, Vy, Vz):
     where Mosaic requires lane-tile-aligned extents).  The extra planes are
     junk by contract — never read by the kernel's compute, never written
     back into the real faces.  One HBM pass per field; amortized over a
-    whole fused chunk by the model wrapper.
+    whole fused chunk by the model wrapper.  Runs under
+    ``jax.named_scope("igg_faces_pad")``, so a device trace names the
+    layout's cost.
     """
     import jax.numpy as jnp
+    from jax import named_scope
 
-    return (
-        jnp.pad(Vx, ((0, PADS[0] - 1), (0, 0), (0, 0))),
-        jnp.pad(Vy, ((0, 0), (0, PADS[1] - 1), (0, 0))),
-        jnp.pad(Vz, ((0, 0), (0, 0), (0, PADS[2] - 1))),
-    )
+    with named_scope("igg_faces_pad"):
+        return (
+            jnp.pad(Vx, ((0, PADS[0] - 1), (0, 0), (0, 0))),
+            jnp.pad(Vy, ((0, 0), (0, PADS[1] - 1), (0, 0))),
+            jnp.pad(Vz, ((0, 0), (0, 0), (0, PADS[2] - 1))),
+        )
 
 
 def unpad_faces(Vxp, Vyp, Vzp):
-    """Inverse of `pad_faces`: slice the ``n+1`` real faces back out."""
-    return (
-        Vxp[: 1 - PADS[0]],
-        Vyp[:, : 1 - PADS[1]],
-        Vzp[:, :, : 1 - PADS[2]],
-    )
+    """Inverse of `pad_faces`: slice the ``n+1`` real faces back out
+    (under ``jax.named_scope("igg_faces_unpad")``)."""
+    from jax import named_scope
+
+    with named_scope("igg_faces_unpad"):
+        return (
+            Vxp[: 1 - PADS[0]],
+            Vyp[:, : 1 - PADS[1]],
+            Vzp[:, :, : 1 - PADS[2]],
+        )
 
 
 def z_patch_shapes(cell_shape):

@@ -41,15 +41,23 @@ def parse_computations(txt: str) -> dict[str, list[str]]:
     return comps
 
 
-def while_body_copies(txt: str, min_elems: int) -> list[tuple[str, str]]:
+def while_body_copies(txt: str, elems) -> list[tuple[str, str]]:
     """``(body computation, instruction)`` of every ``copy``/``copy-start``
-    of at least ``min_elems`` elements inside a while-loop body.
+    inside a while-loop body whose largest array has ``elems`` elements:
+    at least ``elems`` where it is an int, one of the counts where it is a
+    collection (the loop carry's arrays, padded layouts included, which may
+    be larger or smaller than a field).
 
     A while loop keeps each carry element in one buffer; a body whose last
     writer of a carry cannot take that buffer pays a whole-array copy per
     iteration (the fused group schedule's carry copy,
     `models._fused.run_group_schedule`).
     """
+    if isinstance(elems, int):
+        hit = lambda n: n >= elems
+    else:
+        counts = frozenset(elems)
+        hit = counts.__contains__
     comps = parse_computations(txt)
     bodies = {
         b for lines in comps.values() for l in lines
@@ -63,7 +71,7 @@ def while_body_copies(txt: str, min_elems: int) -> list[tuple[str, str]]:
                 continue
             name, rest = m.groups()
             op = re.search(r"\s(copy|copy-start)\(", rest)
-            if op and _out_elems(rest[:op.start()]) >= min_elems:
+            if op and hit(_out_elems(rest[:op.start()])):
                 out.append((b, name))
     return out
 

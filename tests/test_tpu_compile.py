@@ -12,6 +12,7 @@ nothing about results or times.
 import os
 import re
 
+import numpy as np
 import pytest
 
 import jax
@@ -19,12 +20,17 @@ import jax.numpy as jnp
 
 # name: (model, local n (cube), dims, overlaps, nsteps, make_multi_step
 #        kwargs, extra setup kwargs)
-# Longest compile first (Mosaic time grows with the tile window: ~180, 145,
-# 90, 80, 25 and 16 s here), so three threads finish close together.
+# Longest compile first (Mosaic time grows with the tile window: ~180, 165,
+# 145, 90, 80, 40, 25 and 16 s here), so three threads finish close together.
 PROGRAMS = {
     "diffusion_k4_512_tile32x128": ("diffusion3d", 512, (1, 1, 1), (2, 2, 2),
                                     8, dict(fused_k=4, fused_tile=(32, 128)),
                                     {}),
+    # at the cell's 256^3: at 128^3 memory-space assignment keeps the
+    # padded x/y face fields (8.9 MB) in VMEM and evicts them to HBM in
+    # every loop iteration, a path the cell's 66 MiB fields never take
+    "acoustic_k6_256_periodz": ("acoustic3d", 256, (1, 1, 1), (2, 2, 12), 78,
+                                dict(fused_k=6), {}),
     "diffusion_k4_256": ("diffusion3d", 256, (1, 1, 1), (2, 2, 2), 8,
                          dict(fused_k=4), {}),
     "porous_k6_npt10_256": ("porous_convection3d", 256, (1, 1, 1), (2, 2, 2),
@@ -39,12 +45,18 @@ PROGRAMS = {
                                  dict(fused_k=4), {}),
     "diffusion_k4_128_walls": ("diffusion3d", 128, (1, 1, 1), (2, 2, 2), 48,
                                dict(fused_k=4), {}),
+    "acoustic_k6_128_walls": ("acoustic3d", 128, (1, 1, 1), (2, 2, 2), 78,
+                              dict(fused_k=6), {}),
 }
 #: programs on a grid with periodic dimensions (x, y, z); others have none
-PERIODS = {"diffusion_k4_128_periodz": (0, 0, 1)}
-#: programs with a fori excess (12 groups: 8 unrolled, 4 looped), the loop
-#: shape of every 1000-step cell of the benchmark
-LOOPED = ("diffusion_k4_128_periodz", "diffusion_k4_128_walls")
+PERIODS = {"diffusion_k4_128_periodz": (0, 0, 1), "acoustic_k6_256_periodz": (0, 0, 1)}
+#: programs with a fori excess: diffusion 12 groups (8 unrolled, 4 looped),
+#: the loop shape of every 1000-step diffusion cell; acoustic 13 groups (8
+#: unrolled, an odd excess of 5: one more unrolled, two paired iterations;
+#: XLA unrolls a loop of one iteration), the shape of the 1002-step acoustic
+#: cells (167 groups, excess 159)
+LOOPED = ("diffusion_k4_128_periodz", "diffusion_k4_128_walls",
+          "acoustic_k6_128_walls", "acoustic_k6_256_periodz")
 
 #: what each program's text must name: the kernel variants (the `name=` of
 #: each `pallas_call`) and the self-neighbour z exchange's scopes, as a
@@ -60,6 +72,12 @@ NAMES = {
                                  "igg_zpatch_identity", "igg_zpatch_apply",
                                  "igg_zpatch_from_export"],
     "diffusion_k4_128_walls": ["igg_diffusion_k4_plain"],
+    "acoustic_k6_128_walls": ["igg_leapfrog_k6_plain", "igg_faces_pad",
+                              "igg_faces_unpad"],
+    # one chip: the four-field from_export hands the exports back untouched
+    "acoustic_k6_256_periodz": ["igg_leapfrog_k6_zpatch", "igg_faces_pad",
+                                "igg_faces_unpad", "igg_zpatch_identity",
+                                "igg_zpatch_apply"],
 }
 
 
@@ -171,4 +189,27 @@ def test_no_field_copy_in_a_while_body(compiled, name):
     n = PROGRAMS[name][1]
     assert while_body_copies(txt, n ** 3) == [], (
         f"{name}: a field-sized copy in a while body"
+    )
+
+
+@pytest.mark.parametrize("name", ["acoustic_k6_128_walls", "acoustic_k6_256_periodz"])
+def test_no_carried_array_copy_in_acoustic_while_body(compiled, name):
+    """The looped acoustic schedule carries P, the three face fields in the
+    padded layout (n+8 on x and y, n+128 on z) and, on a periodic z, the
+    three packed z patches: no while body copies any of them, larger or
+    smaller than n^3."""
+    from implicitglobalgrid_tpu.ops import pallas_leapfrog
+    from implicitglobalgrid_tpu.utils.hlo_analysis import while_body_copies
+
+    n = PROGRAMS[name][1]
+    cell = (n, n, n)
+    shapes = [cell, *pallas_leapfrog.padded_face_shapes(cell)]
+    if name.endswith("periodz"):
+        shapes += pallas_leapfrog.z_patch_shapes(cell)
+    carried = {int(np.prod(s)) for s in shapes}
+    assert len(carried) > 2  # the padded sizes differ from n^3
+    txt = compiled[name]
+    assert " while(" in txt, f"{name}: the group excess no longer loops"
+    assert while_body_copies(txt, carried) == [], (
+        f"{name}: a copy of a carried array in a while body"
     )
