@@ -239,15 +239,19 @@ def pipelined_support_error(kernel_mod, shape, k, itemsize: int = 4,
     implementation for the three models (``kernel_mod`` = the model's
     Pallas kernel module); the per-model wrappers
     (`models.*.pipelined_support_error`) exist for benchmark provenance.
+    A kernel module with ``Z_SELF_WRAP`` wraps a self-neighbour z inside its
+    tiles, so such a z gates like an inactive one (the plain envelope).
     """
-    from ..ops.halo import dim_has_halo_activity
+    from ..ops.halo import _partner_self, dim_has_halo_activity
     from ..parallel.grid import global_grid
 
     if gg is None:
         gg = global_grid()
     shape = tuple(shape)
     active = tuple(d for d in (0, 1) if dim_has_halo_activity(gg, d))
-    z_active = dim_has_halo_activity(gg, 2)
+    z_active = dim_has_halo_activity(gg, 2) and not (
+        getattr(kernel_mod, "Z_SELF_WRAP", False) and _partner_self(gg, 2)
+    )
     zp = z_active and kernel_mod.fused_support_error(
         shape, k, itemsize, bx, by, zpatch=True
     ) is None

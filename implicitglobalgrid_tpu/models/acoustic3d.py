@@ -45,6 +45,10 @@ from .. import (
 from ..ops.overlap import hide_communication
 from ._fused import spanned_multi_step as _spanned_multi_step
 
+#: the telemetry counter of traced kernel groups that do the self-neighbour z
+#: exchange inside the leapfrog kernel's tiles (``z_wrap``)
+Z_SELF_WRAP_COUNTER = "fused.z_self_wrap"
+
 
 @dataclasses.dataclass(frozen=True)
 class Params:
@@ -273,10 +277,18 @@ def make_multi_step(
     ``IGG_AUTOTUNE``, default off): substitute this point's cached winner
     schedule into the kwargs above (`implicitglobalgrid_tpu.tuning`; pure
     substitution — explicit kwargs always win, results bit-identical).
+
+    On a periodic z that is not split across devices (every block its own z
+    partner) the kernel does the z exchange inside each tile (``z_wrap`` of
+    `fused_leapfrog_steps`): each traced group adds 1 to the telemetry
+    counter ``fused.z_self_wrap``, which every call registers at 0.
     """
     from jax import lax
 
     from ..tuning.search import maybe_autotune
+    from ..utils import telemetry
+
+    telemetry.counter(Z_SELF_WRAP_COUNTER).inc(0)
 
     fused_k, fused_tile, exchange_every, pipelined, coalesce = maybe_autotune(
         "acoustic3d", params, nsteps, autotune, batch=batch,
@@ -298,8 +310,9 @@ def make_multi_step(
     if fused_k:
         import jax
 
-        from ..ops.halo import dim_has_halo_activity, require_deep_halo
+        from ..ops.halo import _partner_self, dim_has_halo_activity, require_deep_halo
         from ..ops.pallas_leapfrog import (
+            Z_SELF_WRAP,
             fused_leapfrog_steps,
             fused_support_error,
             pad_faces,
@@ -345,6 +358,8 @@ def make_multi_step(
             return p_update(P, Vx, Vy, Vz), Vx, Vy, Vz
 
         z_active = dim_has_halo_activity(gg, 2)
+        # Every block its own z partner: the kernel wraps z in its tiles.
+        z_self = z_active and Z_SELF_WRAP and _partner_self(gg, 2)
         from ._fused import (
             fused_with_xla_grad,
             resolve_pipelined,
@@ -375,6 +390,7 @@ def make_multi_step(
             if (
                 zpatch_body is not None
                 and z_active
+                and not z_self
                 and fused_support_error(
                     shape, fused_k, P.dtype.itemsize, bx, by, zpatch=True
                 ) is None
@@ -392,7 +408,8 @@ def make_multi_step(
             err = fused_support_error(shape, fused_k, P.dtype.itemsize, bx, by)
             if err is None:
                 body = fused_body
-                if "plain" in pb and not z_active and resolve_pipelined(
+                # z inactive, or wrapped inside the kernel: x/y only outside
+                if "plain" in pb and (z_self or not z_active) and resolve_pipelined(
                     pipelined, _split(shape, P.dtype.itemsize, False)[1],
                     shape, fused_k, "acoustic",
                 ):
@@ -435,17 +452,34 @@ def make_multi_step(
                 body, donate_argnums=tuple(range(4)) if donate else ()
             )
 
+        def z_wrap_kwargs(shape):
+            """The kernel arguments of the in-tile z wrap, on a z_self grid
+            (or none)."""
+            if not z_self:
+                return {}
+            from ..ops.halo import ol
+
+            return dict(z_wrap=True, z_overlap=ol(2, shape=shape, gg=gg))
+
         def fused_block_step(P, Vx, Vy, Vz):
             from ..ops.halo import update_halo_padded_faces
 
+            zkw = z_wrap_kwargs(tuple(P.shape))
+
             def group(ki, s):
-                s = kernel_steps(*s)
+                if z_self:
+                    telemetry.counter(Z_SELF_WRAP_COUNTER).inc()
+                s = kernel_steps(*s, **zkw)
                 # One all-field slab exchange licenses the next fused_k
                 # steps (see the exchange_every docstring for why P's slab
                 # must ride along) — directly on the padded layout, so the
-                # chunk pays ONE pad/unpad instead of one per group.
+                # chunk pays ONE pad/unpad instead of one per group.  On a
+                # z_self grid the kernel's output already has fresh z halos.
+                if z_self and not active01:
+                    return s
                 return update_halo_padded_faces(
-                    *s, width=fused_k, coalesce=coalesce
+                    *s, width=fused_k, dims=(0, 1) if z_self else None,
+                    coalesce=coalesce,
                 )
 
             P, Vxp, Vyp, Vzp = run_group_schedule(
@@ -497,9 +531,9 @@ def make_multi_step(
             return (P, *unpad_faces(Vxp, Vyp, Vzp))
 
         def fused_pipelined_block_step(P, Vx, Vy, Vz):
-            # Boundary-first split of `fused_block_step` (z-inactive):
-            # ring pass feeds the all-field slab exchange early, interior
-            # pass runs across the in-flight collectives.
+            # Boundary-first split of `fused_block_step` (z inactive, or
+            # z_self): ring pass feeds the all-field x/y slab exchange
+            # early, interior pass runs across the in-flight collectives.
             from ..ops.halo import (
                 _padded_logicals,
                 begin_slab_exchange,
@@ -511,8 +545,12 @@ def make_multi_step(
             s0 = (P, *pad_faces(Vx, Vy, Vz))
             logicals = _padded_logicals(*s0)
 
+            zkw = z_wrap_kwargs(tuple(P.shape))
+
             def boundary(ki, s):
-                out_b = kernel_steps(*s, tile_sel="ring" + sel)
+                if z_self:
+                    telemetry.counter(Z_SELF_WRAP_COUNTER).inc()
+                out_b = kernel_steps(*s, tile_sel="ring" + sel, **zkw)
                 pend = begin_slab_exchange(
                     out_b, (0, 1), width=fused_k, logicals=logicals,
                     coalesce=coalesce,
@@ -520,7 +558,7 @@ def make_multi_step(
                 return out_b, pend
 
             def interior(ki, s, out_b, pend):
-                out = kernel_steps(*s, tile_sel="mid" + sel, carry_in=out_b)
+                out = kernel_steps(*s, tile_sel="mid" + sel, carry_in=out_b, **zkw)
                 return finish_slab_exchange(out, pend, logicals=logicals)
 
             P, Vxp, Vyp, Vzp = run_pipelined_group_schedule(

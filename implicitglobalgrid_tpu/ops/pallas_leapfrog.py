@@ -176,6 +176,20 @@ def fused_support_error(shape, k: int, itemsize: int = 4,
 #: `ops.halo.update_halo_padded_faces`).
 PADS = (8, 8, 128)
 
+#: The kernel does a self-neighbour z exchange inside its tiles (``z_wrap`` of
+#: `fused_leapfrog_steps`): on a periodic z that is not split across devices
+#: the cadence runs it in place of the z patches and exports, and the
+#: pipelined gate (`models._fused.pipelined_support_error`) treats such a z
+#: like an inactive one.
+Z_SELF_WRAP = True
+
+#: x-rows per step of the in-tile z wrap's loop.  Each lane-band copy
+#: unrolls into vector code per row, so the (32, 64) tile's copies written out
+#: whole made the periodic-z program's cold compile 46 s longer on a v5e host
+#: (121 against the walls program's 75 s); a loop over 16-row blocks compiles
+#: in 75 s and runs as fast, 8-row blocks 1.8% slower per kernel step.
+_WRAP_ROWS = 16
+
 
 def padded_face_shapes(cell_shape):
     """The three `pad_faces` array shapes for a given cell shape."""
@@ -240,7 +254,7 @@ def fused_leapfrog_steps(P, Vxp, Vyp, Vzp, k: int,
                          b: float, idx: float, idy: float, idz: float,
                          *, bx: int | None = None, by: int | None = None,
                          z_patches=None, z_export: bool = False,
-                         z_overlap: int | None = None,
+                         z_wrap: bool = False, z_overlap: int | None = None,
                          tile_sel: str = "all", carry_in=None):
     """Advance ``k`` (even) leapfrog steps in one HBM pass per field.
 
@@ -272,6 +286,18 @@ def fused_leapfrog_steps(P, Vxp, Vyp, Vzp, k: int,
     x/y-active grids the exports' own x/y slab exchange refreshes them
     anyway.
 
+    ``z_wrap`` (requires the grid z-overlap ``z_overlap``, and no
+    ``z_patches``): the self-neighbour z exchange of a periodic z that is not
+    split across devices, done inside each tile.  After the k steps every
+    field's z-halo planes are overwritten from the tile's own planes --
+    ``[0,k)`` <- ``[n_f-o_f, n_f-o_f+k)`` and ``[n_f-k, n_f)`` <-
+    ``[o_f-k, o_f)``, the export lanes followed by the patch apply of the
+    ``z_patches``/``z_export`` cadence -- so the output is the k steps plus
+    the z halo update, with no patch input, no export output and the
+    plain variant's VMEM set.  The frozen Vx row ``n0`` / Vy column ``n1``
+    slabs get the same wrap: the x-edge / y-edge tiles write them out from
+    VMEM in place of the fix-up copies.
+
     ``tile_sel``/``carry_in``: tile-subset launch for the pipelined group
     schedule, exactly as on `ops.pallas_stencil.fused_diffusion_steps` — a
     ``"mid*"`` launch aliases the matching ``"ring*"`` launch's outputs
@@ -296,14 +322,17 @@ def fused_leapfrog_steps(P, Vxp, Vyp, Vzp, k: int,
             )
         if any(a.dtype != P.dtype for a in z_patches):
             raise ValueError("z_patches must share the fields' dtype")
-    if z_export:
-        if not zp:
-            raise ValueError("z_export requires z_patches (the z-slab cadence)")
+    if z_export and not zp:
+        raise ValueError("z_export requires z_patches (the z-slab cadence)")
+    if z_wrap and zp:
+        raise ValueError("z_wrap replaces z_patches; pass one or the other")
+    if z_export or z_wrap:
         if z_overlap is None or not (2 * k <= z_overlap <= n2 // 2):
             raise ValueError(
-                f"z_export needs the grid z-overlap with 2k <= o <= n2/2: "
-                f"got o={z_overlap}, k={k}, n2={n2}"
+                f"z_export and z_wrap need the grid z-overlap with "
+                f"2k <= o <= n2/2: got o={z_overlap}, k={k}, n2={n2}"
             )
+    if z_export:
         if 4 * k > 128 - Z_CZ_BAND:
             # Each merged-band half holds 4k lanes (see `ops.halo.Z_CZ_BAND`).
             raise ValueError(
@@ -326,8 +355,8 @@ def fused_leapfrog_steps(P, Vxp, Vyp, Vzp, k: int,
                 float(cax), float(cay), float(caz),
                 float(b), float(idx), float(idy), float(idz),
                 int(bx), int(by), zp,
-                bool(z_export), int(z_overlap) if z_export else 0,
-                str(tile_sel), carry_in is not None)
+                bool(z_export), int(z_overlap) if z_export or z_wrap else 0,
+                str(tile_sel), carry_in is not None, bool(z_wrap))
     args = (P, Vxp, Vyp, Vzp) + (tuple(z_patches) if zp else ())
     if carry_in is not None:
         args += tuple(carry_in)
@@ -337,7 +366,7 @@ def fused_leapfrog_steps(P, Vxp, Vyp, Vzp, k: int,
 @functools.lru_cache(maxsize=64)
 def _build(n0, n1, n2, dtype, k, cax, cay, caz, b, idx, idy, idz, bx, by,
            zp: bool = False, zx: bool = False, o: int = 0,
-           tile_sel: str = "all", carry: bool = False):
+           tile_sel: str = "all", carry: bool = False, zw: bool = False):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -353,10 +382,11 @@ def _build(n0, n1, n2, dtype, k, cax, cay, caz, b, idx, idy, idz, bx, by,
     # Tile-subset launch (see ops/pallas_stencil.py): the loop runs over the
     # subset's index space; per-tile work is unchanged.  The frozen top-face
     # fix-up DMAs belong to the ring pass (the mid pass's aliased outputs
-    # already carry those planes).
+    # already carry those planes).  Under ``zw`` the edge tiles carry them
+    # instead (`top_dmas`), wrapped with the rest of the tile.
     nrun = tile_subset_count(tile_sel, ncx, ncy)
     t_of = tile_subset_map(tile_sel, ncx, ncy)
-    fixup = not tile_sel.startswith("mid")
+    fixup = not tile_sel.startswith("mid") and not zw
     dt_ = jnp.dtype(dtype)
 
     def sx_of(ix):
@@ -444,9 +474,17 @@ def _build(n0, n1, n2, dtype, k, cax, cay, caz, b, idx, idy, idz, bx, by,
         def body(p, vx, vy, vz, sp, svx, svy, svz,
                  p_is, vx_is, vy_is, vz_is, p_os, vx_os, vy_os, vz_os, fix_s,
                  zpcz=None, zpx=None, zpy=None, zp_is=None,
-                 zxcz=None, zxx=None, zxy=None, zx_os=None):
+                 zxcz=None, zxx=None, zxy=None, zx_os=None, top_os=None):
             def ixy(t):
                 return t // ncy, t % ncy
+
+            def owned(t):
+                """Tile t's owned block: window offsets (ox, oy) and global
+                offsets (gx, gy)."""
+                ix, iy = ixy(t)
+                ox = ix * bx - sx_of(ix)
+                oy = pl.multiple_of(iy * by - sy_of(iy), 8)
+                return ox, oy, ix * bx, iy * by
 
             def in_dmas(t, slot):
                 ix, iy = ixy(t)
@@ -486,10 +524,7 @@ def _build(n0, n1, n2, dtype, k, cax, cay, caz, b, idx, idy, idz, bx, by,
                 ) if zp else ())
 
             def out_dmas(t, slot):
-                ix, iy = ixy(t)
-                ox = ix * bx - sx_of(ix)
-                oy = pl.multiple_of(iy * by - sy_of(iy), 8)
-                gx, gy = ix * bx, iy * by
+                ox, oy, gx, gy = owned(t)
                 return (
                     pltpu.make_async_copy(
                         p.at[slot, pl.ds(ox, bx), pl.ds(oy, by)],
@@ -510,10 +545,7 @@ def _build(n0, n1, n2, dtype, k, cax, cay, caz, b, idx, idy, idz, bx, by,
                 )
 
             def zex_dmas(t, slot):
-                ix, iy = ixy(t)
-                ox = ix * bx - sx_of(ix)
-                oy = pl.multiple_of(iy * by - sy_of(iy), 8)
-                gx, gy = ix * bx, iy * by
+                ox, oy, gx, gy = owned(t)
                 return (
                     pltpu.make_async_copy(
                         zxcz.at[slot, pl.ds(ox, bx), pl.ds(oy, by)],
@@ -529,6 +561,29 @@ def _build(n0, n1, n2, dtype, k, cax, cay, caz, b, idx, idy, idz, bx, by,
                     ),
                 )
 
+            def top_dmas(t, slot, op):
+                # zw: the x-edge tiles hold the frozen Vx row-n0 slab (window
+                # rows SX..SX+8) and the y-edge tiles the Vy column-n1 slab
+                # (window columns SY..SY+8); each writes its owned part.
+                ix, iy = ixy(t)
+                ox, oy, gx, gy = owned(t)
+
+                @pl.when(ix == ncx - 1)
+                def _():
+                    getattr(pltpu.make_async_copy(
+                        vx.at[slot, pl.ds(SX, 8), pl.ds(oy, by)],
+                        Vxout.at[pl.ds(n0, 8), pl.ds(gy, by)],
+                        top_os.at[0, slot],
+                    ), op)()
+
+                @pl.when(iy == ncy - 1)
+                def _():
+                    getattr(pltpu.make_async_copy(
+                        vy.at[slot, pl.ds(ox, bx), pl.ds(SY, 8)],
+                        Vyout.at[pl.ds(gx, bx), pl.ds(n1, 8)],
+                        top_os.at[1, slot],
+                    ), op)()
+
             def start_in(t, slot):
                 for d in in_dmas(t, slot):
                     d.start()
@@ -543,6 +598,8 @@ def _build(n0, n1, n2, dtype, k, cax, cay, caz, b, idx, idy, idz, bx, by,
                 if zx:
                     for d in zex_dmas(t, slot):
                         d.start()
+                if zw:
+                    top_dmas(t, slot, "start")
 
             def wait_out(t, slot):
                 for d in out_dmas(t, slot):
@@ -550,22 +607,26 @@ def _build(n0, n1, n2, dtype, k, cax, cay, caz, b, idx, idy, idz, bx, by,
                 if zx:
                     for d in zex_dmas(t, slot):
                         d.wait()
+                if zw:
+                    top_dmas(t, slot, "wait")
 
             # Top-slab fix-up: the frozen Vx row-n0 / Vy col-n1 face planes
             # (plus their 7 junk planes) are outside every tile's owned
-            # block — carry them input→output once.  Vz's top face is
-            # covered by the tiles' full-minor out-DMAs.
-            fix_vx = pltpu.make_async_copy(
-                Vxin.at[pl.ds(n0, 8)], Vxout.at[pl.ds(n0, 8)], fix_s.at[0]
-            )
-            fix_vy = pltpu.make_async_copy(
-                Vyin.at[pl.ds(0, n0), pl.ds(n1, 8)],
-                Vyout.at[pl.ds(0, n0), pl.ds(n1, 8)],
-                fix_s.at[1],
-            )
-            if fixup:
-                fix_vx.start()
-                fix_vy.start()
+            # block — carry them input→output once (under zw the edge tiles
+            # write them, `top_dmas`).  Vz's top face is covered by the
+            # tiles' full-minor out-DMAs.
+            fixes = (
+                pltpu.make_async_copy(
+                    Vxin.at[pl.ds(n0, 8)], Vxout.at[pl.ds(n0, 8)], fix_s.at[0]
+                ),
+                pltpu.make_async_copy(
+                    Vyin.at[pl.ds(0, n0), pl.ds(n1, 8)],
+                    Vyout.at[pl.ds(0, n0), pl.ds(n1, 8)],
+                    fix_s.at[1],
+                ),
+            ) if fixup else ()
+            for d in fixes:
+                d.start()
             start_in(t_of(0), 0)
 
             def tile(i, _):
@@ -641,6 +702,32 @@ def _build(n0, n1, n2, dtype, k, cax, cay, caz, b, idx, idy, idz, bx, by,
                     zxcz[slot, :, :, Z_CZ_BAND + 3 * k : Z_CZ_BAND + 4 * k] = vz[
                         slot, :, :, SZ + 1 - k : SZ + 1
                     ]
+                if zw:
+                    # The self-neighbour z exchange on the owned block (plus
+                    # the 8 top-face rows / columns the edge tiles write out):
+                    # the export lanes above followed by the patch apply.
+                    # Vz's logical z size is SZ+1 and its overlap o+1.  A
+                    # loop over blocks of _WRAP_ROWS x-rows (see there).
+                    ox, oy, _, _ = owned(t)
+                    ys = pl.ds(oy, by)
+                    fields = ((p, ys, SZ), (vx, ys, SZ),
+                              (vy, pl.ds(oy, by + 8), SZ), (vz, ys, SZ + 1))
+
+                    def wrap(fields, x0, nx):
+                        xr = pl.ds(x0, nx)
+                        for ref, yr, nf in fields:
+                            of = o + nf - SZ
+                            ref[slot, xr, yr, 0:k] = ref[slot, xr, yr, nf - of : nf - of + k]
+                            ref[slot, xr, yr, nf - k : nf] = ref[slot, xr, yr, of - k : of]
+
+                    def rows(i, c):
+                        wrap(fields, ox + i * _WRAP_ROWS, _WRAP_ROWS)
+                        return c
+
+                    jax.lax.fori_loop(0, bx // _WRAP_ROWS, rows, 0)
+                    if bx % _WRAP_ROWS:
+                        wrap(fields, ox + bx - bx % _WRAP_ROWS, bx % _WRAP_ROWS)
+                    wrap(fields[1:2], ox + bx, 8)  # Vx's top-face rows
                 start_out(t, slot)
                 return 0
 
@@ -649,9 +736,8 @@ def _build(n0, n1, n2, dtype, k, cax, cay, caz, b, idx, idy, idz, bx, by,
             # tiles by validation; distinct slots).
             wait_out(t_of(nrun - 2), (nrun - 2) % 2)
             wait_out(t_of(nrun - 1), (nrun - 1) % 2)
-            if fixup:
-                fix_vx.wait()
-                fix_vy.wait()
+            for d in fixes:
+                d.wait()
 
         scopes = dict(
             p=pltpu.VMEM((2, SX, SY, SZ), dt_),
@@ -679,6 +765,8 @@ def _build(n0, n1, n2, dtype, k, cax, cay, caz, b, idx, idy, idz, bx, by,
                 zpy=pltpu.VMEM((2, SX, SY + 8, 128), dt_),
                 zp_is=pltpu.SemaphoreType.DMA((3, 2)),
             )
+        if zw:
+            scopes.update(top_os=pltpu.SemaphoreType.DMA((2, 2)))
         if zx:
             scopes.update(
                 zxcz=pltpu.VMEM((2, SX, SY, 128), dt_),
@@ -713,6 +801,6 @@ def _build(n0, n1, n2, dtype, k, cax, cay, caz, b, idx, idy, idz, bx, by,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_envelope.vmem_limit(vmem_bytes)
         ),
-        name=_envelope.kernel_name("leapfrog", k, zp, tile_sel),
+        name=_envelope.kernel_name("leapfrog", k, zp or zw, tile_sel),
     )
     return _envelope.per_member(jax.jit(call))

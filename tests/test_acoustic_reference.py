@@ -46,9 +46,9 @@ def _counter(name):
     return telemetry.snapshot()["counters"].get(name, 0)
 
 
-@pytest.mark.parametrize("traffic", sorted(TRAFFIC))
-@pytest.mark.parametrize("path", ["fused", "xla"])
-def test_program_matches_plain_reference(path, traffic):
+def _advance(path, traffic):
+    """The seeded fields before and after one call of the program, and the
+    plain reference's fields after the same steps."""
     from jax.experimental.pallas.tpu import force_tpu_interpret_mode
 
     cfg = {"local_shape": list(SHAPE), "dtype": "float32", "dims": [1, 1, 1],
@@ -59,23 +59,51 @@ def test_program_matches_plain_reference(path, traffic):
     state = harness.seeded_state(cell, state, 2**31 + 11)
     before = [np.asarray(igg.gather(a, dedup=True)) for a in state]
 
-    fallbacks, paired = _counter("fused.fallback"), _counter("schedule.fori_paired_groups")
     with force_tpu_interpret_mode():
         step = acoustic3d.make_multi_step(
             params, NSTEPS, donate=False, fused_k=K if path == "fused" else None)
         out = jax.block_until_ready(step(*state))
     after = [np.asarray(igg.gather(a, dedup=True)) for a in out]
     igg.finalize_global_grid()
+    want = solver.reference(before, grid, cfg, TRAFFIC[traffic], jnp.float32)
+    return before, after, [np.asarray(w) for w in want]
+
+
+@pytest.mark.parametrize("traffic", sorted(TRAFFIC))
+@pytest.mark.parametrize("path", ["fused", "xla"])
+def test_program_matches_plain_reference(path, traffic):
+    fallbacks, paired = _counter("fused.fallback"), _counter("schedule.fori_paired_groups")
+    before, after, want = _advance(path, traffic)
 
     assert "fused.fallback" in telemetry.snapshot()["counters"]
     if path == "fused":
         assert _counter("fused.fallback") == fallbacks, "the kernel path fell back"
         # the odd excess: one group before the loop, two paired iterations
         assert _counter("schedule.fori_paired_groups") == paired + 4
-    want = solver.reference(before, grid, cfg, TRAFFIC[traffic], jnp.float32)
     for name, got, w in zip(solver.FIELDS, after, want):
-        w = np.asarray(w)
         assert got.shape == w.shape, name
         assert harness.rel_err(got, w) <= REL_TOL, (name, harness.rel_err(got, w))
     # the fields moved: a program that returned its input would fail above
-    assert harness.rel_err(before[0], np.asarray(want[0])) > 1e-2
+    assert harness.rel_err(before[0], want[0]) > 1e-2
+
+
+def test_periodic_z_wrap_left_out_is_caught(monkeypatch):
+    """The kernel run without its in-tile z wrap, so the self-neighbour z
+    exchange of the periodic-z cadence is left out: the comparison with the
+    plain reference fails by far more than the benchmark cell's limit."""
+    from implicitglobalgrid_tpu.ops import pallas_leapfrog
+
+    kernel = pallas_leapfrog.fused_leapfrog_steps
+
+    def no_wrap(*a, z_wrap=False, z_overlap=None, **kw):
+        return kernel(*a, **kw)
+
+    monkeypatch.setattr(pallas_leapfrog, "fused_leapfrog_steps", no_wrap)
+    wraps = _counter(acoustic3d.Z_SELF_WRAP_COUNTER)
+    _, after, want = _advance("fused", "periodz")
+    assert _counter(acoustic3d.Z_SELF_WRAP_COUNTER) > wraps, "the wrap path did not run"
+    limit = harness.load_json(
+        ROOT, os.path.join("igg_bench", "limits", "acoustic256.fused.periodz.json")
+    )["rel_err"]
+    worst = max(harness.rel_err(g, w) for g, w in zip(after, want))
+    assert worst > 100 * limit, worst

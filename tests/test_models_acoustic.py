@@ -12,8 +12,14 @@ import jax
 
 import implicitglobalgrid_tpu as igg
 from implicitglobalgrid_tpu.models import acoustic3d
+from implicitglobalgrid_tpu.utils import telemetry
 
 from tests.test_models_diffusion import dedup_global
+
+
+def _z_self_wraps():
+    """The program's count of traced groups that wrapped z in the kernel."""
+    return telemetry.snapshot()["counters"].get(acoustic3d.Z_SELF_WRAP_COUNTER, 0)
 
 
 def _run(nt, nx, devices=None, hide_comm=False):
@@ -157,12 +163,16 @@ def test_fused_single_device_matches_xla():
     igg.finalize_global_grid()
 
     state, params = acoustic3d.setup(16, 32, 128, **kw)
+    wraps = _z_self_wraps()
     with force_tpu_interpret_mode():
         stepf = acoustic3d.make_multi_step(
             params, nt, donate=False, fused_k=2, fused_tile=(8, 16)
         )
         got = [np.asarray(A) for A in jax.block_until_ready(stepf(*state))]
     igg.finalize_global_grid()
+    # walls: no z exchange, so no in-kernel z wrap (the counter is registered)
+    assert acoustic3d.Z_SELF_WRAP_COUNTER in telemetry.snapshot()["counters"]
+    assert _z_self_wraps() == wraps
     for g, r in zip(got, ref):
         np.testing.assert_allclose(g, r, rtol=2e-5, atol=2e-5)
 
@@ -258,38 +268,72 @@ def test_fused_zpatch_deep_halo_z_split_matches_xla():
     igg.finalize_global_grid()
 
     state, params = acoustic3d.setup(16, 32, 128, **kw)
+    wraps = _z_self_wraps()
     with force_tpu_interpret_mode():
         stepf = acoustic3d.make_multi_step(
             params, nt, donate=False, fused_k=2, fused_tile=(8, 16)
         )
         got = [np.asarray(igg.gather(A)) for A in jax.block_until_ready(stepf(*state))]
     igg.finalize_global_grid()
+    # a real z partner: the patch/export cadence, no in-kernel wrap
+    assert _z_self_wraps() == wraps
     for name, g, r in zip(("P", "Vx", "Vy", "Vz"), got, ref):
         np.testing.assert_allclose(g, r, rtol=2e-5, atol=2e-5, err_msg=name)
 
 
-def test_fused_zpatch_periodic_z_matches_xla():
-    """Same cadence on the periodic self-neighbor z config (1 device,
-    z-activity via the wrap — the degenerate config the hardware bench
-    uses)."""
+@pytest.mark.parametrize(
+    "dims,nloc,pipelined",
+    [
+        # 1 device, z-activity via the wrap: the one-chip benchmark's config
+        ((1, 1, 1), (16, 32, 128), False),
+        # x split, each device its own z partner: x slabs exchange outside
+        # the wrapping kernel, serialized or under the ring/interior split
+        ((2, 1, 1), (24, 32, 128), False),
+        ((2, 1, 1), (24, 32, 128), True),
+        # x split and a real z partner: the patch/export kernel, its packed
+        # exports riding the x/y exchange (`fused_zpatch_step` and
+        # `fused_zpatch_pipelined_step`), no in-kernel wrap
+        ((2, 1, 2), (24, 32, 128), False),
+        ((2, 1, 2), (24, 32, 128), True),
+    ],
+    ids=["self", "x2-self", "x2-self-piped", "x2z2", "x2z2-piped"],
+)
+def test_fused_zpatch_periodic_z_matches_xla(dims, nloc, pipelined):
+    """The fused cadence on a periodic z against the per-step path
+    (interpret-mode kernel).  Where every block is its own z partner the
+    kernel does the z exchange inside its tiles (``fused.z_self_wrap``); a
+    z split across devices keeps the patch/export cadence."""
+    import warnings
+
     from jax.experimental.pallas.tpu import force_tpu_interpret_mode
 
     nt = 4
     kw = dict(
-        devices=jax.devices()[:1], periodz=1, overlapz=4, quiet=True,
-        dtype=jax.numpy.float32,
+        devices=jax.devices()[: int(np.prod(dims))], dimx=dims[0],
+        dimy=dims[1], dimz=dims[2], periodz=1, overlapx=4, overlapz=4,
+        quiet=True, dtype=jax.numpy.float32,
     )
-    state, params = acoustic3d.setup(16, 32, 128, **kw)
+    state, params = acoustic3d.setup(*nloc, **kw)
     step = acoustic3d.make_multi_step(params, nt, donate=False)
-    ref = [np.asarray(A) for A in jax.block_until_ready(step(*state))]
+    ref = [np.asarray(igg.gather(A)) for A in jax.block_until_ready(step(*state))]
     igg.finalize_global_grid()
 
-    state, params = acoustic3d.setup(16, 32, 128, **kw)
-    with force_tpu_interpret_mode():
+    state, params = acoustic3d.setup(*nloc, **kw)
+    if pipelined:
+        # the split is admissible: the pipelined body, not a fallback, runs
+        assert acoustic3d.pipelined_support_error(nloc, 2, bx=8, by=16) is None
+    wraps = _z_self_wraps()
+    with force_tpu_interpret_mode(), warnings.catch_warnings():
+        warnings.filterwarnings("error", message=".*not admissible")
         stepf = acoustic3d.make_multi_step(
-            params, nt, donate=False, fused_k=2, fused_tile=(8, 16)
+            params, nt, donate=False, fused_k=2, fused_tile=(8, 16),
+            pipelined=pipelined,
         )
-        got = [np.asarray(A) for A in jax.block_until_ready(stepf(*state))]
+        got = [np.asarray(igg.gather(A)) for A in jax.block_until_ready(stepf(*state))]
     igg.finalize_global_grid()
+    if dims[2] == 1:
+        assert _z_self_wraps() >= wraps + 1
+    else:
+        assert _z_self_wraps() == wraps
     for name, g, r in zip(("P", "Vx", "Vy", "Vz"), got, ref):
         np.testing.assert_allclose(g, r, rtol=2e-5, atol=2e-5, err_msg=name)

@@ -217,3 +217,60 @@ def test_envelope_rejects_x64():
     for err_fn in (fused_support_error, pt_err, diff_err):
         assert "not supported by TPU" in err_fn((64, 128, 128), 2, 8)
         assert err_fn((64, 128, 128), 2, 4) is None
+
+
+@pytest.mark.parametrize(
+    "k,shape,o,bx",
+    [
+        (2, (16, 32, 128), 4, 8),
+        (2, (16, 32, 128), 6, 8),  # o > 2k: the sources sit deeper than the halo
+        (6, (32, 32, 128), 12, 8),
+        # 24 rows a tile: one step of the wrap's row-block loop and a rest
+        (2, (48, 32, 128), 4, 24),
+    ],
+)
+def test_z_wrap_matches_patch_export_cadence(k, shape, o, bx):
+    """The in-tile self-neighbour z wrap equals the patch/export kernel
+    followed by the top-face fix-up and the chunk-end patch apply, bit for
+    bit, over two groups from a state whose z halos are not fresh — the four
+    fields' real faces, the frozen Vx row n0 / Vy column n1 among them."""
+    from jax.experimental.pallas.tpu import force_tpu_interpret_mode
+
+    import implicitglobalgrid_tpu as igg
+    from implicitglobalgrid_tpu.ops.halo import (
+        apply_z_patches,
+        fix_topface_z_exports,
+        identity_z_patches,
+    )
+
+    state, params = _setup(shape, seed=7, spacing=(0.1, 0.15, 0.2), K=1.3, rho=0.8)
+    coef = (
+        params.dt / params.rho / params.dx, params.dt / params.rho / params.dy,
+        params.dt / params.rho / params.dz, params.dt * params.K,
+        1.0 / params.dx, 1.0 / params.dy, 1.0 / params.dz,
+    )
+    tile = dict(bx=bx, by=16)
+    # one device, periodic z: every block is its own z partner
+    igg.init_global_grid(*shape, periodz=1, overlapz=o, quiet=True,
+                         devices=jax.devices()[:1])
+    s0 = (state[0], *pad_faces(*state[1:]))
+    with force_tpu_interpret_mode():
+        s, patches = s0, identity_z_patches(*s0, width=k)
+        for _ in range(2):
+            out = fused_leapfrog_steps(
+                *s, k, *coef, **tile, z_patches=patches, z_export=True,
+                z_overlap=o,
+            )
+            s = out[:4]
+            # the self partner hands the exports back as the next patches
+            patches = fix_topface_z_exports(out[4:], *s, width=k)
+        want = apply_z_patches(*s, patches, width=k)
+        got = s0
+        for _ in range(2):
+            got = fused_leapfrog_steps(*got, k, *coef, **tile, z_wrap=True,
+                                       z_overlap=o)
+    want = (want[0], *unpad_faces(*want[1:]))
+    got = (got[0], *unpad_faces(*got[1:]))
+    for name, g, w, a in zip(("P", "Vx", "Vy", "Vz"), got, want, state):
+        assert not np.array_equal(np.asarray(g), np.asarray(a)), name
+        assert np.array_equal(np.asarray(g), np.asarray(w)), name

@@ -26,6 +26,7 @@ from implicitglobalgrid_tpu.ops.overlap import (
     tile_subset_map,
 )
 from implicitglobalgrid_tpu.analysis.ir import is_kernel_eqn
+from implicitglobalgrid_tpu.utils import telemetry
 from jax.experimental.pallas.tpu import force_tpu_interpret_mode
 
 
@@ -267,14 +268,30 @@ def test_pipelined_inadmissible_falls_back_warn_once():
     np.testing.assert_array_equal(ser, pip)
 
 
-def test_pipelined_acoustic_matches_serialized_bitwise():
+@pytest.mark.parametrize(
+    "dims",
+    [
+        # x split, each device its own z partner: the in-kernel z wrap
+        (2, 1, 1),
+        # x split and a real z partner: the patch/export kernel, its packed
+        # exports riding the x/y exchange
+        (2, 1, 2),
+    ],
+    ids=["x2-self", "x2z2"],
+)
+def test_pipelined_acoustic_matches_serialized_bitwise(dims):
     from implicitglobalgrid_tpu.models import acoustic3d
 
     def run(pipelined):
-        kw = dict(devices=jax.devices()[:2], dimx=2, dimy=1, dimz=1,
+        kw = dict(devices=jax.devices()[: dims[0] * dims[1] * dims[2]],
+                  dimx=dims[0], dimy=dims[1], dimz=dims[2],
                   overlapx=4, overlapy=4, overlapz=4, periodz=1, quiet=True,
                   dtype=jnp.float32)
         state, params = acoustic3d.setup(24, 32, 128, **kw)
+        # the split is admissible: pipelined=True runs the pipelined body
+        assert acoustic3d.pipelined_support_error(
+            (24, 32, 128), 2, bx=8, by=16) is None
+        wraps = counter()
         with force_tpu_interpret_mode():
             step = acoustic3d.make_multi_step(
                 params, 4, donate=False, fused_k=2, fused_tile=(8, 16),
@@ -282,7 +299,12 @@ def test_pipelined_acoustic_matches_serialized_bitwise():
             )
             out = [np.asarray(x) for x in jax.block_until_ready(step(*state))]
         igg.finalize_global_grid()
+        assert (counter() > wraps) == (dims[2] == 1)
         return out
+
+    def counter():
+        return telemetry.snapshot()["counters"].get(
+            acoustic3d.Z_SELF_WRAP_COUNTER, 0)
 
     for r, g in zip(run(False), run(True)):
         np.testing.assert_array_equal(r, g)

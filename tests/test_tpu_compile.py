@@ -74,10 +74,14 @@ NAMES = {
     "diffusion_k4_128_walls": ["igg_diffusion_k4_plain"],
     "acoustic_k6_128_walls": ["igg_leapfrog_k6_plain", "igg_faces_pad",
                               "igg_faces_unpad"],
-    # one chip: the four-field from_export hands the exports back untouched
+    # one chip: the kernel wraps z inside its tiles (its own z partner)
     "acoustic_k6_256_periodz": ["igg_leapfrog_k6_zpatch", "igg_faces_pad",
-                                "igg_faces_unpad", "igg_zpatch_identity",
-                                "igg_zpatch_apply"],
+                                "igg_faces_unpad"],
+}
+#: what a program's text must not name: the one-chip periodic-z acoustic
+#: program has no patch to make at chunk entry and none to apply at its end
+ABSENT = {
+    "acoustic_k6_256_periodz": ["igg_zpatch_identity", "igg_zpatch_apply"],
 }
 
 
@@ -169,6 +173,8 @@ def test_kernel_variants_are_named(compiled, name):
     txt = compiled[name]
     for want in NAMES[name]:
         assert want in txt, f"{name}: {want} not in the compiled program"
+    for gone in ABSENT.get(name, ()):
+        assert gone not in txt, f"{name}: {gone} in the compiled program"
     kernels = [ln for ln in txt.splitlines()
                if 'custom_call_target="tpu_custom_call"' in ln]
     assert kernels and all(
@@ -194,18 +200,16 @@ def test_no_field_copy_in_a_while_body(compiled, name):
 
 @pytest.mark.parametrize("name", ["acoustic_k6_128_walls", "acoustic_k6_256_periodz"])
 def test_no_carried_array_copy_in_acoustic_while_body(compiled, name):
-    """The looped acoustic schedule carries P, the three face fields in the
-    padded layout (n+8 on x and y, n+128 on z) and, on a periodic z, the
-    three packed z patches: no while body copies any of them, larger or
-    smaller than n^3."""
+    """The looped acoustic schedule carries P and the three face fields in
+    the padded layout (n+8 on x and y, n+128 on z); on a one-chip periodic z
+    the kernel wraps z in its tiles, so no z patches are carried: no while
+    body copies any of them, larger or smaller than n^3."""
     from implicitglobalgrid_tpu.ops import pallas_leapfrog
     from implicitglobalgrid_tpu.utils.hlo_analysis import while_body_copies
 
     n = PROGRAMS[name][1]
     cell = (n, n, n)
     shapes = [cell, *pallas_leapfrog.padded_face_shapes(cell)]
-    if name.endswith("periodz"):
-        shapes += pallas_leapfrog.z_patch_shapes(cell)
     carried = {int(np.prod(s)) for s in shapes}
     assert len(carried) > 2  # the padded sizes differ from n^3
     txt = compiled[name]
