@@ -53,9 +53,12 @@ TRACE_CALLEES = frozenset(
         "make_jaxpr",
         "eval_shape",
         # package-local combinators that call their arguments inside an
-        # enclosing trace (the fused group schedules)
+        # enclosing trace (the fused group schedules), and the binding of a
+        # kernel family's hooks, which the fused cadence calls in its trace
+        # (`models._fused.Family.bind`)
         "run_group_schedule",
         "run_pipelined_group_schedule",
+        "bind",
     }
 )
 

@@ -1,4 +1,11 @@
-"""Shared fused-kernel helpers (diffusion + acoustic + porous).
+"""The fused cadence of the three solvers (diffusion + acoustic + porous).
+
+Each solver declares its kernel family once (`Family`: the kernel module,
+the layout its kernel runs on, how one group launches, its z-patch
+operations, its XLA twin).  `fused_cadence` checks a ``fused_k`` request,
+builds the group bodies from that declaration and picks one at trace time
+with ONE gate (`pick_body`).  Adding a solver means a kernel module, a
+`Family` and its physics.
 
 Fallback warning: the reference's precedent is runtime path selection by
 threshold (`/root/reference/src/update_halo.jl:755-784`); here the selection
@@ -14,18 +21,25 @@ callable its ``igg.multi_step`` host span.
 
 Counters: ``fused.fallback`` (and ``fused.fallback.<model>``) count every
 fallback decision, one per trace of a kernel path the envelope rejects;
-``make_multi_step`` registers ``fused.fallback`` at 0, so a telemetry
-snapshot reads 0, not nothing, where no kernel path fell back.
+``fused.z_self_wrap`` counts traced kernel groups that do a self-neighbour z
+exchange inside the kernel's tiles.  ``make_multi_step`` registers both at
+0, so a telemetry snapshot reads 0, not nothing, where no kernel path fell
+back or wrapped.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
+from typing import Any, Callable
 
 _warned: set = set()
 
 #: the telemetry counter of fused-kernel fallback decisions
 FALLBACK_COUNTER = "fused.fallback"
+#: the telemetry counter of traced kernel groups that do the self-neighbour z
+#: exchange inside the kernel's tiles (a family with ``z_wrap``)
+Z_SELF_WRAP_COUNTER = "fused.z_self_wrap"
 
 
 def spanned_multi_step(model: str):
@@ -33,22 +47,86 @@ def spanned_multi_step(model: str):
     callable it returns — plain jit, `igg.stencil` or batched alike — runs
     each call inside one ``igg.multi_step`` span (`tracing.spanned_calls`)
     tagged ``model``, ``nsteps`` and ``call``, the callable's own count of
-    its calls.  Building one registers the ``fused.fallback`` counter (at 0
-    until a kernel path falls back, `warn_fused_fallback`)."""
+    its calls.  Building one registers the ``fused.fallback`` and
+    ``fused.z_self_wrap`` counters at 0.
+
+    ``autotune`` (None = ``IGG_AUTOTUNE``, default off) is read here for the
+    three models: the cached winner schedule of this point substitutes into
+    the five tunable keywords (`tuning.search.maybe_autotune`; explicit
+    keywords always win, results stay bit-identical)."""
     from ..utils import telemetry, tracing
 
     def deco(make):
         @functools.wraps(make)
-        def make_spanned(params, nsteps, *args, **kwargs):
+        def make_spanned(params, nsteps, *, autotune=None, batch=False, **kw):
+            from ..tuning.search import maybe_autotune
+
             telemetry.counter(FALLBACK_COUNTER).inc(0)
+            telemetry.counter(Z_SELF_WRAP_COUNTER).inc(0)
+            kw = maybe_autotune(model, params, nsteps, autotune, batch=batch, **kw)
             return tracing.spanned_calls(
-                make(params, nsteps, *args, **kwargs), "igg.multi_step",
+                make(params, nsteps, batch=batch, **kw), "igg.multi_step",
                 model=model, nsteps=int(nsteps),
             )
 
         return make_spanned
 
     return deco
+
+
+def stencil_call(fn, nfields: int, donate_argnums=(), batch: bool = False):
+    """`igg.stencil` of a per-block step, or with ``batch`` the ensemble step
+    over ``nfields`` batched fields (`models._batched.batched_stencil`)."""
+    if batch:
+        from ._batched import batched_stencil
+
+        return batched_stencil(fn, nfields, donate_argnums=donate_argnums)
+    from ..ops.stencil import stencil
+
+    return stencil(fn, donate_argnums=donate_argnums)
+
+
+def group_exchange(fields, width: int, early, coalesce=None) -> tuple:
+    """The XLA cadences' width-``width`` slab exchange of all ``fields``
+    after a group: `update_halo`, or with ``early`` the early-dispatch shape
+    ``pipelined=True`` asks for (`ops.halo.begin_slab_exchange` /
+    `finish_slab_exchange`, bit-identical values)."""
+    from .. import update_halo
+    from ..ops.halo import begin_slab_exchange, finish_slab_exchange
+
+    if early:
+        pend = begin_slab_exchange(fields, (0, 1, 2), width=width, coalesce=coalesce)
+        return finish_slab_exchange(fields, pend)
+    out = update_halo(*fields, width=width, coalesce=coalesce)
+    return out if len(fields) > 1 else (out,)
+
+
+def check_xla_cadence(fam, w: int, *, nsteps=None, pipelined=None,
+                      hide_comm: bool = False) -> None:
+    """Argument checks of a model's XLA paths at ``exchange_every=w``
+    (``nsteps``: where it must be a multiple of ``w``)."""
+    from ..ops.halo import require_deep_halo
+
+    if w < 1:
+        raise ValueError(f"exchange_every must be >= 1 (got {w})")
+    if w > 1:
+        if hide_comm:
+            raise ValueError(
+                "exchange_every and hide_comm are mutually exclusive: overlap "
+                f"scheduling hides the per-{fam.units[0]} exchange; a slab "
+                "cadence replaces it."
+            )
+        if nsteps is not None and nsteps % w != 0:
+            raise ValueError(
+                f"nsteps={nsteps} must be a multiple of exchange_every={w}"
+            )
+        require_deep_halo(w)
+    elif pipelined:
+        raise ValueError(
+            "pipelined applies to the group cadences (fused_k or "
+            f"exchange_every > 1); the per-{fam.units[0]} path has no group "
+            "schedule."
+        )
 
 
 def run_group_schedule(chunks, body, carry, *, unroll_limit=8,
@@ -227,20 +305,132 @@ def split_selector(kernel_mod, shape, k, width, itemsize, bx, by, active01,
     return tile_split_sel(active01), err, (bx, by)
 
 
-def pipelined_support_error(kernel_mod, shape, k, itemsize: int = 4,
-                            bx=None, by=None, gg=None,
-                            stagger: int = 0) -> str | None:
+@dataclasses.dataclass(frozen=True)
+class ZPatch:
+    """A layout's z-patch operations: the z-patch kernel variant applies a
+    patch per tile and exports the next group's z send slabs, and these
+    carry one group's exports to the next group's patch."""
+
+    identity: Callable  # (s, width) -> the patch that rewrites fresh halos
+    exchange: Callable  # (s, exports, width) -> (s, patch): serialized group
+    finish: Callable  # (exports, s, width) -> patch: after finish_slab_exchange
+    apply: Callable  # (s, patch, width) -> s: the chunk-end application
+    fix: Callable | None = None  # (exports, s, width) -> exports, first
+
+
+@dataclasses.dataclass(frozen=True)
+class Family:
+    """One solver's fused kernel family, declared once in its model module.
+
+    Fixed facts (the module's ``FAMILY``): ``name`` labels warnings and
+    counters; ``kernels`` is the kernel module (`ops/pallas_*.py`: its
+    envelope ``fused_support_error``, tile ladder ``default_tile`` and
+    kernel names); ``stagger=1`` where the face fields' ``ol`` is one deeper
+    than the grid overlap; ``z_wrap``: the kernel can do a self-neighbour z
+    exchange inside its tiles (``z_wrap=True, z_overlap=o``); ``units``:
+    what one kernel step is, for the error messages; ``idle_steps``: on a
+    grid with no halo activity the XLA twin is the one-step XLA path.
+
+    Hooks, bound per ``make_multi_step`` call (`bind`), on the layout ``s``
+    (a tuple of fields) the kernel runs on:
+
+    * ``enter(*state) -> (s, ctx)`` / ``leave(s, ctx) -> state``: into and
+      out of the layout (``ctx``: the read-only fields the kernel takes);
+    * ``launch(ki, s, ctx, bx, by, **kw)``: one kernel group, returning the
+      layout's fields and then any z exports (``kw``: ``z_patches`` with
+      ``z_overlap``, or ``z_wrap`` with ``z_overlap``, and ``tile_sel`` /
+      ``carry_in`` of a ring or interior pass);
+    * ``exchange(s, width[, dims])``: the slab exchange on the layout;
+    * ``logicals(s)``: the fields' real shapes for `begin_slab_exchange`;
+    * ``zpatch(s) -> ZPatch``;
+    * ``xla(w, early)``: the model's XLA cadence at ``w`` steps per
+      exchange, the fused cadence's twin (``early``: early-dispatch shape).
+    """
+
+    name: str
+    kernels: Any
+    stagger: int = 0
+    z_wrap: bool = False
+    units: tuple = ("step", "steps")
+    idle_steps: bool = False
+    enter: Callable | None = None
+    leave: Callable | None = None
+    launch: Callable | None = None
+    exchange: Callable | None = None
+    logicals: Callable = lambda s: None
+    zpatch: Callable | None = None
+    xla: Callable | None = None
+
+    def bind(self, **hooks) -> "Family":
+        return dataclasses.replace(self, **hooks)
+
+
+def pad_enter(C, *faces):
+    """``enter`` hook of the padded face layout (`ops.pallas_leapfrog.
+    pad_faces`: a cell field and three staggered face fields, +8 in x/y and
+    +128 in z), the layout the leapfrog and PT kernels run on."""
+    from ..ops.pallas_leapfrog import pad_faces
+
+    return (C, *pad_faces(*faces)), None
+
+
+def unpad_leave(s, ctx=None):
+    """``leave`` hook of the padded face layout (see `pad_enter`)."""
+    from ..ops.pallas_leapfrog import unpad_faces
+
+    return (s[0], *unpad_faces(*s[1:]))
+
+
+def padded_faces(coalesce=None) -> dict:
+    """Exchange hooks of the padded face layout (see `pad_enter`), shared by
+    the leapfrog and PT families: slab exchanges run on the padded fields
+    with their real ``n+1`` shapes, and the z patches go through the
+    padded-face set with its top-face fix-up."""
+    from ..ops import halo
+
+    def exchange(s, width, dims=None):
+        return halo.update_halo_padded_faces(
+            *s, width=width, dims=dims, coalesce=coalesce
+        )
+
+    def from_exports(exports, s, width):
+        return halo.z_patches_from_exports(exports, tuple(s[0].shape), width=width)
+
+    def z_exchange(s, exports, width):
+        # x/y slabs exchange outside for the fields, then the z
+        # communication runs on the packed exports alone
+        s = exchange(s, width, dims=(0, 1))
+        return s, from_exports(exports, s, width)
+
+    zpatch = ZPatch(
+        identity=lambda s, width: halo.identity_z_patches(*s, width=width),
+        fix=lambda exports, s, width: halo.fix_topface_z_exports(
+            exports, *s, width=width
+        ),
+        exchange=z_exchange,
+        finish=from_exports,
+        apply=lambda s, patch, width: halo.apply_z_patches(*s, patch, width=width),
+    )
+    return dict(
+        exchange=exchange,
+        logicals=lambda s: halo._padded_logicals(*s),
+        zpatch=lambda s: zpatch,
+    )
+
+
+def pipelined_support_error(fam, shape, k, itemsize: int = 4, bx=None,
+                            by=None, gg=None) -> str | None:
     """Why the pipelined group schedule cannot split this config, or None.
 
-    Mirrors the cadence builders' trace-time decision: on z-active grids
+    Mirrors the cadence's trace-time decision (`pick_body`): on a grid that
+    exchanges z with a partner (or whose family cannot wrap z in its tiles)
     the z-patch kernel variant must be admissible (the pipelined schedule
     routes z through the in-kernel patches; a z-DUS cadence stays
-    serialized), then the split must clear `split_selector`.  One
-    implementation for the three models (``kernel_mod`` = the model's
-    Pallas kernel module); the per-model wrappers
-    (`models.*.pipelined_support_error`) exist for benchmark provenance.
-    A kernel module with ``Z_SELF_WRAP`` wraps a self-neighbour z inside its
-    tiles, so such a z gates like an inactive one (the plain envelope).
+    serialized), then the split must clear `split_selector`.  A
+    self-neighbour z that the family wraps inside its tiles gates like an
+    inactive one (the plain envelope).  One implementation for the three
+    models; the per-model wrappers (`models.*.pipelined_support_error`)
+    exist for benchmark provenance.
     """
     from ..ops.halo import _partner_self, dim_has_halo_activity
     from ..parallel.grid import global_grid
@@ -248,11 +438,12 @@ def pipelined_support_error(kernel_mod, shape, k, itemsize: int = 4,
     if gg is None:
         gg = global_grid()
     shape = tuple(shape)
+    kernels = fam.kernels
     active = tuple(d for d in (0, 1) if dim_has_halo_activity(gg, d))
     z_active = dim_has_halo_activity(gg, 2) and not (
-        getattr(kernel_mod, "Z_SELF_WRAP", False) and _partner_self(gg, 2)
+        fam.z_wrap and _partner_self(gg, 2)
     )
-    zp = z_active and kernel_mod.fused_support_error(
+    zp = z_active and kernels.fused_support_error(
         shape, k, itemsize, bx, by, zpatch=True
     ) is None
     if z_active and not zp:
@@ -261,13 +452,281 @@ def pipelined_support_error(kernel_mod, shape, k, itemsize: int = 4,
         # The split only exists on a kernel path: a config the plain
         # envelope rejects runs the XLA cadence, and labeling it
         # "pipelined" would corrupt the A/B provenance.
-        kerr = kernel_mod.fused_support_error(shape, k, itemsize, bx, by)
+        kerr = kernels.fused_support_error(shape, k, itemsize, bx, by)
         if kerr is not None:
             return f"kernel envelope rejects this config ({kerr}): XLA cadence"
     _, err, _ = split_selector(
-        kernel_mod, shape, k, k, itemsize, bx, by, active, zp, stagger, gg
+        kernels, shape, k, k, itemsize, bx, by, active, zp, fam.stagger, gg
     )
     return err
+
+
+def pick_body(fam, *, active, z_active, z_self, plain_err, zpatch_err,
+              schedule_err, split_err, pipelined, shape, k) -> str:
+    """THE trace-time gate of every fused cadence: which body runs.
+
+    ``active``: some dimension has halo activity; ``z_active`` / ``z_self``:
+    z has, and every block is its own z partner; ``plain_err`` /
+    ``zpatch_err``: the kernel envelope's verdicts (None = admitted);
+    ``schedule_err``: why the schedule has no kernel group, or None;
+    ``split_err(zpatch)``: the ring/interior split's verdict.
+
+    1. z exchanged with a partner (or a self partner the family cannot wrap
+       in its tiles) and the z-patch envelope admits the block: the z-patch
+       body (``zpatch``, or ``zpatch_piped`` where the split is admitted).
+    2. Else the plain envelope admits it: ``plain`` — with the z wrap on a
+       self-neighbour z — or ``plain_piped`` where the grid communicates and
+       the split is admitted.
+    3. Else warn once and run the XLA twin (``xla``), with the
+       early-dispatch exchange (``xla_early``) on ``pipelined=True`` where
+       that twin has a group exchange to dispatch.
+    """
+    z_exchange = z_active and not (fam.z_wrap and z_self)
+    if z_exchange and schedule_err is None and zpatch_err is None:
+        piped = resolve_pipelined(pipelined, split_err(True), shape, k, fam.name)
+        return "zpatch_piped" if piped else "zpatch"
+    err = plain_err or schedule_err
+    if err is None:
+        piped = active and not z_exchange and resolve_pipelined(
+            pipelined, split_err(False), shape, k, fam.name
+        )
+        return "plain_piped" if piped else "plain"
+    warn_fused_fallback(shape, k, err, model=fam.name)
+    return "xla_early" if pipelined and (active or not fam.idle_steps) else "xla"
+
+
+def fused_cadence(fam, k, *, nsteps=None, chunks=None, schedule_err=None,
+                  fused_tile=None, exchange_every=1, pipelined=None,
+                  hide_comm=False, coalesce=None):
+    """Check a ``fused_k=k`` request and build the fused cadence of ``fam``:
+    returns ``(body, idle)``, ``body(*state) -> state`` picking its group
+    body at trace time (`pick_body`), ``idle`` when no dimension has halo
+    activity.
+
+    ``nsteps``: steps per call, run as groups of ``k`` (a multiple of ``k``);
+    or ``chunks``: the group sizes, with ``schedule_err`` why there is no
+    kernel group where the list is empty.  The bodies (serialized and
+    pipelined, plain and z-patch) run the group sequence through
+    `run_group_schedule` / `run_pipelined_group_schedule`; every group
+    after the first re-runs the kernel on the exchanged layout, ``k``
+    kernel steps per width-``k`` slab exchange (the kernel's k-deep
+    contaminated rind is exactly the slab the exchange refreshes, and the
+    sent planes ``[ol-k, ol)`` are still exact).  Kernel bodies are wrapped
+    with `fused_with_xla_grad`: ``jax.grad`` differentiates the XLA twin.
+    """
+    from ..ops.halo import (
+        _partner_self,
+        begin_slab_exchange,
+        dim_has_halo_activity,
+        finish_slab_exchange,
+        ol,
+        require_deep_halo,
+    )
+    from ..parallel.grid import global_grid
+    from ..utils import telemetry
+
+    gg = global_grid()
+    one, many = fam.units
+    if hide_comm:
+        raise ValueError(
+            "fused_k and hide_comm are mutually exclusive: the fused "
+            f"kernel's slab exchange is already amortized over k {one}s; "
+            f"overlap scheduling applies to the per-{one} XLA path."
+        )
+    if nsteps is not None and nsteps % k != 0:
+        raise ValueError(f"nsteps={nsteps} must be a multiple of fused_k={k}")
+    if exchange_every not in (1, k):
+        raise ValueError(
+            f"fused_k={k} already exchanges every fused_k {many}; "
+            f"exchange_every={exchange_every} conflicts."
+        )
+    require_deep_halo(k, gg, what="fused_k")
+    bx, by = fused_tile if fused_tile is not None else (None, None)
+    if (bx is None) != (by is None):
+        # A half-specified tile is a caller error, not a shape the kernel
+        # cannot run: raise eagerly rather than warn and fall back.
+        raise ValueError(f"fused_tile={fused_tile}: pass both bx and by, or neither")
+    if chunks is None:
+        chunks = [k] * (nsteps // k)
+    active = [d for d in range(3) if dim_has_halo_activity(gg, d)]
+    active01 = tuple(d for d in (0, 1) if d in active)
+    z_self = _partner_self(gg, 2)
+    # Every block its own z partner: a z_wrap kernel wraps z in its tiles.
+    wrap = fam.z_wrap and 2 in active and z_self
+    if not active and pipelined:
+        warn_pipelined_fallback(
+            None, k, "no halo activity: nothing to overlap", model=fam.name
+        )
+    if not active or (wrap and not active01):
+        exchange = None  # the kernel's output already has fresh halos
+    elif wrap:
+        exchange = lambda s: fam.exchange(s, k, dims=(0, 1))  # noqa: E731
+    else:
+        exchange = lambda s: fam.exchange(s, k)  # noqa: E731
+
+    def split(s, zpatch):
+        """(ring/mid selector suffix, admissibility error, resolved tile):
+        the pipelined bodies pin every group to the tile this validated."""
+        A = s[0]
+        return split_selector(
+            fam.kernels, tuple(A.shape), k, k, A.dtype.itemsize, bx, by,
+            active01, zpatch, fam.stagger, gg,
+        )
+
+    def z_kw(s):
+        """The kernel arguments of the in-tile z wrap (or none)."""
+        if not wrap:
+            return {}
+        return dict(z_wrap=True, z_overlap=ol(2, shape=tuple(s[0].shape), gg=gg))
+
+    def plain(*state):
+        s, ctx = fam.enter(*state)
+        zkw = z_kw(s)
+
+        def group(ki, s):
+            if wrap:
+                telemetry.counter(Z_SELF_WRAP_COUNTER).inc()
+            s = fam.launch(ki, s, ctx, bx, by, **zkw)
+            return s if exchange is None else exchange(s)
+
+        return fam.leave(run_group_schedule(chunks, group, s), ctx)
+
+    def plain_piped(*state):
+        # Boundary-first split of `plain`: the ring pass feeds the x/y slab
+        # exchange early, the interior pass runs across the in-flight
+        # collectives, the received slabs land on the aliased output.
+        s0, ctx = fam.enter(*state)
+        sel, _, tile = split(s0, False)
+        logicals = fam.logicals(s0)
+        zkw = z_kw(s0)
+
+        def boundary(ki, s):
+            if wrap:
+                telemetry.counter(Z_SELF_WRAP_COUNTER).inc()
+            out_b = fam.launch(ki, s, ctx, *tile, tile_sel="ring" + sel, **zkw)
+            return out_b, begin_slab_exchange(
+                out_b, (0, 1), width=k, logicals=logicals, coalesce=coalesce
+            )
+
+        def interior(ki, s, out_b, pend):
+            out = fam.launch(
+                ki, s, ctx, *tile, tile_sel="mid" + sel, carry_in=out_b, **zkw
+            )
+            return finish_slab_exchange(out, pend, logicals=logicals)
+
+        s = run_pipelined_group_schedule(chunks, boundary, interior, s0)
+        return fam.leave(s, ctx)
+
+    def zpatch(*state):
+        # The kernel applies the z patch per tile in VMEM AND exports the
+        # next group's send slabs (extraction outside the kernel paid
+        # whole-array relayouts per group); x/y slabs exchange outside.
+        s0, ctx = fam.enter(*state)
+        zp = fam.zpatch(s0)
+        o_z = ol(2, shape=tuple(s0[0].shape), gg=gg)
+        n = len(s0)
+        # Chunk entry has fresh halos: the first group's patch re-writes
+        # the planes already in place.
+        patch0 = zp.identity(s0, k)
+
+        def group(ki, carry):
+            s, patch = carry
+            out = fam.launch(ki, s, ctx, bx, by, z_patches=patch, z_overlap=o_z)
+            s, exports = out[:n], out[n:]
+            if zp.fix is not None:
+                exports = zp.fix(exports, s, k)
+            return zp.exchange(s, exports, k)
+
+        s, patch = run_group_schedule(chunks, group, (s0, patch0))
+        # One whole-array application restores the chunk-boundary
+        # fresh-halo invariant (amortized over the whole chunk).
+        return fam.leave(zp.apply(s, patch, k), ctx)
+
+    def zpatch_piped(*state):
+        # Boundary-first split of `zpatch`: the fields' x/y slabs exchange
+        # early off the ring pass; the z exports (which every tile feeds)
+        # complete with the interior pass, and their thin communication
+        # stays on the group's serialized tail.
+        s0, ctx = fam.enter(*state)
+        zp = fam.zpatch(s0)
+        o_z = ol(2, shape=tuple(s0[0].shape), gg=gg)
+        n = len(s0)
+        patch0 = zp.identity(s0, k)
+        sel, _, tile = split(s0, True)
+        logicals = fam.logicals(s0)
+
+        def boundary(ki, carry):
+            s, patch = carry
+            out_b = fam.launch(
+                ki, s, ctx, *tile, z_patches=patch, z_overlap=o_z,
+                tile_sel="ring" + sel,
+            )
+            return out_b, begin_slab_exchange(
+                out_b[:n], (0, 1), width=k, logicals=logicals, coalesce=coalesce
+            )
+
+        def interior(ki, carry, out_b, pend):
+            s, patch = carry
+            out = fam.launch(
+                ki, s, ctx, *tile, z_patches=patch, z_overlap=o_z,
+                tile_sel="mid" + sel, carry_in=out_b,
+            )
+            s2, exports = out[:n], out[n:]
+            if zp.fix is not None:  # reads the PRE-exchange outputs
+                exports = zp.fix(exports, s2, k)
+            s2 = finish_slab_exchange(s2, pend, logicals=logicals)
+            return s2, zp.finish(exports, s2, k)
+
+        s, patch = run_pipelined_group_schedule(
+            chunks, boundary, interior, (s0, patch0)
+        )
+        return fam.leave(zp.apply(s, patch, k), ctx)
+
+    bodies = dict(plain=plain, plain_piped=plain_piped, zpatch=zpatch,
+                  zpatch_piped=zpatch_piped)
+    twin_w = 1 if not active and fam.idle_steps else k
+
+    def gated(*state):
+        # Shapes are only known at trace time, so the kernel-vs-fallback
+        # choice happens there: a local block the kernel's envelope
+        # rejects warns once and runs the XLA twin at the same exchange
+        # cadence (the deep halo is already validated above).
+        A = state[0]
+        shape, isz = tuple(A.shape), A.dtype.itemsize
+        env = fam.kernels.fused_support_error
+        pick = pick_body(
+            fam, active=bool(active), z_active=2 in active, z_self=z_self,
+            plain_err=env(shape, k, isz, bx, by),
+            zpatch_err=env(shape, k, isz, bx, by, zpatch=True),
+            schedule_err=schedule_err,
+            split_err=lambda zpatch: split(state, zpatch)[1],
+            pipelined=pipelined, shape=shape, k=k,
+        )
+        if pick == "xla_early":
+            return fam.xla(k, True)(*state)
+        twin = fam.xla(twin_w, False)
+        if pick == "xla":
+            return twin(*state)
+        return fused_with_xla_grad(bodies[pick], twin)(*state)
+
+    # A lambda: the jitted program is then named `jit__lambda`, the prefix
+    # of every op name a device trace of the cadence reads.
+    return (lambda *state: gated(*state)), not active
+
+
+def fused_multi_step(fam, k, nsteps, *, nfields, donate_argnums=(),
+                     batch=False, **knobs):
+    """`fused_cadence` of ``nsteps`` steps per call, as one program: on a
+    grid with no halo activity a plain `jax.jit` on the grid's one device
+    (no collectives, so no shard_map), else `stencil_call`."""
+    body, idle = fused_cadence(fam, k, nsteps=nsteps, **knobs)
+    if not idle:
+        return stencil_call(body, nfields, donate_argnums, batch)
+    import jax
+
+    if batch:
+        body = jax.vmap(body)
+    return jax.jit(body, donate_argnums=donate_argnums)
 
 
 def warn_pipelined_fallback(shape, k, reason, model: str = "diffusion") -> None:

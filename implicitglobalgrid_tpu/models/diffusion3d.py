@@ -47,7 +47,9 @@ from .. import (
     update_halo,
     zeros,
 )
+from ..ops import pallas_stencil
 from ..ops.overlap import hide_communication
+from . import _fused
 
 
 @dataclasses.dataclass(frozen=True)
@@ -69,10 +71,6 @@ class Params:
 
 def _inn(A):
     return A[1:-1, 1:-1, 1:-1]
-
-
-from ._fused import spanned_multi_step as _spanned_multi_step
-from ._fused import warn_fused_fallback as _warn_fused_fallback  # shared w/ acoustic
 
 
 def _gaussians(X, Y, Z, params: Params, jnp):
@@ -202,13 +200,7 @@ def make_step(params: Params, *, donate: bool = True, batch: bool = False):
             T = update_halo(T)
             return T, Cp
 
-    if batch:
-        from ._batched import batched_stencil
-
-        return batched_stencil(
-            block_step, 2, donate_argnums=(0,) if donate else ()
-        )
-    return stencil(block_step, donate_argnums=(0,) if donate else ())
+    return _fused.stencil_call(block_step, 2, (0,) if donate else (), batch)
 
 
 def pipelined_support_error(shape, k, itemsize: int = 4, bx=None, by=None,
@@ -219,10 +211,7 @@ def pipelined_support_error(shape, k, itemsize: int = 4, bx=None, by=None,
     time (`models._fused.pipelined_support_error` over the diffusion
     kernel's envelope) — exported for benchmark provenance.
     """
-    from ..ops import pallas_stencil
-    from ._fused import pipelined_support_error as _generic
-
-    return _generic(pallas_stencil, shape, k, itemsize, bx, by, gg, stagger=0)
+    return _fused.pipelined_support_error(FAMILY, shape, k, itemsize, bx, by, gg)
 
 
 def _tune_state(params: Params):
@@ -237,7 +226,60 @@ def _tune_state(params: Params):
     return ones(shape, params.dtype), ones(shape, params.dtype)
 
 
-@_spanned_multi_step("diffusion3d")
+def _cell_zpatch(transposed: bool, coalesce=None):
+    """The diffusion kernel's z-patch operations on its one cell field:
+    full-y tiles take the transposed thin-plane patch layout
+    (`ops.pallas_stencil.zpatch_transposed`, ~16x less patch/export window
+    traffic), others the packed 128-lane one.  The x/y exchange of ``T``
+    and of its export run in ONE coalesced pass (one permute pair per dim
+    for the pair of fields; corner semantics preserved), then the z
+    communication runs on the export alone."""
+    from ..ops import halo
+
+    if transposed:
+        def exchange(s, exports, width):
+            T, zex = halo.exchange_dims_multi(
+                (s[0], *exports), (0, 1), width=width,
+                logicals=(None, tuple(s[0].shape)), axes=(None, halo._T_AXES),
+                coalesce=coalesce,
+            )
+            return (T,), halo.z_patch_from_export_t(zex, width=width)
+
+        def finish(exports, s, width):
+            zex = halo.exchange_dims_t(
+                exports[0], width=width, shape=tuple(s[0].shape), coalesce=coalesce
+            )
+            return halo.z_patch_from_export_t(zex, width=width)
+
+        return _fused.ZPatch(
+            identity=lambda s, width: halo.identity_z_patch_t(s[0], width=width),
+            exchange=exchange, finish=finish,
+            apply=lambda s, p, width: (halo.apply_z_patch_t(s[0], p, width=width),),
+        )
+
+    def exchange(s, exports, width):
+        T, zex = halo.exchange_dims_multi(
+            (s[0], *exports), (0, 1), width=width, coalesce=coalesce
+        )
+        return (T,), halo.z_patch_from_export(zex, width=width)
+
+    def finish(exports, s, width):
+        zex = halo.exchange_dims(exports[0], (0, 1), width=width)
+        return halo.z_patch_from_export(zex, width=width)
+
+    return _fused.ZPatch(
+        identity=lambda s, width: halo.identity_z_patch(s[0], width=width),
+        exchange=exchange, finish=finish,
+        apply=lambda s, p, width: (halo.apply_z_patch(s[0], p, width=width),),
+    )
+
+
+#: the diffusion kernel family (`ops/pallas_stencil.py`); its hooks are bound
+#: per `make_multi_step` call
+FAMILY = _fused.Family("diffusion", pallas_stencil, idle_steps=True)
+
+
+@_fused.spanned_multi_step("diffusion3d")
 def make_multi_step(
     params: Params,
     nsteps: int,
@@ -249,7 +291,6 @@ def make_multi_step(
     pipelined: bool | None = None,
     batch: bool = False,
     coalesce: bool | None = None,
-    autotune: bool | None = None,
 ):
     """Like `make_step` but advances ``nsteps`` steps per call via `lax.fori_loop`.
 
@@ -280,7 +321,8 @@ def make_multi_step(
     exchange (`update_halo(T, width=fused_k)`) — k steps per HBM pass *and*
     per collective, so both the memory and the latency cost amortize.
     Requires ``nsteps % fused_k == 0`` and TPU-compatible shapes (see
-    `fused_diffusion_steps`).
+    `fused_diffusion_steps`); the cadence and its trace-time choice of
+    kernel path are `models._fused.fused_cadence`'s.
 
     ``pipelined`` (default auto): run the fused groups on the
     boundary-first pipelined schedule
@@ -312,416 +354,85 @@ def make_multi_step(
     cached winner config of this (backend, topology, model, local size,
     dtype, batch) point into the kwargs above — searching (cost-model
     pruned, short measured runs) and persisting it on first use
-    (`implicitglobalgrid_tpu.tuning`, docs/performance.md).  A pure
-    schedule substitution: results stay bit-identical to the default
-    config.  Explicitly-set kwargs always win — autotune only fills fields
-    left at their defaults.
+    (`implicitglobalgrid_tpu.tuning`, docs/performance.md;
+    `models._fused.spanned_multi_step` reads it).  A pure schedule
+    substitution: results stay bit-identical to the default config.
+    Explicitly-set kwargs always win — autotune only fills fields left at
+    their defaults.
     """
     from jax import lax
 
-    from ..tuning.search import maybe_autotune
+    update = _diffusion_update(params)
+    donate_argnums = (0,) if donate else ()
 
-    fused_k, fused_tile, exchange_every, pipelined, coalesce = maybe_autotune(
-        "diffusion3d", params, nsteps, autotune, batch=batch,
-        fused_k=fused_k, fused_tile=fused_tile, exchange_every=exchange_every,
-        pipelined=pipelined, coalesce=coalesce,
-    )
+    def xla_path(w, early=False):
+        """``w`` steps per exchange: ``w=1`` the per-step exchange (overlapped
+        under ``hide_comm``), else a width-``w`` slab exchange after every
+        ``w`` steps (``early``: its early-dispatch shape)."""
+        if w == 1:
+            if params.hide_comm:
+                one = hide_communication(update, radius=1)
+            else:
 
-    def _wrap(block_fn):
-        dn = (0,) if donate else ()
-        if batch:
-            from ._batched import batched_stencil
+                def one(T, Cp):
+                    return update_halo(update(T, Cp), coalesce=coalesce)
 
-            return batched_stencil(block_fn, 2, donate_argnums=dn)
-        return stencil(block_fn, donate_argnums=dn)
-
-    if fused_k:
-        from ..parallel.grid import global_grid
-        from ..ops.pallas_stencil import fused_diffusion_steps, fused_support_error
-
-        gg = global_grid()
-        if params.hide_comm:
-            raise ValueError(
-                "fused_k and hide_comm are mutually exclusive: the fused "
-                "kernel's slab exchange is already amortized over k steps; "
-                "overlap scheduling applies to the per-step XLA path."
-            )
-        if nsteps % fused_k != 0:
-            raise ValueError(f"nsteps={nsteps} must be a multiple of fused_k={fused_k}")
-        if exchange_every not in (1, fused_k):
-            raise ValueError(
-                f"fused_k={fused_k} already exchanges every fused_k steps; "
-                f"exchange_every={exchange_every} conflicts."
-            )
-        import jax
-
-        from ..ops.halo import require_deep_halo
-
-        require_deep_halo(fused_k, gg, what="fused_k")
-        from ..ops.halo import dim_has_halo_activity
-
-        active = [d for d in range(3) if dim_has_halo_activity(gg, d)]
-        update = _diffusion_update(params)
-        cx = params.dt * params.lam / (params.dx * params.dx)
-        cy = params.dt * params.lam / (params.dy * params.dy)
-        cz = params.dt * params.lam / (params.dz * params.dz)
-        bx, by = fused_tile if fused_tile is not None else (None, None)
-        if (bx is None) != (by is None):
-            # A half-specified tile is a caller error, not a shape the kernel
-            # cannot run — raise eagerly rather than warn-and-fall-back.
-            raise ValueError(f"fused_tile={fused_tile}: pass both bx and by, or neither")
-
-        z_active = dim_has_halo_activity(gg, 2)
-
-        # Shapes are only known at trace time, so the kernel-vs-fallback
-        # choice happens there: a local block the kernel's envelope rejects
-        # warns once and runs the XLA path at the SAME exchange cadence
-        # (w steps per width-w slab exchange — the deep halo is already
-        # validated above), the reference's runtime-path-selection move
-        # (`/root/reference/src/update_halo.jl:755-784`).
-        from ._fused import fused_with_xla_grad, resolve_pipelined, split_selector
-
-        active01 = tuple(d for d in (0, 1) if d in active)
-
-        def _split(shape, itemsize, zpatch):
-            """(ring/mid selector suffix, admissibility error) for the
-            resolved tile — the shared trace-time gate (`split_selector`)."""
-            from ..ops import pallas_stencil
-
-            return split_selector(
-                pallas_stencil, shape, fused_k, fused_k, itemsize, bx, by,
-                active01, zpatch, stagger=0, gg=gg,
-            )
-
-        def fused_or_fallback(T, Cp, fused_body, xla_body, zpatch_body=None,
-                              pipelined_bodies=None):
-            # Kernel paths are wrapped with `fused_with_xla_grad`: the
-            # primal runs the Pallas chunk, jax.grad differentiates the
-            # XLA-cadence twin (the kernels have no VJP).
-            shape = tuple(T.shape)
-            pb = pipelined_bodies or {}
-            if (
-                zpatch_body is not None
-                and z_active
-                and fused_support_error(
-                    shape, fused_k, T.dtype.itemsize, bx, by, zpatch=True
-                ) is None
-            ):
-                # In-kernel z-slab application (docs/performance.md's
-                # exchanged-dimension anisotropy note).
-                body = zpatch_body
-                if "zpatch" in pb and resolve_pipelined(
-                    pipelined, _split(shape, T.dtype.itemsize, True)[1],
-                    shape, fused_k, "diffusion",
-                ):
-                    body = pb["zpatch"]
-                return fused_with_xla_grad(body, xla_body)(T, Cp)
-            err = fused_support_error(shape, fused_k, T.dtype.itemsize, bx, by)
-            if err is None:
-                body = fused_body
-                # The non-zpatch pipelined split only exists on z-inactive
-                # grids (a z-DUS exchange spans every tile's rows).
-                if "plain" in pb and not z_active and resolve_pipelined(
-                    pipelined, _split(shape, T.dtype.itemsize, False)[1],
-                    shape, fused_k, "diffusion",
-                ):
-                    body = pb["plain"]
-                return fused_with_xla_grad(body, xla_body)(T, Cp)
-            _warn_fused_fallback(tuple(T.shape), fused_k, err)
-            if pipelined and "xla" in pb:
-                # Explicit request: the XLA cadence with the early-dispatch
-                # exchange shape (begin/finish; bit-identical values).
-                return pb["xla"](T, Cp)
-            return xla_body(T, Cp)
-
-        from ._fused import run_group_schedule
-
-        groups = [fused_k] * (nsteps // fused_k)
-
-        if not active:
-            if pipelined:
-                from ._fused import warn_pipelined_fallback
-
-                warn_pipelined_fallback(
-                    None, fused_k, "no halo activity: nothing to overlap"
-                )
-
-            def fused_chunk(T, Cp):
-                T = run_group_schedule(
-                    groups,
-                    lambda ki, T: fused_diffusion_steps(
-                        T, Cp, ki, cx, cy, cz, bx=bx, by=by
-                    ),
-                    T,
-                )
+            def block_step(T, Cp):
+                T = lax.fori_loop(0, nsteps, lambda i, T: one(T, Cp), T)
                 return T, Cp
 
-            def xla_chunk(T, Cp):
-                # No halo activity: the exchange is a no-op, plain steps.
-                return lax.fori_loop(0, nsteps, lambda i, T: update(T, Cp), T), Cp
-
-            # No halo activity means no collectives: skip the shard_map
-            # wrapper and jit directly (fields are committed to the grid's
-            # single device).
-            body = lambda T, Cp: fused_or_fallback(T, Cp, fused_chunk, xla_chunk)
-            if batch:
-                body = jax.vmap(body)
-            return jax.jit(body, donate_argnums=(0,) if donate else ())
-
-        def fused_block_step(T, Cp):
-            def body(ki, T):
-                T = fused_diffusion_steps(T, Cp, ki, cx, cy, cz, bx=bx, by=by)
-                # One slab exchange licenses the next fused_k steps: the
-                # kernel's k-deep contaminated rind is exactly the region
-                # the width-k exchange refreshes, and the sent planes
-                # [ol-k, ol) sit at distance >= k from the block edge,
-                # where k kernel steps are still exact.
-                return update_halo(T, width=fused_k, coalesce=coalesce)
-
-            return run_group_schedule(groups, body, T), Cp
-
-        def fused_zpatch_step(T, Cp):
-            from ..ops.halo import (
-                _T_AXES,
-                apply_z_patch,
-                apply_z_patch_t,
-                exchange_dims_multi,
-                identity_z_patch,
-                identity_z_patch_t,
-                ol,
-                z_patch_from_export,
-                z_patch_from_export_t,
-            )
-            from ..ops.pallas_stencil import zpatch_transposed
-
-            shape = tuple(T.shape)
-            o_z = ol(2, shape=shape, gg=gg)
-            # Patch layout follows the kernel's tile choice: full-y tiles
-            # take the transposed thin-plane layout (round 5 — ~16x less
-            # patch/export window traffic), others the packed 128-lane one.
-            tr = zpatch_transposed(shape, fused_k, T.dtype.itemsize, bx, by)
-
-            def group(ki, carry):
-                T, patch = carry
-                # The kernel applies the z patch per tile in VMEM AND
-                # exports the next group's send slabs (round 4: extraction
-                # outside the kernel paid whole-array relayouts per group);
-                # x/y slabs exchange outside (cheap DUS) for both T and the
-                # packed export IN ONE COALESCED PASS (one permute pair per
-                # dim for the pair of fields; corner semantics preserved),
-                # then the z communication runs on the packed array alone.
-                T, zex = fused_diffusion_steps(
-                    T, Cp, fused_k, cx, cy, cz, bx=bx, by=by, z_patch=patch,
-                    z_export=True, z_overlap=o_z,
-                )
-                if tr:
-                    T, zex = exchange_dims_multi(
-                        (T, zex), (0, 1), width=fused_k,
-                        logicals=(None, shape), axes=(None, _T_AXES),
-                        coalesce=coalesce,
-                    )
-                    return T, z_patch_from_export_t(zex, width=fused_k)
-                T, zex = exchange_dims_multi(
-                    (T, zex), (0, 1), width=fused_k, coalesce=coalesce
-                )
-                return T, z_patch_from_export(zex, width=fused_k)
-
-            mk_ident = identity_z_patch_t if tr else identity_z_patch
-            T, patch = run_group_schedule(
-                groups, group, (T, mk_ident(T, width=fused_k))
-            )
-            mk_apply = apply_z_patch_t if tr else apply_z_patch
-            return mk_apply(T, patch, width=fused_k), Cp
-
-        def fused_pipelined_block_step(T, Cp):
-            # Boundary-first split of `fused_block_step` (z-inactive grids):
-            # the ring pass feeds the x/y slab exchange early, the interior
-            # pass runs across the in-flight collectives, the received
-            # slabs land on the aliased combined output.
-            from ..ops.halo import begin_slab_exchange, finish_slab_exchange
-            from ._fused import run_pipelined_group_schedule
-
-            sel, _, _ = _split(tuple(T.shape), T.dtype.itemsize, False)
-
-            def boundary(ki, T):
-                Tb = fused_diffusion_steps(
-                    T, Cp, ki, cx, cy, cz, bx=bx, by=by, tile_sel="ring" + sel
-                )
-                return (Tb,), begin_slab_exchange(
-                    (Tb,), (0, 1), width=fused_k, coalesce=coalesce
-                )
-
-            def interior(ki, T, b_out, pend):
-                T2 = fused_diffusion_steps(
-                    T, Cp, ki, cx, cy, cz, bx=bx, by=by,
-                    tile_sel="mid" + sel, carry_in=b_out,
-                )
-                (T2,) = finish_slab_exchange((T2,), pend)
-                return T2
-
-            return run_pipelined_group_schedule(groups, boundary, interior, T), Cp
-
-        def fused_zpatch_pipelined_step(T, Cp):
-            # Boundary-first split of `fused_zpatch_step`: x/y slabs of T
-            # exchange early off the ring pass; the packed z export (which
-            # every tile feeds) completes with the interior pass and its
-            # thin communication stays on the serialized tail of the group.
-            from ..ops.halo import (
-                apply_z_patch,
-                apply_z_patch_t,
-                begin_slab_exchange,
-                exchange_dims,
-                exchange_dims_t,
-                finish_slab_exchange,
-                identity_z_patch,
-                identity_z_patch_t,
-                ol,
-                z_patch_from_export,
-                z_patch_from_export_t,
-            )
-            from ..ops.pallas_stencil import zpatch_transposed
-            from ._fused import run_pipelined_group_schedule
-
-            shape = tuple(T.shape)
-            o_z = ol(2, shape=shape, gg=gg)
-            tr = zpatch_transposed(shape, fused_k, T.dtype.itemsize, bx, by)
-            sel, _, _ = _split(shape, T.dtype.itemsize, True)
-
-            def boundary(ki, carry):
-                T, patch = carry
-                b_out = fused_diffusion_steps(
-                    T, Cp, fused_k, cx, cy, cz, bx=bx, by=by, z_patch=patch,
-                    z_export=True, z_overlap=o_z, tile_sel="ring" + sel,
-                )
-                pend = begin_slab_exchange(
-                    b_out[:1], (0, 1), width=fused_k, coalesce=coalesce
-                )
-                return b_out, pend
-
-            def interior(ki, carry, b_out, pend):
-                T, patch = carry
-                T2, zex = fused_diffusion_steps(
-                    T, Cp, fused_k, cx, cy, cz, bx=bx, by=by, z_patch=patch,
-                    z_export=True, z_overlap=o_z,
-                    tile_sel="mid" + sel, carry_in=b_out,
-                )
-                (T2,) = finish_slab_exchange((T2,), pend)
-                if tr:
-                    zex = exchange_dims_t(
-                        zex, width=fused_k, shape=shape, coalesce=coalesce
-                    )
-                    return T2, z_patch_from_export_t(zex, width=fused_k)
-                zex = exchange_dims(zex, (0, 1), width=fused_k)
-                return T2, z_patch_from_export(zex, width=fused_k)
-
-            mk_ident = identity_z_patch_t if tr else identity_z_patch
-            T, patch = run_pipelined_group_schedule(
-                groups, boundary, interior, (T, mk_ident(T, width=fused_k))
-            )
-            mk_apply = apply_z_patch_t if tr else apply_z_patch
-            return mk_apply(T, patch, width=fused_k), Cp
-
-        def xla_cadence_step(T, Cp):
-            def group(i, T):
-                T = lax.fori_loop(0, fused_k, lambda j, T: update(T, Cp), T)
-                return update_halo(T, width=fused_k, coalesce=coalesce)
-
-            return lax.fori_loop(0, nsteps // fused_k, group, T), Cp
-
-        def xla_pipelined_cadence_step(T, Cp):
-            # The XLA fallback with the early-dispatch exchange shape: the
-            # group's permutes depend on slab slices only (begin), the
-            # received planes land lazily (finish).  Values bit-identical
-            # to `xla_cadence_step`; there is no tile split to ride, so
-            # only `pipelined=True` selects it.
-            from ..ops.halo import begin_slab_exchange, finish_slab_exchange
-
-            def group(i, T):
-                T = lax.fori_loop(0, fused_k, lambda j, T: update(T, Cp), T)
-                pend = begin_slab_exchange(
-                    (T,), (0, 1, 2), width=fused_k, coalesce=coalesce
-                )
-                (T,) = finish_slab_exchange((T,), pend)
-                return T
-
-            return lax.fori_loop(0, nsteps // fused_k, group, T), Cp
-
-        return _wrap(
-            lambda T, Cp: fused_or_fallback(
-                T, Cp, fused_block_step, xla_cadence_step, fused_zpatch_step,
-                pipelined_bodies={
-                    "plain": fused_pipelined_block_step,
-                    "zpatch": fused_zpatch_pipelined_step,
-                    "xla": xla_pipelined_cadence_step,
-                },
-            )
-        )
-
-    update = _diffusion_update(params)
-
-    if exchange_every < 1:
-        raise ValueError(f"exchange_every must be >= 1 (got {exchange_every})")
-    if exchange_every > 1:
-        from ..ops.halo import require_deep_halo
-
-        if params.hide_comm:
-            raise ValueError(
-                "exchange_every and hide_comm are mutually exclusive: overlap "
-                "scheduling hides the per-step exchange; a slab cadence "
-                "replaces it."
-            )
-        if nsteps % exchange_every != 0:
-            raise ValueError(
-                f"nsteps={nsteps} must be a multiple of exchange_every={exchange_every}"
-            )
-        require_deep_halo(exchange_every)
-        w = exchange_every
+            return block_step
 
         def block_step(T, Cp):
             def group(i, T):
                 T = lax.fori_loop(0, w, lambda j, T: update(T, Cp), T)
-                if pipelined:
-                    # Early-dispatch exchange shape (bit-identical values);
-                    # see the ``pipelined`` docstring note.
-                    from ..ops.halo import (
-                        begin_slab_exchange,
-                        finish_slab_exchange,
-                    )
+                return _fused.group_exchange((T,), w, early, coalesce)[0]
 
-                    pend = begin_slab_exchange(
-                        (T,), (0, 1, 2), width=w, coalesce=coalesce
-                    )
-                    (T,) = finish_slab_exchange((T,), pend)
-                    return T
-                return update_halo(T, width=w, coalesce=coalesce)
+            return lax.fori_loop(0, nsteps // w, group, T), Cp
 
-            T = lax.fori_loop(0, nsteps // w, group, T)
-            return T, Cp
+        return block_step
 
-        return _wrap(block_step)
+    if fused_k:
+        from ..ops.pallas_stencil import fused_diffusion_steps, zpatch_transposed
 
-    if pipelined:
-        raise ValueError(
-            "pipelined applies to the group cadences (fused_k or "
-            "exchange_every > 1); the per-step path has no group schedule."
+        cx = params.dt * params.lam / (params.dx * params.dx)
+        cy = params.dt * params.lam / (params.dy * params.dy)
+        cz = params.dt * params.lam / (params.dz * params.dz)
+
+        def launch(ki, s, Cp, bx, by, z_patches=None, **kw):
+            if z_patches is not None:
+                kw.update(z_patch=z_patches, z_export=True)
+            out = fused_diffusion_steps(s[0], Cp, ki, cx, cy, cz, bx=bx, by=by, **kw)
+            return out if isinstance(out, tuple) else (out,)
+
+        def zpatch(s):
+            T, tile = s[0], fused_tile or (None, None)
+            tr = zpatch_transposed(tuple(T.shape), fused_k, T.dtype.itemsize, *tile)
+            return _cell_zpatch(tr, coalesce)
+
+        fam = FAMILY.bind(
+            enter=lambda T, Cp: ((T,), Cp),
+            leave=lambda s, Cp: (s[0], Cp),
+            launch=launch,
+            exchange=lambda s, width: (
+                update_halo(s[0], width=width, coalesce=coalesce),
+            ),
+            zpatch=zpatch,
+            xla=xla_path,
+        )
+        return _fused.fused_multi_step(
+            fam, fused_k, nsteps, nfields=2, donate_argnums=donate_argnums,
+            batch=batch, fused_tile=fused_tile, exchange_every=exchange_every,
+            pipelined=pipelined, hide_comm=params.hide_comm, coalesce=coalesce,
         )
 
-    if params.hide_comm:
-        overlapped = hide_communication(update, radius=1)
-
-        def one(T, Cp):
-            return overlapped(T, Cp)
-
-    else:
-
-        def one(T, Cp):
-            return update_halo(update(T, Cp), coalesce=coalesce)
-
-    def block_step(T, Cp):
-        T = lax.fori_loop(0, nsteps, lambda i, T: one(T, Cp), T)
-        return T, Cp
-
-    return _wrap(block_step)
+    _fused.check_xla_cadence(
+        FAMILY, exchange_every, nsteps=nsteps, pipelined=pipelined,
+        hide_comm=params.hide_comm,
+    )
+    return _fused.stencil_call(
+        xla_path(exchange_every, bool(pipelined)), 2, donate_argnums, batch
+    )
 
 
 def run(

@@ -43,8 +43,9 @@ from .. import (
     update_halo,
     zeros,
 )
+from ..ops import pallas_pt
 from ..ops.overlap import hide_communication
-from ._fused import spanned_multi_step as _spanned_multi_step
+from . import _fused
 
 
 @dataclasses.dataclass(frozen=True)
@@ -333,14 +334,9 @@ def make_step(params: Params, *, donate: bool = True, batch: bool = False):
     `models.diffusion3d.make_step`).  No ``igg.multi_step`` span: at one
     step per call its microseconds would be a visible share of a step.
     """
-    donate_argnums = tuple(range(5)) if donate else ()
-    if batch:
-        from ._batched import batched_stencil
-
-        return batched_stencil(
-            _build_block_step(params), 5, donate_argnums=donate_argnums
-        )
-    return stencil(_build_block_step(params), donate_argnums=donate_argnums)
+    return _fused.stencil_call(
+        _build_block_step(params), 5, tuple(range(5)) if donate else (), batch
+    )
 
 
 def _pt_schedule(npt: int, w: int, *, even: bool = True):
@@ -378,17 +374,20 @@ def pipelined_support_error(shape, k, itemsize: int = 4, bx=None, by=None,
     ``npt``: when given, also require the PT schedule to admit a kernel
     chunk at all (``npt=1`` leaves none, and the cadence then runs the XLA
     path regardless of the split)."""
-    from ..ops import pallas_pt
-    from ._fused import pipelined_support_error as _generic
-
     if npt is not None and not _pt_schedule(int(npt), k)[1]:
         return f"npt={npt} leaves no even kernel chunk: XLA cadence"
-    # stagger=1: the flux fields' shape-aware ol is one deeper than the
-    # grid overlap, and their send planes must fit the ring tiles too.
-    return _generic(pallas_pt, shape, k, itemsize, bx, by, gg, stagger=1)
+    return _fused.pipelined_support_error(FAMILY, shape, k, itemsize, bx, by, gg)
 
 
-@_spanned_multi_step("porous_convection3d")
+#: the PT kernel family (`ops/pallas_pt.py`, padded face layout for the
+#: fluxes; stagger=1: their ol is one deeper than the grid overlap); the
+#: hooks are bound per `make_multi_step` call
+FAMILY = _fused.Family(
+    "porous", pallas_pt, stagger=1, units=("iteration", "PT iterations")
+)
+
+
+@_fused.spanned_multi_step("porous_convection3d")
 def make_multi_step(
     params: Params,
     nsteps: int,
@@ -400,7 +399,6 @@ def make_multi_step(
     pipelined: bool | None = None,
     batch: bool = False,
     coalesce: bool | None = None,
-    autotune: bool | None = None,
 ):
     """Advance ``nsteps`` time steps per call in ONE XLA program
     (`lax.fori_loop` over whole time steps) — the production path: per-call
@@ -457,16 +455,7 @@ def make_multi_step(
     always win, results bit-identical).  ``npt`` is part of the tuning KEY,
     never tuned: it changes the numerics, and tuning changes schedule only.
     """
-    from jax import lax
-
-    from ._fused import run_group_schedule
-    from ..tuning.search import maybe_autotune
-
-    fused_k, fused_tile, exchange_every, pipelined, coalesce = maybe_autotune(
-        "porous_convection3d", params, nsteps, autotune, batch=batch,
-        fused_k=fused_k, fused_tile=fused_tile, exchange_every=exchange_every,
-        pipelined=pipelined, coalesce=coalesce,
-    )
+    from ._fused import group_exchange, run_group_schedule
 
     t_update = _temperature_update(params)
     flux_update = _flux_update(params)
@@ -492,7 +481,8 @@ def make_multi_step(
         by a width-``w`` slab exchange (width ``w`` for EVERY chunk: it
         heals any chunk's stale rind and keeps the exchange geometry
         uniform; the sent planes sit ``o - w >= w >= ki`` from the edge, so
-        they are exact)."""
+        they are exact).  ``early_exchange``: ``pipelined=True``'s
+        early-dispatch exchange shape (bit-identical values)."""
         sched = ([w] * (npt // w)) if chunks is None else list(chunks)
 
         def block_step(T, Pf, qDx, qDy, qDz):
@@ -511,19 +501,7 @@ def make_multi_step(
             def group(ki, s):
                 for _ in range(ki):
                     s = pt_iterate(T, s)
-                if early_exchange:
-                    # pipelined=True: the early-dispatch exchange shape
-                    # (begin/finish; bit-identical values).
-                    from ..ops.halo import (
-                        begin_slab_exchange,
-                        finish_slab_exchange,
-                    )
-
-                    pend = begin_slab_exchange(
-                        s, (0, 1, 2), width=w, coalesce=coalesce
-                    )
-                    return finish_slab_exchange(s, pend)
-                return update_halo(*s, width=w, coalesce=coalesce)
+                return group_exchange(s, w, early_exchange, coalesce)
 
             s = run_group_schedule(
                 sched, group, s, unroll_limit=1, fori_excess_only=False
@@ -536,363 +514,67 @@ def make_multi_step(
         return block_step
 
     if fused_k:
-        import jax
+        from ..ops.pallas_pt import fused_pt_iterations
 
-        from ..ops.halo import dim_has_halo_activity, require_deep_halo
-        from ..ops.pallas_pt import (
-            fused_pt_iterations,
-            fused_support_error,
-            pad_faces,
-            unpad_faces,
-        )
-        from ..parallel.grid import global_grid
-        from ._fused import run_group_schedule, warn_fused_fallback
-
-        gg = global_grid()
-        if params.hide_comm:
-            raise ValueError(
-                "fused_k and hide_comm are mutually exclusive: the fused "
-                "kernel's slab exchange is already amortized over k "
-                "iterations; overlap scheduling applies to the per-iteration "
-                "XLA path."
-            )
-        if exchange_every not in (1, fused_k):
-            raise ValueError(
-                f"fused_k={fused_k} already exchanges every fused_k PT "
-                f"iterations; exchange_every={exchange_every} conflicts."
-            )
-        require_deep_halo(fused_k, gg, what="fused_k")
-        active = [d for d in range(3) if dim_has_halo_activity(gg, d)]
         w = fused_k
         # Ragged schedule (VERDICT r3 #5: ``w | npt`` made the kernel benefit
         # depend on a numerics parameter — npt=10 admitted only w=2): chunk
         # npt into even kernel chunks of at most w iterations, preceded by
-        # one per-iteration-exchanged XLA iteration when npt is odd.
+        # one per-iteration-exchanged XLA iteration when npt is odd.  Patch
+        # application and export stay at width w for every chunk (heals the
+        # previous chunk's w-deep rind).
         lead, chunks = _pt_schedule(npt, w)
         th = params.theta_q
         idx, idy, idz = 1.0 / params.dx, 1.0 / params.dy, 1.0 / params.dz
         ralam = params.Ra * params.lam_T
         bp = params.beta_p
-        bx, by = fused_tile if fused_tile is not None else (None, None)
-        if (bx is None) != (by is None):
-            raise ValueError(f"fused_tile={fused_tile}: pass both bx and by, or neither")
 
-        def kernel_iters(ki, T, Pf, qxp, qyp, qzp, z_patches=None, tile=None,
-                         **zkw):
-            # ``tile``: the pipelined paths pin every chunk to the tile the
-            # split gate validated at k=w (a shorter ragged chunk would
-            # otherwise re-resolve its own ladder default — a geometry the
-            # ring/mid admissibility check never saw).  Serialized paths
-            # keep the per-chunk ladder resolution.
-            tbx, tby = tile if tile is not None else (bx, by)
+        def launch(ki, s, T, bx, by, z_patches=None, **kw):
+            if z_patches is not None:
+                kw.update(z_patch_width=w, z_export=True, z_export_width=w)
             return fused_pt_iterations(
-                T, Pf, qxp, qyp, qzp, ki, th, idx, idy, idz, ralam, bp,
-                bx=tbx, by=tby, z_patches=z_patches, **zkw,
+                T, *s, ki, th, idx, idy, idz, ralam, bp,
+                bx=bx, by=by, z_patches=z_patches, **kw,
             )
 
-        if not active:
-            if pipelined:
-                from ._fused import warn_pipelined_fallback
-
-                warn_pipelined_fallback(
-                    None, w,
-                    "no halo activity: nothing to overlap", model="porous",
+        def enter(T, Pf, qDx, qDy, qDz):
+            # The fluxes stay padded across the whole PT loop: one pad/unpad
+            # per time step, not one per group.
+            for _ in range(lead):
+                Pf, qDx, qDy, qDz = update_halo(
+                    *pt_iterate(T, (Pf, qDx, qDy, qDz)), coalesce=coalesce
                 )
+            return _fused.pad_enter(Pf, qDx, qDy, qDz)[0], T
 
-            def fused_block_step(T, Pf, qDx, qDy, qDz):
-                # Fluxes stay padded across the whole PT loop (no exchange
-                # to serve); the no-op update_halo calls are skipped too.
-                for _ in range(lead):
-                    Pf, qDx, qDy, qDz = pt_iterate(T, (Pf, qDx, qDy, qDz))
-                qxp, qyp, qzp = pad_faces(qDx, qDy, qDz)
-                Pf, qxp, qyp, qzp = run_group_schedule(
-                    chunks, lambda ki, s: kernel_iters(ki, T, *s),
-                    (Pf, qxp, qyp, qzp),
-                )
-                qDx, qDy, qDz = unpad_faces(qxp, qyp, qzp)
-                T = t_update(T, qDx, qDy, qDz)
-                return T, Pf, qDx, qDy, qDz
+        def leave(s, T):
+            Pf, qDx, qDy, qDz = _fused.unpad_leave(s)
+            T = t_update(T, qDx, qDy, qDz)
+            return update_halo(T), Pf, qDx, qDy, qDz
 
-        else:
+        def xla(width, early):
+            return cadence_block_step(width, lead, chunks, early)
 
-            def fused_block_step(T, Pf, qDx, qDy, qDz):
-                from ..ops.halo import update_halo_padded_faces
-
-                for _ in range(lead):
-                    Pf, qDx, qDy, qDz = update_halo(
-                        *pt_iterate(T, (Pf, qDx, qDy, qDz)),
-                        coalesce=coalesce,
-                    )
-
-                def group(ki, s):
-                    out = kernel_iters(ki, T, *s)
-                    # All four PT fields slab-exchange (the fluxes' rind
-                    # relaxation history is stale — see exchange_every) —
-                    # directly on the padded layout: one pad/unpad per
-                    # whole PT loop instead of one per group.  Width w for
-                    # every chunk: heals any chunk's stale rind; sent
-                    # planes sit o-w >= w >= ki from the edge, so they are
-                    # exact after ki iterations.
-                    return update_halo_padded_faces(
-                        *out, width=w, coalesce=coalesce
-                    )
-
-                Pf, qxp, qyp, qzp = run_group_schedule(
-                    chunks, group, (Pf, *pad_faces(qDx, qDy, qDz))
-                )
-                qDx, qDy, qDz = unpad_faces(qxp, qyp, qzp)
-                T = t_update(T, qDx, qDy, qDz)
-                T = update_halo(T)
-                return T, Pf, qDx, qDy, qDz
-
-            def fused_zpatch_step(T, Pf, qDx, qDy, qDz):
-                from ..ops.halo import (
-                    apply_z_patches,
-                    fix_topface_z_exports,
-                    identity_z_patches,
-                    ol,
-                    update_halo_padded_faces,
-                    z_patches_from_exports,
-                )
-
-                for _ in range(lead):
-                    Pf, qDx, qDy, qDz = update_halo(
-                        *pt_iterate(T, (Pf, qDx, qDy, qDz)),
-                        coalesce=coalesce,
-                    )
-                s0 = (Pf, *pad_faces(qDx, qDy, qDz))
-                o_z = ol(2, shape=tuple(Pf.shape), gg=gg)
-                patches0 = identity_z_patches(*s0, width=w)
-
-                def group_k(ki, carry):
-                    s, patches = carry
-                    # In-kernel z-slab application + in-kernel export of
-                    # the next group's send slabs (round 4); x/y exchange
-                    # outside for fields and packed exports alike — see
-                    # acoustic3d's fused_zpatch_step.  Patch application
-                    # and export both at width w regardless of ki (ragged
-                    # schedule: heals the previous chunk's w-deep rind).
-                    out = kernel_iters(
-                        ki, T, *s, z_patches=patches, z_patch_width=w,
-                        z_export=True, z_export_width=w, z_overlap=o_z,
-                    )
-                    s, exports = out[:4], out[4:]
-                    exports = fix_topface_z_exports(exports, *s, width=w)
-                    s = update_halo_padded_faces(
-                        *s, width=w, dims=(0, 1), coalesce=coalesce
-                    )
-                    patches = z_patches_from_exports(
-                        exports, tuple(s[0].shape), width=w
-                    )
-                    return s, patches
-
-                s, patches = run_group_schedule(chunks, group_k, (s0, patches0))
-                Pf, qxp, qyp, qzp = apply_z_patches(*s, patches, width=w)
-                qDx, qDy, qDz = unpad_faces(qxp, qyp, qzp)
-                T = t_update(T, qDx, qDy, qDz)
-                T = update_halo(T)
-                return T, Pf, qDx, qDy, qDz
-
-            def fused_pipelined_block_step(T, Pf, qDx, qDy, qDz):
-                # Boundary-first split of `fused_block_step` (z-inactive):
-                # ring pass feeds the all-field slab exchange early,
-                # interior pass runs across the in-flight collectives.
-                from ..ops.halo import (
-                    _padded_logicals,
-                    begin_slab_exchange,
-                    finish_slab_exchange,
-                )
-                from ._fused import run_pipelined_group_schedule
-
-                for _ in range(lead):
-                    Pf, qDx, qDy, qDz = update_halo(
-                        *pt_iterate(T, (Pf, qDx, qDy, qDz)),
-                        coalesce=coalesce,
-                    )
-                sel, _, ptile = _split(tuple(Pf.shape), Pf.dtype.itemsize, False)
-                s0 = (Pf, *pad_faces(qDx, qDy, qDz))
-                logicals = _padded_logicals(*s0)
-
-                def boundary(ki, s):
-                    out_b = kernel_iters(ki, T, *s, tile=ptile, tile_sel="ring" + sel)
-                    pend = begin_slab_exchange(
-                        out_b, (0, 1), width=w, logicals=logicals,
-                        coalesce=coalesce,
-                    )
-                    return out_b, pend
-
-                def interior(ki, s, out_b, pend):
-                    out = kernel_iters(
-                        ki, T, *s, tile=ptile, tile_sel="mid" + sel,
-                        carry_in=out_b,
-                    )
-                    return finish_slab_exchange(out, pend, logicals=logicals)
-
-                # Same loop shaping as the serialized Pallas cadence (the
-                # unrolled-group pipelining win; only the XLA cadence needs
-                # the all-or-nothing fori shape).
-                Pf, qxp, qyp, qzp = run_pipelined_group_schedule(
-                    chunks, boundary, interior, s0
-                )
-                qDx, qDy, qDz = unpad_faces(qxp, qyp, qzp)
-                T = t_update(T, qDx, qDy, qDz)
-                T = update_halo(T)
-                return T, Pf, qDx, qDy, qDz
-
-            def fused_zpatch_pipelined_step(T, Pf, qDx, qDy, qDz):
-                # Boundary-first split of `fused_zpatch_step`: the PT
-                # fields' x/y slabs exchange early off the ring pass; the
-                # packed z exports complete with the interior pass.
-                from ..ops.halo import (
-                    _padded_logicals,
-                    apply_z_patches,
-                    begin_slab_exchange,
-                    finish_slab_exchange,
-                    fix_topface_z_exports,
-                    identity_z_patches,
-                    ol,
-                    z_patches_from_exports,
-                )
-                from ._fused import run_pipelined_group_schedule
-
-                for _ in range(lead):
-                    Pf, qDx, qDy, qDz = update_halo(
-                        *pt_iterate(T, (Pf, qDx, qDy, qDz)),
-                        coalesce=coalesce,
-                    )
-                s0 = (Pf, *pad_faces(qDx, qDy, qDz))
-                o_z = ol(2, shape=tuple(Pf.shape), gg=gg)
-                patches0 = identity_z_patches(*s0, width=w)
-                sel, _, ptile = _split(tuple(Pf.shape), Pf.dtype.itemsize, True)
-                logicals = _padded_logicals(*s0)
-
-                def boundary(ki, carry):
-                    s, patches = carry
-                    out_b = kernel_iters(
-                        ki, T, *s, z_patches=patches, z_patch_width=w,
-                        z_export=True, z_export_width=w, z_overlap=o_z,
-                        tile=ptile, tile_sel="ring" + sel,
-                    )
-                    pend = begin_slab_exchange(
-                        out_b[:4], (0, 1), width=w, logicals=logicals,
-                        coalesce=coalesce,
-                    )
-                    return out_b, pend
-
-                def interior(ki, carry, out_b, pend):
-                    s, patches = carry
-                    out = kernel_iters(
-                        ki, T, *s, z_patches=patches, z_patch_width=w,
-                        z_export=True, z_export_width=w, z_overlap=o_z,
-                        tile=ptile, tile_sel="mid" + sel, carry_in=out_b,
-                    )
-                    s2, exports = out[:4], out[4:]
-                    exports = fix_topface_z_exports(exports, *s2, width=w)
-                    s2 = finish_slab_exchange(s2, pend, logicals=logicals)
-                    patches2 = z_patches_from_exports(
-                        exports, tuple(s2[0].shape), width=w
-                    )
-                    return s2, patches2
-
-                # Serialized-cadence loop shaping (see above).
-                s, patches = run_pipelined_group_schedule(
-                    chunks, boundary, interior, (s0, patches0)
-                )
-                Pf, qxp, qyp, qzp = apply_z_patches(*s, patches, width=w)
-                qDx, qDy, qDz = unpad_faces(qxp, qyp, qzp)
-                T = t_update(T, qDx, qDy, qDz)
-                T = update_halo(T)
-                return T, Pf, qDx, qDy, qDz
-
-        xla_block_step = cadence_block_step(w, lead, chunks)
-        z_active = dim_has_halo_activity(gg, 2)
-        from ._fused import fused_with_xla_grad, resolve_pipelined, split_selector
-
-        active01 = tuple(d for d in (0, 1) if d in active)
-
-        def _split(shape, itemsize, zpatch):
-            """(ring/mid selector suffix, admissibility error) — the shared
-            trace-time gate (`split_selector`; stagger=1 for the flux
-            fields).  The ragged schedule keeps patch/export widths at
-            ``w`` for every chunk, so the split is gated at the worst case
-            ``ki = w`` too."""
-            from ..ops import pallas_pt
-
-            return split_selector(
-                pallas_pt, shape, w, w, itemsize, bx, by,
-                active01, zpatch, stagger=1, gg=gg,
-            )
-
-        def block_step(T, Pf, qDx, qDy, qDz):
-            # Shapes are only known at trace time, so the kernel-vs-fallback
-            # choice happens there (the reference's runtime-path-selection
-            # move, `/root/reference/src/update_halo.jl:755-784`).  Kernel
-            # paths are wrapped with `fused_with_xla_grad`: primal runs the
-            # Pallas chunk, jax.grad differentiates the XLA cadence.
-            shape = tuple(Pf.shape)
-            if (
-                chunks
-                and active
-                and z_active
-                and fused_support_error(
-                    shape, w, Pf.dtype.itemsize, bx, by, zpatch=True
-                ) is None
-            ):
-                # In-kernel z-slab application (see docs/performance.md).
-                body = fused_zpatch_step
-                if resolve_pipelined(
-                    pipelined, _split(shape, Pf.dtype.itemsize, True)[1],
-                    shape, w, "porous",
-                ):
-                    body = fused_zpatch_pipelined_step
-                return fused_with_xla_grad(body, xla_block_step)(
-                    T, Pf, qDx, qDy, qDz
-                )
-            err = fused_support_error(shape, w, Pf.dtype.itemsize, bx, by)
-            if err is None and not chunks:
-                err = f"npt={npt} leaves no even kernel chunk"
-            if err is None:
-                body = fused_block_step
-                if active and not z_active and resolve_pipelined(
-                    pipelined, _split(shape, Pf.dtype.itemsize, False)[1],
-                    shape, w, "porous",
-                ):
-                    body = fused_pipelined_block_step
-                return fused_with_xla_grad(body, xla_block_step)(
-                    T, Pf, qDx, qDy, qDz
-                )
-            warn_fused_fallback(tuple(Pf.shape), w, err, model="porous")
-            if pipelined:
-                return cadence_block_step(w, lead, chunks, early_exchange=True)(
-                    T, Pf, qDx, qDy, qDz
-                )
-            return xla_block_step(T, Pf, qDx, qDy, qDz)
-
-    elif exchange_every < 1:
-        raise ValueError(f"exchange_every must be >= 1 (got {exchange_every})")
-    elif exchange_every > 1:
-        from ..ops.halo import require_deep_halo
-
-        if params.hide_comm:
-            raise ValueError(
-                "exchange_every and hide_comm are mutually exclusive: overlap "
-                "scheduling hides the per-iteration exchange; a slab cadence "
-                "replaces it."
-            )
-        require_deep_halo(exchange_every)
-        block_step = cadence_block_step(
-            exchange_every, *_pt_schedule(npt, exchange_every, even=False),
-            early_exchange=bool(pipelined),
+        fam = FAMILY.bind(
+            enter=enter, leave=leave, launch=launch, xla=xla,
+            **_fused.padded_faces(coalesce),
         )
-
+        block_step, _ = _fused.fused_cadence(
+            fam, w, chunks=chunks,
+            schedule_err=None if chunks else f"npt={npt} leaves no even kernel chunk",
+            fused_tile=fused_tile, exchange_every=exchange_every,
+            pipelined=pipelined, hide_comm=params.hide_comm, coalesce=coalesce,
+        )
     else:
-        if pipelined:
-            raise ValueError(
-                "pipelined applies to the group cadences (fused_k or "
-                "exchange_every > 1); the per-iteration path has no group "
-                "schedule."
+        _fused.check_xla_cadence(
+            FAMILY, exchange_every, pipelined=pipelined, hide_comm=params.hide_comm
+        )
+        if exchange_every > 1:
+            block_step = cadence_block_step(
+                exchange_every, *_pt_schedule(npt, exchange_every, even=False),
+                early_exchange=bool(pipelined),
             )
-        block_step = _build_block_step(params, coalesce=coalesce)
+        else:
+            block_step = _build_block_step(params, coalesce=coalesce)
 
     # The Python unroll is only cheap for production-sized chunks; past this
     # the trace/HLO grows linearly (each step carries npt PT iterations) and
@@ -911,16 +593,12 @@ def make_multi_step(
             s = block_step(*s)
         return s
 
-    donate_argnums = tuple(range(5)) if donate else ()
-    if batch:
-        # Ensemble cadence: vmap over the leading member axis — every path
-        # (PT fori_loop, slab exchanges, fused PT kernels via the
-        # pallas_call batching rule) batches with a B-invariant collective
-        # budget (see `models.diffusion3d.make_multi_step`).
-        from ._batched import batched_stencil
-
-        return batched_stencil(multi, 5, donate_argnums=donate_argnums)
-    return stencil(multi, donate_argnums=donate_argnums)
+    # batch: vmap over the leading member axis — every path (PT fori_loop,
+    # slab exchanges, fused PT kernels via the pallas_call batching rule)
+    # batches with a B-invariant collective budget.
+    return _fused.stencil_call(
+        multi, 5, tuple(range(5)) if donate else (), batch
+    )
 
 
 def run(

@@ -176,13 +176,6 @@ def fused_support_error(shape, k: int, itemsize: int = 4,
 #: `ops.halo.update_halo_padded_faces`).
 PADS = (8, 8, 128)
 
-#: The kernel does a self-neighbour z exchange inside its tiles (``z_wrap`` of
-#: `fused_leapfrog_steps`): on a periodic z that is not split across devices
-#: the cadence runs it in place of the z patches and exports, and the
-#: pipelined gate (`models._fused.pipelined_support_error`) treats such a z
-#: like an inactive one.
-Z_SELF_WRAP = True
-
 #: x-rows per step of the in-tile z wrap's loop.  Each lane-band copy
 #: unrolls into vector code per row, so the (32, 64) tile's copies written out
 #: whole made the periodic-z program's cold compile 46 s longer on a v5e host

@@ -304,20 +304,22 @@ def autotune_requested(autotune) -> bool:
 
 
 def maybe_autotune(model: str, params, nsteps: int, autotune, *,
-                   batch: bool = False, **kwargs) -> tuple:
-    """The models' ONE-statement ``make_multi_step`` hook: resolve the five
-    tunable kwargs through the winner cache when autotuning is requested
-    (kwarg > ``IGG_AUTOTUNE`` > off), pass them through untouched otherwise.
-    Returns ``(fused_k, fused_tile, exchange_every, pipelined, coalesce)``
+                   batch: bool = False, **kwargs) -> dict:
+    """The models' ``make_multi_step`` hook (`models._fused.
+    spanned_multi_step`): resolve the five tunable kwargs through the
+    winner cache when autotuning is requested (kwarg > ``IGG_AUTOTUNE`` >
+    off), pass them through untouched otherwise.  Returns ``kwargs`` with
+    every tunable field present (its default where the caller left it out)
     — one definition for the three models, so a new tunable field cannot
     be wired into one entry point and silently dropped from another.
     """
+    kwargs = {**_KWARG_DEFAULTS, **kwargs}
     if autotune_requested(autotune):
         kwargs = apply_tuned_config(
-            model, _space.model_module(model), params, nsteps, dict(kwargs),
+            model, _space.model_module(model), params, nsteps, kwargs,
             batch=batch,
         )
-    return tuple(kwargs[k] for k in _KWARG_DEFAULTS)
+    return kwargs
 
 
 def apply_tuned_config(model: str, module, params, nsteps: int,

@@ -2,13 +2,17 @@
 carry-copy-free paired fori body, and the deep-z envelope gate coupling
 (`ops/pallas_stencil`) — advisor r4 findings."""
 
+import importlib
+import itertools
+import warnings
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import implicitglobalgrid_tpu as igg
-from implicitglobalgrid_tpu.models._fused import run_group_schedule
+from implicitglobalgrid_tpu.models._fused import pick_body, run_group_schedule
 from implicitglobalgrid_tpu.ops.pallas_stencil import fused_support_error
 from implicitglobalgrid_tpu.utils import telemetry
 from implicitglobalgrid_tpu.utils.hlo_analysis import (
@@ -174,3 +178,91 @@ def test_deep_z_gate_and_budget_jointly_cover_by128():
         assert err is not None, f"(k={k}, n2={n2}) must be rejected"
     # the hardware-validated deep-z rung stays in the envelope
     assert fused_support_error((64, 256, 512), 4, 4, 32, 128) is None
+
+
+# --- the one trace-time gate (`models/_fused.pick_body`) -------------------
+
+
+def _resolve(pipelined, split_ok):
+    return pipelined is not False and split_ok
+
+
+def _diffusion_gate(xy, z, plain_ok, zpatch_ok, split_ok, chunks_ok, pipelined):
+    """The diffusion cadence's choice as it was written in its model file:
+    a grid with no halo activity runs its own jitted chunk without
+    pipelined bodies; a z-active grid takes the z-patch kernel first."""
+    z_active = z != "inactive"
+    if not (xy or z_active):
+        return "plain" if plain_ok else "xla"
+    if z_active and zpatch_ok:
+        return "zpatch_piped" if _resolve(pipelined, split_ok) else "zpatch"
+    if plain_ok:
+        piped = not z_active and _resolve(pipelined, split_ok)
+        return "plain_piped" if piped else "plain"
+    return "xla_early" if pipelined else "xla"
+
+
+def _acoustic_gate(xy, z, plain_ok, zpatch_ok, split_ok, chunks_ok, pipelined):
+    """The acoustic cadence's choice as it was written in its model file: a
+    self-neighbour z wraps inside the kernel's tile, so it gates like an
+    inactive z."""
+    z_active = z != "inactive"
+    z_self = z == "self"
+    if not (xy or z_active):
+        return "plain" if plain_ok else "xla"
+    if z_active and not z_self and zpatch_ok:
+        return "zpatch_piped" if _resolve(pipelined, split_ok) else "zpatch"
+    if plain_ok:
+        piped = (z_self or not z_active) and _resolve(pipelined, split_ok)
+        return "plain_piped" if piped else "plain"
+    return "xla_early" if pipelined else "xla"
+
+
+def _porous_gate(xy, z, plain_ok, zpatch_ok, split_ok, chunks_ok, pipelined):
+    """The porous cadence's choice as it was written in its model file: one
+    gate for every grid, a PT schedule without an even chunk falls back,
+    and any truthy ``pipelined`` takes the early-dispatch XLA cadence."""
+    z_active = z != "inactive"
+    active = xy or z_active
+    if chunks_ok and active and z_active and zpatch_ok:
+        return "zpatch_piped" if _resolve(pipelined, split_ok) else "zpatch"
+    if plain_ok and chunks_ok:
+        piped = active and not z_active and _resolve(pipelined, split_ok)
+        return "plain_piped" if piped else "plain"
+    return "xla_early" if pipelined else "xla"
+
+
+_GATES = {"diffusion3d": _diffusion_gate, "acoustic3d": _acoustic_gate,
+          "porous_convection3d": _porous_gate}
+
+
+@pytest.mark.parametrize("pipelined", [None, True, False],
+                         ids=["auto", "piped", "serial"])
+@pytest.mark.parametrize("z", ["inactive", "self", "split"])
+@pytest.mark.parametrize("model", sorted(_GATES))
+def test_gate_table(model, z, pipelined):
+    """`_fused.pick_body` with each solver's family picks the body that
+    solver's own gate picked before the three gates became one, over x/y
+    activity x the plain and z-patch envelopes x the split's admissibility
+    (x, for porous, whether the PT schedule has a kernel chunk)."""
+    fam = importlib.import_module(
+        f"implicitglobalgrid_tpu.models.{model}").FAMILY
+    chunk_cases = (True, False) if model == "porous_convection3d" else (True,)
+    for xy, plain_ok, zpatch_ok, split_ok, chunks_ok in itertools.product(
+            (False, True), (True, False), (True, False), (True, False),
+            chunk_cases):
+        want = _GATES[model](xy, z, plain_ok, zpatch_ok, split_ok, chunks_ok,
+                             pipelined)
+        schedule_err = None if chunks_ok else "no kernel chunk"
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore", RuntimeWarning)
+            got = pick_body(
+                fam, active=xy or z != "inactive", z_active=z != "inactive",
+                z_self=z == "self",
+                plain_err=None if plain_ok else "plain envelope",
+                zpatch_err=None if zpatch_ok else "z-patch envelope",
+                schedule_err=schedule_err,
+                split_err=lambda zpatch: None if split_ok else "split",
+                pipelined=pipelined, shape=(24, 32, 128), k=2,
+            )
+        assert got == want, (xy, plain_ok, zpatch_ok, split_ok, chunks_ok)

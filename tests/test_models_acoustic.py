@@ -291,8 +291,8 @@ def test_fused_zpatch_deep_halo_z_split_matches_xla():
         ((2, 1, 1), (24, 32, 128), False),
         ((2, 1, 1), (24, 32, 128), True),
         # x split and a real z partner: the patch/export kernel, its packed
-        # exports riding the x/y exchange (`fused_zpatch_step` and
-        # `fused_zpatch_pipelined_step`), no in-kernel wrap
+        # exports riding the x/y exchange (the `zpatch` and `zpatch_piped`
+        # bodies of `models._fused.fused_cadence`), no in-kernel wrap
         ((2, 1, 2), (24, 32, 128), False),
         ((2, 1, 2), (24, 32, 128), True),
     ],
