@@ -276,7 +276,7 @@ def _cell_zpatch(transposed: bool, coalesce=None):
 
 #: the diffusion kernel family (`ops/pallas_stencil.py`); its hooks are bound
 #: per `make_multi_step` call
-FAMILY = _fused.Family("diffusion", pallas_stencil, idle_steps=True)
+FAMILY = _fused.Family("diffusion", pallas_stencil, z_wrap=True, idle_steps=True)
 
 
 @_fused.spanned_multi_step("diffusion3d")
@@ -393,6 +393,7 @@ def make_multi_step(
         return block_step
 
     if fused_k:
+        from ..ops import halo
         from ..ops.pallas_stencil import fused_diffusion_steps, zpatch_transposed
 
         cx = params.dt * params.lam / (params.dx * params.dx)
@@ -405,6 +406,13 @@ def make_multi_step(
             out = fused_diffusion_steps(s[0], Cp, ki, cx, cy, cz, bx=bx, by=by, **kw)
             return out if isinstance(out, tuple) else (out,)
 
+        def exchange(s, width, dims=None):
+            # ``dims=(0, 1)``: the x/y slabs of a grid whose z the kernel
+            # wraps in its tiles
+            if dims is None:
+                return (update_halo(s[0], width=width, coalesce=coalesce),)
+            return (halo.exchange_dims(s[0], dims, width=width),)
+
         def zpatch(s):
             T, tile = s[0], fused_tile or (None, None)
             tr = zpatch_transposed(tuple(T.shape), fused_k, T.dtype.itemsize, *tile)
@@ -414,9 +422,7 @@ def make_multi_step(
             enter=lambda T, Cp: ((T,), Cp),
             leave=lambda s, Cp: (s[0], Cp),
             launch=launch,
-            exchange=lambda s, width: (
-                update_halo(s[0], width=width, coalesce=coalesce),
-            ),
+            exchange=exchange,
             zpatch=zpatch,
             xla=xla_path,
         )

@@ -241,7 +241,7 @@ def fused_support_error(shape, k: int, itemsize: int = 4,
 def fused_diffusion_steps(T, Cp, k: int, cx: float, cy: float, cz: float,
                           *, bx: int | None = None, by: int | None = None,
                           z_patch=None, z_export: bool = False,
-                          z_overlap: int | None = None,
+                          z_wrap: bool = False, z_overlap: int | None = None,
                           tile_sel: str = "all", carry_in=None):
     """Advance ``k`` (even) diffusion steps in one HBM pass.
 
@@ -270,6 +270,16 @@ def fused_diffusion_steps(T, Cp, k: int, cx: float, cy: float, cz: float,
     docs/performance.md).  `ops.halo.z_patch_from_export` turns the export
     into the next patch.
 
+    ``z_wrap`` (requires the grid z-overlap ``z_overlap``, and no
+    ``z_patch``): the self-neighbour z exchange of a periodic z that is not
+    split across devices, done inside each tile.  After the k steps the
+    owned block's z-halo planes are overwritten from its own planes --
+    ``[0,k)`` <- ``[n2-o, n2-o+k)`` and ``[n2-k, n2)`` <- ``[o-k, o)``, the
+    export planes followed by the patch apply of the ``z_patch``/
+    ``z_export`` cadence -- so the output is the k steps plus the z halo
+    update, with no patch input, no export output and the plain variant's
+    tile and VMEM set (as `ops.pallas_leapfrog.fused_leapfrog_steps`).
+
     ``tile_sel`` (pipelined group schedule, `ops.overlap.tile_subset_map`):
     restrict the launch to a tile subset — ``"ring*"`` = the boundary tiles
     (owning the x/y slab-exchange send planes), ``"mid*"`` = the interior
@@ -287,14 +297,17 @@ def fused_diffusion_steps(T, Cp, k: int, cx: float, cy: float, cz: float,
     zp = z_patch is not None
     if zp and z_patch.dtype != T.dtype:
         raise ValueError("z_patch must share T's dtype")
-    if z_export:
-        if not zp:
-            raise ValueError("z_export requires z_patch (the z-slab cadence)")
+    if z_export and not zp:
+        raise ValueError("z_export requires z_patch (the z-slab cadence)")
+    if z_wrap and zp:
+        raise ValueError("z_wrap replaces z_patch; pass one or the other")
+    if z_export or z_wrap:
         if z_overlap is None or not (2 * k <= z_overlap <= n2 // 2):
             raise ValueError(
-                f"z_export needs the grid z-overlap with 2k <= o <= n2/2: "
-                f"got o={z_overlap}, k={k}, n2={n2}"
+                f"z_export and z_wrap need the grid z-overlap with "
+                f"2k <= o <= n2/2: got o={z_overlap}, k={k}, n2={n2}"
             )
+    if z_export:
         if 4 * k > 128:
             raise ValueError(f"z_export packs 4k lanes; k={k} > 32 unsupported")
     err = fused_support_error(
@@ -325,8 +338,8 @@ def fused_diffusion_steps(T, Cp, k: int, cx: float, cy: float, cz: float,
     )
     fn = _build(n0, n1, n2, str(T.dtype), int(k),
                 float(cx), float(cy), float(cz), int(bx), int(by), zp,
-                bool(z_export), int(z_overlap) if z_export else 0,
-                str(tile_sel), carry_in is not None)
+                bool(z_export), int(z_overlap) if z_export or z_wrap else 0,
+                str(tile_sel), carry_in is not None, bool(z_wrap))
     args = (T, Cp, z_patch) if zp else (T, Cp)
     if carry_in is not None:
         args += tuple(carry_in)
@@ -336,13 +349,14 @@ def fused_diffusion_steps(T, Cp, k: int, cx: float, cy: float, cz: float,
 @functools.lru_cache(maxsize=64)
 def _build(n0, n1, n2, dtype, k, cx, cy, cz, bx, by, zp: bool = False,
            zx: bool = False, o: int = 0, tile_sel: str = "all",
-           carry: bool = False):
+           carry: bool = False, zw: bool = False):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     from .overlap import tile_subset_count, tile_subset_map
+    from .pallas_leapfrog import _WRAP_ROWS
 
     # Full-y mode (by == n1): the window spans all of y with no y halo (the
     # window edge IS the block edge, where the frozen ring reproduces the
@@ -543,6 +557,27 @@ def _build(n0, n1, n2, dtype, k, cx, cy, cz, bx, by, zp: bool = False,
                         step_into(scratch, tin[slot], minv, ring=(j == 0))
                     else:
                         step_into(tin.at[slot], scratch[:], minv, ring=False)
+                if zw:
+                    # The self-neighbour z exchange on the owned block: the
+                    # export planes followed by the patch apply, as a loop
+                    # over blocks of _WRAP_ROWS x-rows (see there).
+                    ix, iy = ixy(t)
+                    ox = ix * bx - sx_of(ix)
+                    ys = (slice(0, n1) if fy else
+                          pl.ds(pl.multiple_of(iy * by - sy_of(iy), 8), by))
+
+                    def wrap(x0, nx):
+                        xr = pl.ds(x0, nx)
+                        tin[slot, xr, ys, 0:k] = tin[slot, xr, ys, n2 - o : n2 - o + k]
+                        tin[slot, xr, ys, n2 - k : n2] = tin[slot, xr, ys, o - k : o]
+
+                    def rows(r, c):
+                        wrap(ox + r * _WRAP_ROWS, _WRAP_ROWS)
+                        return c
+
+                    jax.lax.fori_loop(0, bx // _WRAP_ROWS, rows, 0)
+                    if bx % _WRAP_ROWS:
+                        wrap(ox + bx - bx % _WRAP_ROWS, bx % _WRAP_ROWS)
                 if zx and zt:
                     # Transposed export: whole-window transposes (static
                     # slices only — a traced-offset VMEM *load* is not
@@ -638,6 +673,6 @@ def _build(n0, n1, n2, dtype, k, cx, cy, cz, bx, by, zp: bool = False,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_envelope.vmem_limit(2 * vmem_bytes)
         ),
-        name=_envelope.kernel_name("diffusion", k, zp, tile_sel),
+        name=_envelope.kernel_name("diffusion", k, zp or zw, tile_sel),
     )
     return _envelope.per_member(jax.jit(call))

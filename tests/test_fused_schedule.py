@@ -190,14 +190,17 @@ def _resolve(pipelined, split_ok):
 def _diffusion_gate(xy, z, plain_ok, zpatch_ok, split_ok, chunks_ok, pipelined):
     """The diffusion cadence's choice as it was written in its model file:
     a grid with no halo activity runs its own jitted chunk without
-    pipelined bodies; a z-active grid takes the z-patch kernel first."""
+    pipelined bodies; a z-active grid takes the z-patch kernel first, except
+    a self-neighbour z, which the kernel wraps inside its tile (since the
+    diffusion family has ``z_wrap``), so it gates like an inactive z."""
     z_active = z != "inactive"
+    z_self = z == "self"
     if not (xy or z_active):
         return "plain" if plain_ok else "xla"
-    if z_active and zpatch_ok:
+    if z_active and not z_self and zpatch_ok:
         return "zpatch_piped" if _resolve(pipelined, split_ok) else "zpatch"
     if plain_ok:
-        piped = not z_active and _resolve(pipelined, split_ok)
+        piped = (z_self or not z_active) and _resolve(pipelined, split_ok)
         return "plain_piped" if piped else "plain"
     return "xla_early" if pipelined else "xla"
 

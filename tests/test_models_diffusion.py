@@ -443,3 +443,41 @@ def test_fused_zpatch_periodic_z_matches_xla():
         T_got = np.asarray(jax.block_until_ready(stepf(*state))[0])
     igg.finalize_global_grid()
     np.testing.assert_allclose(T_got, T_ref, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("periodz", [1, 0], ids=["periodz", "walls"])
+def test_fused_self_z_wraps_in_kernel(periodz):
+    """On one device a periodic z is its own z partner: the kernel wraps z
+    inside its tiles (``fused.z_self_wrap`` counts the groups), and the
+    program has no z-patch op left (no ``igg_zpatch_*`` scope).  With walls
+    nothing wraps.  Either way the result is the XLA cadence's."""
+    from jax.experimental.pallas.tpu import force_tpu_interpret_mode
+
+    from implicitglobalgrid_tpu.models._fused import Z_SELF_WRAP_COUNTER
+    from implicitglobalgrid_tpu.utils import telemetry
+
+    def wraps():
+        return telemetry.snapshot()["counters"].get(Z_SELF_WRAP_COUNTER, 0)
+
+    nt = 4
+    kw = dict(
+        devices=jax.devices()[:1], periodz=periodz, overlapz=4, quiet=True,
+        dtype=jax.numpy.float32,
+    )
+    state, params = diffusion3d.setup(16, 32, 128, **kw)
+    step = diffusion3d.make_multi_step(params, nt, donate=False)
+    T_ref = np.asarray(jax.block_until_ready(step(*state))[0])
+    igg.finalize_global_grid()
+
+    state, params = diffusion3d.setup(16, 32, 128, **kw)
+    before = wraps()
+    with force_tpu_interpret_mode():
+        stepf = diffusion3d.make_multi_step(params, nt, donate=False, fused_k=2)
+        text = stepf.lower(*state).as_text(debug_info=True)
+        T_got = np.asarray(jax.block_until_ready(stepf(*state))[0])
+    igg.finalize_global_grid()
+    assert (wraps() > before) == bool(periodz)
+    assert "igg_zpatch_" not in text
+    variant = "zpatch" if periodz else "plain"
+    assert f"igg_diffusion_k2_{variant}" in text
+    np.testing.assert_allclose(T_got, T_ref, rtol=1e-5, atol=1e-5)

@@ -239,7 +239,7 @@ def _diffusion_states(nloc, dims, periods, k, nt, pipelined, tile):
     [
         # x-split, z-inactive: the non-zpatch ring0/mid0 split
         ((2, 1, 1), {}, (24, 32, 128), (8, 16)),
-        # x-split + periodic z: the z-patch cadence under the split
+        # x-split + periodic z: the in-kernel z wrap under the split
         ((2, 1, 1), {"periodz": 1}, (24, 32, 128), (8, 16)),
         # y-split (ring1/mid1), z-inactive
         ((1, 2, 1), {}, (16, 48, 128), (8, 16)),
@@ -308,6 +308,47 @@ def test_pipelined_acoustic_matches_serialized_bitwise(dims):
 
     for r, g in zip(run(False), run(True)):
         np.testing.assert_array_equal(r, g)
+
+
+@pytest.mark.parametrize(
+    "dims",
+    [
+        # x split, each device its own z partner: the in-kernel z wrap
+        (2, 1, 1),
+        # x split and a real z partner: the patch/export kernel, its
+        # exports riding the x/y exchange
+        (2, 1, 2),
+    ],
+    ids=["x2-self", "x2z2"],
+)
+def test_pipelined_diffusion_z_wrap_matches_serialized_bitwise(dims):
+    from implicitglobalgrid_tpu.models import diffusion3d
+    from implicitglobalgrid_tpu.models._fused import Z_SELF_WRAP_COUNTER
+
+    def run(pipelined):
+        kw = dict(devices=jax.devices()[: dims[0] * dims[1] * dims[2]],
+                  dimx=dims[0], dimy=dims[1], dimz=dims[2],
+                  overlapx=4, overlapy=4, overlapz=4, periodz=1, quiet=True,
+                  dtype=jnp.float32)
+        state, params = diffusion3d.setup(24, 32, 128, **kw)
+        # the split is admissible: pipelined=True runs the pipelined body
+        assert diffusion3d.pipelined_support_error(
+            (24, 32, 128), 2, bx=8, by=16) is None
+        wraps = counter()
+        with force_tpu_interpret_mode():
+            step = diffusion3d.make_multi_step(
+                params, 4, donate=False, fused_k=2, fused_tile=(8, 16),
+                pipelined=pipelined,
+            )
+            out = np.asarray(jax.block_until_ready(step(*state))[0])
+        igg.finalize_global_grid()
+        assert (counter() > wraps) == (dims[2] == 1)
+        return out
+
+    def counter():
+        return telemetry.snapshot()["counters"].get(Z_SELF_WRAP_COUNTER, 0)
+
+    np.testing.assert_array_equal(run(False), run(True))
 
 
 def test_pipelined_porous_ragged_matches_serialized_bitwise():

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 # name: (model, local n (cube), dims, overlaps, nsteps, make_multi_step
 #        kwargs, extra setup kwargs)
 # Longest compile first (Mosaic time grows with the tile window: ~180, 165,
-# 145, 90, 80, 40, 25 and 16 s here), so three threads finish close together.
+# 145, 90, 80, 40, 25, 16 and 16 s here), so three threads finish close together.
 PROGRAMS = {
     "diffusion_k4_512_tile32x128": ("diffusion3d", 512, (1, 1, 1), (2, 2, 2),
                                     8, dict(fused_k=4, fused_tile=(32, 128)),
@@ -47,9 +47,16 @@ PROGRAMS = {
                                dict(fused_k=4), {}),
     "acoustic_k6_128_walls": ("acoustic3d", 128, (1, 1, 1), (2, 2, 2), 78,
                               dict(fused_k=6), {}),
+    # the four-chip split with a periodic z each chip owns: the kernel wraps z
+    # inside its y-windowed tiles, under the ring/interior split
+    "four_chip_diffusion_pipelined_periodz": (
+        "diffusion3d", 256, (2, 2, 1), (8, 8, 8), 8,
+        dict(fused_k=4, fused_tile=(32, 64), pipelined=True),
+        dict(overlapx=8, overlapy=8)),
 }
 #: programs on a grid with periodic dimensions (x, y, z); others have none
-PERIODS = {"diffusion_k4_128_periodz": (0, 0, 1), "acoustic_k6_256_periodz": (0, 0, 1)}
+PERIODS = {"diffusion_k4_128_periodz": (0, 0, 1), "acoustic_k6_256_periodz": (0, 0, 1),
+           "four_chip_diffusion_pipelined_periodz": (0, 0, 1)}
 #: programs with a fori excess: diffusion 12 groups (8 unrolled, 4 looped),
 #: the loop shape of every 1000-step diffusion cell; acoustic 13 groups (8
 #: unrolled, an odd excess of 5: one more unrolled, two paired iterations;
@@ -68,9 +75,10 @@ NAMES = {
     "acoustic_k6_256": ["igg_leapfrog_k6_plain"],
     "four_chip_diffusion_pipelined": ["igg_diffusion_k4_plain_ring01",
                                       "igg_diffusion_k4_plain_mid01"],
-    "diffusion_k4_128_periodz": ["igg_diffusion_k4_zpatch",
-                                 "igg_zpatch_identity", "igg_zpatch_apply",
-                                 "igg_zpatch_from_export"],
+    "four_chip_diffusion_pipelined_periodz": [
+        "igg_diffusion_k4_zpatch_ring01", "igg_diffusion_k4_zpatch_mid01"],
+    # one chip: the kernel wraps z inside its tiles (its own z partner)
+    "diffusion_k4_128_periodz": ["igg_diffusion_k4_zpatch"],
     "diffusion_k4_128_walls": ["igg_diffusion_k4_plain"],
     "acoustic_k6_128_walls": ["igg_leapfrog_k6_plain", "igg_faces_pad",
                               "igg_faces_unpad"],
@@ -78,10 +86,15 @@ NAMES = {
     "acoustic_k6_256_periodz": ["igg_leapfrog_k6_zpatch", "igg_faces_pad",
                                 "igg_faces_unpad"],
 }
-#: what a program's text must not name: the one-chip periodic-z acoustic
-#: program has no patch to make at chunk entry and none to apply at its end
+#: what a program's text must not name: the one-chip periodic-z programs
+#: have no patch to make at chunk entry, none to take from an export and none
+#: to apply at their end
 ABSENT = {
     "acoustic_k6_256_periodz": ["igg_zpatch_identity", "igg_zpatch_apply"],
+    "diffusion_k4_128_periodz": ["igg_zpatch_identity", "igg_zpatch_apply",
+                                 "igg_zpatch_from_export"],
+    "four_chip_diffusion_pipelined_periodz": [
+        "igg_zpatch_identity", "igg_zpatch_apply", "igg_zpatch_from_export"],
 }
 
 
